@@ -40,6 +40,9 @@ class HW:
     ici_bw: float = 50e9              # B/s per link
     hbm_bytes: float = 16e9
     vmem_bytes: float = 128 * 2**20
+    # VMEM a Pallas kernel gets when it asks for no limit (the compiler's
+    # default scoped limit); the mapper plans blocks that fit it.
+    vmem_scoped_bytes: float = 16 * 2**20
     vpu_flops: float = 197e12 / 8     # non-MXU elementwise throughput
     # Weights-generator unit. 0.0 -> generation timeshares the main unit
     # (TPU MXU: t_gen serialises into the engine stage). > 0 -> dedicated
@@ -62,7 +65,8 @@ V5P = HW(name="v5p", peak_flops=459e12, hbm_bw=2765e9, ici_bw=100e9,
 # TPU v6e (Trillium): 918 TFLOP/s bf16, 32 GB HBM at 1640 GB/s, 4 ICI
 # links totalling ~3.58 Tbps one-way (Google Cloud "TPU v6e" docs).
 V6E = HW(name="v6e", peak_flops=918e12, hbm_bw=1640e9, ici_bw=112e9,
-         hbm_bytes=32e9, vmem_bytes=128 * 2**20, vpu_flops=918e12 / 8)
+         hbm_bytes=32e9, vmem_bytes=128 * 2**20,
+         vmem_scoped_bytes=32 * 2**20, vpu_flops=918e12 / 8)
 
 # Generic dual-socket AVX-512 server: ~2 TFLOP/s f32 across cores,
 # ~100 GB/s sustained DDR5 (STREAM-like), 32 MiB LLC standing in for
@@ -104,6 +108,35 @@ def resolve_hw(hw) -> HW:
     if isinstance(hw, HW):
         return hw
     return hw_by_name(hw)
+
+
+# ``jax.Device.device_kind`` -> the preset of that device. A device that is
+# not listed has no preset: that is an error, never a default.
+DEVICE_KINDS = {
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v6 lite": "v6e",
+}
+
+
+def hw_for_device(device) -> HW:
+    """The HW preset of a ``jax.Device``, looked up by its ``device_kind``."""
+    try:
+        return hw_by_name(DEVICE_KINDS[device.device_kind])
+    except KeyError:
+        raise KeyError(f"no HW preset for device kind {device.device_kind!r}; "
+                       f"known kinds: {sorted(DEVICE_KINDS)}") from None
+
+
+def check_hw_for_device(hw, device) -> None:
+    """On a TPU, planning for any target but the device's own is an error."""
+    if device.platform != "tpu":
+        return
+    want = hw_for_device(device).name
+    if resolve_hw(hw).name != want:
+        raise ValueError(f"--hw {resolve_hw(hw).name} does not match the "
+                         f"device ({device.device_kind!r} is {want!r})")
+
 
 BoundClass = Literal["IFM", "OFM", "W", "C"]
 

@@ -14,12 +14,14 @@ next to the static recovery in benchmarks/table10_balance.py.
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+from typing import Callable, Optional, Sequence
 
 import numpy as np
 
 
 BLOCK_MENU = (64, 128, 192, 256, 384, 512)
+LANE = 128      # last block dim: a multiple of this, or the whole dim
+SUBLANE = 8     # second-to-last block dim: a multiple of this, or whole
 
 
 def util(dim: int, block: int) -> float:
@@ -30,6 +32,15 @@ def util(dim: int, block: int) -> float:
 def gemm_utilisation(M: int, K: int, N: int,
                      bm: int, bk: int, bn: int) -> float:
     return util(M, bm) * util(K, bk) * util(N, bn)
+
+
+def legal_blocks(dim: int, menu: Sequence[int], align: int) -> list[int]:
+    """Menu sizes the chip's (8, 128) tiling accepts for one block dim: the
+    multiples of ``align``, plus the whole dim where it fits the menu."""
+    out = [b for b in menu if b % align == 0]
+    if dim <= max(menu) and dim not in out:
+        out.append(dim)
+    return out
 
 
 @dataclasses.dataclass
@@ -47,22 +58,35 @@ class BalanceChoice:
 
 def balance_blocks(M: int, K: int, N: int, *,
                    menu: Sequence[int] = BLOCK_MENU,
-                   vmem_limit: int = 96 * 2**20,
-                   dtype_bytes: int = 2) -> BalanceChoice:
-    """Pick (bm, bk, bn) maximising utilisation under the VMEM footprint
-    bm*bk + bk*bn + bm*bn <= limit. MXU wants every block a multiple of 128
-    where the dim allows; 64 is allowed for small dims (8x128 lanes)."""
+                   vmem_limit: int = 16 * 2**20,
+                   dtype_bytes: int = 2, n_align: int = LANE,
+                   footprint: Optional[Callable[[int, int, int], int]] = None
+                   ) -> BalanceChoice:
+    """Pick (bm, bk, bn) maximising utilisation under a VMEM footprint.
+
+    bm is a sublane dim (multiple of 8); bk and bn are lane dims (multiples
+    of 128, or of ``n_align`` for bn, unless the block is the whole dim).
+    ``footprint(bm, bk, bn)`` is the kernel's VMEM estimate; the default is
+    the double-buffered x, W and output tiles."""
+    if footprint is None:
+        def footprint(bm, bk, bn):
+            return (bm * bk + bk * bn + bm * bn) * dtype_bytes * 2
     naive = gemm_utilisation(M, K, N, 128, 128, 128)
-    best = (128, 128, 128, naive)
-    for bm in menu:
-        for bk in menu:
-            for bn in menu:
-                fp = (bm * bk + bk * bn + bm * bn) * dtype_bytes * 2  # dbl buf
-                if fp > vmem_limit:
+    best = None        # ties keep the 128^3 default where it is legal
+    if 128 in legal_blocks(N, menu, n_align) and footprint(128, 128, 128) \
+            <= vmem_limit:
+        best = (128, 128, 128, naive)
+    for bm in legal_blocks(M, menu, SUBLANE):
+        for bk in legal_blocks(K, menu, LANE):
+            for bn in legal_blocks(N, menu, n_align):
+                if footprint(bm, bk, bn) > vmem_limit:
                     continue
                 u = gemm_utilisation(M, K, N, bm, bk, bn)
-                if u > best[3] + 1e-12:
+                if best is None or u > best[3] + 1e-12:
                     best = (bm, bk, bn, u)
+    if best is None:
+        raise ValueError(f"no legal blocks for ({M}, {K}, {N}) fit "
+                         f"{vmem_limit} bytes of VMEM")
     return BalanceChoice(best[0], best[1], best[2], naive, best[3])
 
 

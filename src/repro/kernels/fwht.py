@@ -1,12 +1,16 @@
-"""Pallas TPU kernel: fast Walsh-Hadamard transform via the Kronecker two-matmul
-factorisation (MXU-native form of the FPGA OVSF generator's butterfly network).
+"""Pallas TPU kernel: fast Walsh-Hadamard transform as one MXU matmul per
+lane chunk plus butterflies across chunks (MXU-native form of the FPGA OVSF
+generator's butterfly network).
 
-H_L = H_La (x) H_Lb with L = La * Lb (both powers of two). For X viewed as
-(batch, La, Lb):  WHT_L(x) = H_La @ X @ H_Lb  (H symmetric), i.e. two MXU
-matmuls of shapes (La,La) and (Lb,Lb) instead of log2(L) VPU butterfly passes.
-The Hadamard factors are generated *in-register* from iota + bit-parity — no
-HBM traffic for the basis, which is the paper's core on-the-fly insight mapped
-to the TPU memory hierarchy (HBM->VMEM->VREG).
+H_L = H_La (x) H_Lb with L = La * Lb, Lb = min(L, 128) one lane tile. Index
+n = a * Lb + b splits into high bits a and low bits b, and the sign
+(-1)^popcount(n & m) factors over the two bit fields. So the transform is a
+(Lb, Lb) matmul on each Lb-wide lane chunk (the low bits) followed by
+log2(La) butterfly passes that add and subtract whole chunks (the high
+bits). Every slice is a static, lane-aligned one: no in-kernel reshape.
+The Hadamard factor is generated *in-register* from iota + bit-parity -- no
+HBM traffic for the basis, which is the paper's core on-the-fly insight
+mapped to the TPU memory hierarchy (HBM->VMEM->VREG).
 """
 from __future__ import annotations
 
@@ -14,67 +18,85 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.ovsf import next_pow2
+from repro.kernels.ovsf_gemm import DEFAULT_SCOPED_VMEM
+
+_LANES = 128
+# VMEM the row block may take: under the default scoped limit, with room
+# for the compiler's own scratch.
+_VMEM_BUDGET = DEFAULT_SCOPED_VMEM * 3 // 4
 
 
 def _iota_hadamard(n: int, dtype) -> jnp.ndarray:
     """(n, n) +-1 Sylvester-Hadamard built from iota + popcount parity."""
-    i = jax.lax.broadcasted_iota(jnp.uint32, (n, n), 0)
-    j = jax.lax.broadcasted_iota(jnp.uint32, (n, n), 1)
+    i = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
     x = i & j
-    # branch-free popcount parity
+    # branch-free popcount parity (non-negative int32: >> is logical)
     x = x ^ (x >> 16)
     x = x ^ (x >> 8)
     x = x ^ (x >> 4)
     x = x ^ (x >> 2)
     x = x ^ (x >> 1)
-    par = (x & jnp.uint32(1)).astype(jnp.int32)
-    return (1 - 2 * par).astype(dtype)
+    return (1 - 2 * (x & 1)).astype(dtype)
 
 
-def _split_factors(L: int) -> tuple[int, int]:
-    """L = La * Lb with both <= max(128, sqrt) to keep MXU operands square-ish."""
-    k = int(np.log2(L))
-    kb = (k + 1) // 2
-    return 1 << (k - kb), 1 << kb  # (La, Lb), Lb >= La
-
-
-def _fwht_kernel(x_ref, o_ref, *, La: int, Lb: int):
-    bm = x_ref.shape[0]
-    x = x_ref[...].astype(jnp.float32).reshape(bm, La, Lb)
-    Ha = _iota_hadamard(La, jnp.float32)
-    Hb = _iota_hadamard(Lb, jnp.float32)
-    # y[m,a,b] = sum_{a',b'} Ha[a,a'] Hb[b,b'] x[m,a',b']
-    y = jnp.einsum("mab,bc->mac", x, Hb, preferred_element_type=jnp.float32)
-    y = jnp.einsum("ea,mab->meb", Ha, y, preferred_element_type=jnp.float32)
-    o_ref[...] = y.reshape(bm, La * Lb).astype(o_ref.dtype)
+def _fwht_kernel(x_ref, o_ref, acc_ref, *, La: int, Lb: int):
+    # bf16 inputs times +-1 are exact products on the one-pass MXU path;
+    # fp32 inputs need the full-precision passes.
+    fp32 = x_ref.dtype == jnp.float32
+    H = _iota_hadamard(Lb, x_ref.dtype)
+    prec = jax.lax.Precision.HIGHEST if fp32 else jax.lax.Precision.DEFAULT
+    chunk = lambda a: slice(a * Lb, (a + 1) * Lb)
+    for a in range(La):                     # low bits: MXU, per lane chunk
+        acc_ref[:, chunk(a)] = jnp.dot(x_ref[:, chunk(a)], H, precision=prec,
+                                       preferred_element_type=jnp.float32)
+    h = 1
+    while h < La:                           # high bits: chunk butterflies
+        for a in range(La):
+            if a & h:
+                continue
+            u = acc_ref[:, chunk(a)]
+            v = acc_ref[:, chunk(a + h)]
+            acc_ref[:, chunk(a)] = u + v
+            acc_ref[:, chunk(a + h)] = u - v
+        h *= 2
+    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
 def fwht_pallas(x: jnp.ndarray, *, block_m: int = 256, interpret: bool = False
                 ) -> jnp.ndarray:
-    """WHT along the last axis of (..., L); L must be a power of two."""
+    """WHT along the last axis of (..., L); L must be a power of two.
+
+    The row block is at most ``block_m`` and at most what fits the VMEM
+    budget: the input and output blocks (two buffers each) plus the fp32
+    accumulator, L wide."""
     orig_shape = x.shape
     L = orig_shape[-1]
     if L & (L - 1):
         raise ValueError(f"FWHT length must be a power of two, got {L}")
-    La, Lb = _split_factors(L)
+    Lb = min(L, _LANES)
     xf = x.reshape(-1, L)
     M = xf.shape[0]
-    bm = min(block_m, M)
+    row_bytes = L * (4 * x.dtype.itemsize + 4)
+    bm_vmem = max(8, _VMEM_BUDGET // row_bytes // 8 * 8)
+    bm = min(block_m, bm_vmem, -(-M // 8) * 8)
     pad = (-M) % bm
     if pad:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))
     Mp = xf.shape[0]
     out = pl.pallas_call(
-        functools.partial(_fwht_kernel, La=La, Lb=Lb),
+        functools.partial(_fwht_kernel, La=L // Lb, Lb=Lb),
         grid=(Mp // bm,),
         in_specs=[pl.BlockSpec((bm, L), lambda m: (m, 0))],
         out_specs=pl.BlockSpec((bm, L), lambda m: (m, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, L), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, L), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xf)
     return out[:M].reshape(orig_shape)

@@ -27,97 +27,109 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.ovsf import next_pow2, unpack_int4
+from repro.core.ovsf import next_pow2
 
 
-def _sign_tile(idx_col: jnp.ndarray, j0: jnp.ndarray, k0: jnp.ndarray,
-               bk: int, seg: int, n_keep: int) -> jnp.ndarray:
-    """(bj, bk) +-1 Hadamard sign tile.
+def _sign_tile(codes: jnp.ndarray, j0, k0, bk: int, seg: int,
+               n_keep: int) -> jnp.ndarray:
+    """(bk, bj) +-1 Hadamard tile S^T, with k on sublanes and j on lanes.
 
-    Monolithic (seg == 0): S[j, k] = (-1)^popcount(idx[j] & (k0+k)).
-    Segmented (seg == L0): codes only touch their own length-L0 segment
-    (block-diagonal basis, paper Alg. 1):
-      S[j, k] = (-1)^popcount(idx[j] & ((k0+k) % L0)) * [seg(j0+j) == seg(k0+k)]
-    Built entirely from iota + bitwise ops — the on-chip OVSF generator.
+    Monolithic (seg == 0): S^T[k, j] = (-1)^popcount(idx[j] & (k0+k)).
+    Segmented (seg == L0, a power of two): codes only touch their own
+    length-L0 segment (block-diagonal basis, paper Alg. 1):
+      S^T[k, j] = (-1)^popcount(idx[j] & ((k0+k) % L0))
+                  * [j0+j in rows n_keep*seg(k0+k) .. n_keep*(seg(k0+k)+1)-1]
+    Built entirely from iota + bitwise ops -- the on-chip OVSF generator.
+    ``codes`` is the (1, bj) row of code ids. Every operand is a
+    non-negative int32 below 2**31, so arithmetic shifts act as logical
+    ones, and no integer division is needed.
     """
-    bj = idx_col.shape[0]
-    codes = idx_col.astype(jnp.uint32)                                # (bj, 1)
-    cols = (k0.astype(jnp.uint32)
-            + jax.lax.broadcasted_iota(jnp.uint32, (bj, bk), 1))      # (bj, bk)
-    kk = cols % jnp.uint32(seg) if seg else cols
-    x = codes & kk
+    bj = codes.shape[-1]
+    ks = k0 + jax.lax.broadcasted_iota(jnp.int32, (bk, bj), 0)
+    x = codes & (ks & (seg - 1) if seg else ks)
     x = x ^ (x >> 16)
     x = x ^ (x >> 8)
     x = x ^ (x >> 4)
     x = x ^ (x >> 2)
     x = x ^ (x >> 1)
-    par = (x & jnp.uint32(1)).astype(jnp.int32)
-    s = (1 - 2 * par).astype(jnp.float32)
+    s = (1 - 2 * (x & 1)).astype(jnp.float32)
     if seg:
-        rows = (j0.astype(jnp.uint32)
-                + jax.lax.broadcasted_iota(jnp.uint32, (bj, bk), 0))
-        same = (rows // jnp.uint32(n_keep)) == (cols // jnp.uint32(seg))
-        s = jnp.where(same, s, 0.0)
+        js = j0 + jax.lax.broadcasted_iota(jnp.int32, (bk, bj), 1)
+        first = (ks >> (seg.bit_length() - 1)) * n_keep
+        s = jnp.where((js >= first) & (js < first + n_keep), s, 0.0)
     return s
 
 
-def _dequant_tile(al_c: jnp.ndarray, scale_c: jnp.ndarray,
-                  quant: str) -> jnp.ndarray:
-    """Fused dequant epilogue: int8 / packed-int4 alpha tile -> fp32.
+def _unpack_tile(al_c: jnp.ndarray, quant: str) -> jnp.ndarray:
+    """Quantised alpha chunk -> fp32 integer values (scales are applied to
+    the sign tile instead, see ``_gen_w_tile``).
 
-    This runs *inside* the generator loop on the (bj, bn) tile just DMA'd
-    from HBM — the quantised bytes are what crossed the memory wall; fp32
-    alphas exist only tile-at-a-time in VMEM. scale_c is the per-row
-    (segment-expanded) fp32 scale column.
+    int4 nibbles unpack as ``[low nibbles | high nibbles]`` along lanes, so
+    each bn-wide tile comes out with its even columns first and its odd
+    columns second; ``_untile_int4`` restores the order outside the kernel.
     """
-    al = unpack_int4(al_c) if quant == "int4" else al_c
-    return al.astype(jnp.float32) * scale_c.astype(jnp.float32)
+    if quant == "int4":
+        p32 = al_c.astype(jnp.int32)
+        lo = p32 & 0xF
+        lo = lo - jnp.where(lo >= 8, 16, 0)
+        al_c = jnp.concatenate([lo, p32 >> 4], axis=-1)
+    return al_c.astype(jnp.float32)
 
 
-def _gen_w_tile(idx_ref, alpha_ref, k: jnp.ndarray, *, bk: int, bj: int,
+def _gen_w_tile(idx_ref, alpha_ref, k: jnp.ndarray, *, bk: int,
                 seg: int = 0, n_keep: int = 0, scale_ref=None,
                 quant: str = "") -> jnp.ndarray:
-    """Generate the (bk, bn) weight tile for k-block ``k`` from alphas in VMEM.
+    """Generate the (bk, bn) fp32 weight tile for k-block ``k``.
 
-    With ``quant`` set, ``alpha_ref`` holds int8 (or int4-packed-in-int8)
-    coefficients and ``scale_ref`` the per-row fp32 scales; each chunk is
-    dequantised in-register right before its MXU contraction.
+    ``idx_ref`` is (n_chunks, 1, bj) code ids and ``alpha_ref`` the
+    (n_chunks, bj, bn_store) alpha block; the loop walks the leading chunk
+    axis, which needs no tiled dynamic slice. With ``quant`` set,
+    ``alpha_ref`` holds int8 (or int4-packed-in-int8) coefficients and
+    ``scale_ref`` (n_chunks, 1, bj) their per-row fp32 scales, folded into
+    the sign tile's columns right before the MXU contraction.
     """
-    J = idx_ref.shape[0]
-    bn_store = alpha_ref.shape[1]
-    bn = 2 * bn_store if quant == "int4" else bn_store
+    n_chunks, bj, _ = alpha_ref.shape
+    bn = 2 * alpha_ref.shape[2] if quant == "int4" else alpha_ref.shape[2]
     k0 = k * bk
-    n_chunks = J // bj
 
     def body(c, acc):
-        j0 = c * bj
-        idx_c = jax.lax.dynamic_slice(idx_ref[...], (j0, 0), (bj, 1))
-        al_c = jax.lax.dynamic_slice(alpha_ref[...], (j0, 0), (bj, bn_store))
+        st = _sign_tile(idx_ref[c], c * bj, k0, bk, seg, n_keep)     # (bk, bj)
+        al_c = alpha_ref[c]                                          # (bj, bn)
         if quant:
-            sc_c = jax.lax.dynamic_slice(scale_ref[...], (j0, 0), (bj, 1))
-            al_c = _dequant_tile(al_c, sc_c, quant)
-        else:
-            al_c = al_c.astype(jnp.float32)
-        S = _sign_tile(idx_c, j0, k0, bk, seg, n_keep)                 # (bj, bk)
-        return acc + jax.lax.dot_general(
-            S, al_c, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                        # (bk, bn)
+            st = st * scale_ref[c]
+            al_c = _unpack_tile(al_c, quant)
+        elif al_c.dtype != jnp.float32:
+            st = st.astype(al_c.dtype)  # +-1 is exact; products stay exact
+        return acc + _dot(st, al_c)
 
-    acc0 = jnp.zeros((bk, bn), jnp.float32)
-    return jax.lax.fori_loop(0, n_chunks, body, acc0)
+    return jax.lax.fori_loop(0, n_chunks, body,
+                             jnp.zeros((bk, bn), jnp.float32))
+
+
+def _dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """MXU matmul with fp32 accumulation; fp32 operands keep fp32 accuracy."""
+    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    return jnp.dot(a, b, precision=prec, preferred_element_type=jnp.float32)
 
 
 def _row_scales(alpha_scale, J: int, bj: int) -> jnp.ndarray:
-    """(n_seg,)/(n_seg,1) per-segment scales -> padded (Jp, 1) per-row fp32.
+    """(n_seg,)/(n_seg,1) per-segment scales -> (n_chunks, 1, bj) per-row fp32.
 
-    J fp32 values — 1/d_out of the alpha buffer; negligible HBM traffic next
+    J fp32 values -- 1/d_out of the alpha buffer; negligible HBM traffic next
     to the int8 stream it describes."""
     s = jnp.asarray(alpha_scale, jnp.float32).reshape(-1)
     if s.shape[0] <= 0 or J % s.shape[0]:
         raise ValueError(
             f"alpha_scale has {s.shape[0]} segments; J={J} not divisible")
     rows = jnp.repeat(s, J // s.shape[0])
-    return _pad1(rows, bj).reshape(-1, 1)
+    return _pad1(rows, bj).reshape(-1, 1, bj)
+
+
+def _untile_int4(out: jnp.ndarray, bn: int) -> jnp.ndarray:
+    """Undo ``_unpack_tile``'s per-tile [even | odd] column order."""
+    R, N = out.shape
+    return out.reshape(R, N // bn, 2, bn // 2).swapaxes(2, 3).reshape(R, N)
 
 
 # ---------------------------------------------------------------------------
@@ -125,7 +137,7 @@ def _row_scales(alpha_scale, J: int, bj: int) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 def _ovsf_gemm_kernel(idx_ref, x_ref, alpha_ref, *rest,
-                      bk: int, bj: int, nk: int, seg: int, n_keep: int,
+                      bk: int, nk: int, seg: int, n_keep: int,
                       quant: str = ""):
     if quant:
         scale_ref, o_ref, acc_ref = rest
@@ -138,13 +150,10 @@ def _ovsf_gemm_kernel(idx_ref, x_ref, alpha_ref, *rest,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_tile = _gen_w_tile(idx_ref, alpha_ref, k, bk=bk, bj=bj, seg=seg,
+    w_tile = _gen_w_tile(idx_ref, alpha_ref, k, bk=bk, seg=seg,
                          n_keep=n_keep, scale_ref=scale_ref,
                          quant=quant)                                  # (bk, bn)
-    x_tile = x_ref[...].astype(jnp.float32)                            # (bm, bk)
-    acc_ref[...] += jax.lax.dot_general(
-        x_tile, w_tile, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(x_ref[...].astype(jnp.float32), w_tile)
 
     @pl.when(k == nk - 1)
     def _flush():
@@ -169,8 +178,13 @@ def ovsf_gemm(x: jnp.ndarray, alphas: jnp.ndarray, idx: jnp.ndarray, *,
     storage form ((J, d_out) int8 or (J, d_out//2) nibble-packed int8) and
     ``alpha_scale`` the per-segment scales; the generator loop dequantises
     each tile in-register right before its S^T @ alpha contraction, so the
-    quantised bytes are all that streams from HBM — fp32 alphas are never
+    quantised bytes are all that streams from HBM -- fp32 alphas are never
     materialised.
+
+    Compiled (not interpreted), the blocks must fit the chip's (8, 128)
+    tiling: ``block_m`` a multiple of 8, ``block_k`` and ``block_n`` a
+    multiple of 128 (256 for int4) unless they cover the whole dimension.
+    ``runtime.mapper`` plans blocks that do.
     """
     quant = alpha_dtype
     if quant not in ("", "int8", "int4"):
@@ -180,42 +194,38 @@ def ovsf_gemm(x: jnp.ndarray, alphas: jnp.ndarray, idx: jnp.ndarray, *,
     M, d_in = x.shape
     J = alphas.shape[0]
     d_out = alphas.shape[1] * (2 if quant == "int4" else 1)
-    seg = 0
-    keep = 0
-    if idx.ndim == 2:
-        ns, keep = idx.shape
-        seg = d_in // ns
-        idx = idx.reshape(-1)
-        if seg and block_k % seg:
-            block_k = max((block_k // seg) * seg, seg)
+    seg, keep, idx, block_k = _code_layout(idx, d_in, block_k)
     bm = min(block_m, _ceil_mult(M, 8))
     bn = min(block_n, d_out)
     if quant == "int4" and bn % 2:
         bn += 1
     bk = min(block_k, d_in)
     bj = min(block_j, _ceil_mult(J, 8))
-
-    xp = _pad2(x, bm, bk)
-    alp = _pad2(alphas, bj, bn // 2 if quant == "int4" else bn)
-    idxp = _pad1(idx.astype(jnp.int32), bj).reshape(-1, 1)
-    Mp, Kp = xp.shape
-    Jp = alp.shape[0]
-    Np = alp.shape[1] * (2 if quant == "int4" else 1)
-    nk = Kp // bk
     bn_store = bn // 2 if quant == "int4" else bn
 
-    operands = [idxp, xp, alp]
+    xp = _pad2(x, bm, bk)
+    alp = _pad2(alphas, bj, bn_store)
+    Mp, Kp = xp.shape
+    Jp, Np_store = alp.shape
+    Np = Np_store * (2 if quant == "int4" else 1)
+    nk = Kp // bk
+    nc = Jp // bj
+
+    operands = [_pad1(idx.astype(jnp.int32), bj).reshape(nc, 1, bj), xp,
+                alp.reshape(nc, bj, Np_store)]
     in_specs = [
-        pl.BlockSpec((Jp, 1), lambda m, n, k: (0, 0)),        # idx (whole)
-        pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),       # x
-        pl.BlockSpec((Jp, bn_store), lambda m, n, k: (0, n)), # alphas
+        pl.BlockSpec((nc, 1, bj), lambda m, n, k: (0, 0, 0)),     # idx (whole)
+        pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),           # x
+        pl.BlockSpec((nc, bj, bn_store), lambda m, n, k: (0, 0, n)),  # alphas
     ]
     if quant:
         operands.append(_row_scales(alpha_scale, J, bj))
-        in_specs.append(pl.BlockSpec((Jp, 1), lambda m, n, k: (0, 0)))
+        in_specs.append(pl.BlockSpec((nc, 1, bj), lambda m, n, k: (0, 0, 0)))
 
+    vmem = gemm_vmem_bytes(bm, bk, bn, bj, J, x_dtype=x.dtype,
+                           alpha_dtype=alphas.dtype, quant=quant)
     out = pl.pallas_call(
-        functools.partial(_ovsf_gemm_kernel, bk=bk, bj=bj, nk=nk, seg=seg,
+        functools.partial(_ovsf_gemm_kernel, bk=bk, nk=nk, seg=seg,
                           n_keep=keep, quant=quant),
         grid=(Mp // bm, Np // bn, nk),
         in_specs=in_specs,
@@ -225,10 +235,12 @@ def ovsf_gemm(x: jnp.ndarray, alphas: jnp.ndarray, idx: jnp.ndarray, *,
         # (m, n) grid dims are independent output tiles; only the k-loop
         # carries the accumulator. Declaring this lets the Mosaic pipeline
         # parallelise/overlap across m/n while keeping k sequential.
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(("parallel", "parallel",
+                                          "arbitrary"), vmem),
         interpret=interpret,
     )(*operands)
+    if quant == "int4":
+        out = _untile_int4(out, bn)
     return out[:M, :d_out]
 
 
@@ -236,7 +248,7 @@ def ovsf_gemm(x: jnp.ndarray, alphas: jnp.ndarray, idx: jnp.ndarray, *,
 # Weight-stationary decompression (generate once, reuse)
 # ---------------------------------------------------------------------------
 
-def _decompress_kernel(idx_ref, alpha_ref, *rest, bk: int, bj: int,
+def _decompress_kernel(idx_ref, alpha_ref, *rest, bk: int,
                        seg: int, n_keep: int, quant: str = ""):
     if quant:
         scale_ref, o_ref = rest
@@ -244,7 +256,7 @@ def _decompress_kernel(idx_ref, alpha_ref, *rest, bk: int, bj: int,
         (o_ref,) = rest
         scale_ref = None
     k = pl.program_id(0)
-    o_ref[...] = _gen_w_tile(idx_ref, alpha_ref, k, bk=bk, bj=bj, seg=seg,
+    o_ref[...] = _gen_w_tile(idx_ref, alpha_ref, k, bk=bk, seg=seg,
                              n_keep=n_keep, scale_ref=scale_ref,
                              quant=quant).astype(o_ref.dtype)
 
@@ -268,55 +280,116 @@ def ovsf_decompress(alphas: jnp.ndarray, idx: jnp.ndarray, *, d_in: int,
         raise ValueError("ovsf_decompress: alpha_scale required")
     J = alphas.shape[0]
     d_out = alphas.shape[1] * (2 if quant == "int4" else 1)
-    seg = 0
-    keep = 0
-    if idx.ndim == 2:
-        ns, keep = idx.shape
-        seg = d_in // ns
-        idx = idx.reshape(-1)
-        if seg and block_k % seg:
-            block_k = max((block_k // seg) * seg, seg)
+    seg, keep, idx, block_k = _code_layout(idx, d_in, block_k)
     L = next_pow2(d_in)
     bk = min(block_k, L if not seg else d_in)
     bn = min(block_n, d_out)
     if quant == "int4" and bn % 2:
         bn += 1
     bj = min(block_j, _ceil_mult(J, 8))
-
-    alp = _pad2(alphas, bj, bn // 2 if quant == "int4" else bn)
-    idxp = _pad1(idx.astype(jnp.int32), bj).reshape(-1, 1)
-    Jp = alp.shape[0]
-    Np = alp.shape[1] * (2 if quant == "int4" else 1)
-    Kp = _round_up(d_in, bk)
     bn_store = bn // 2 if quant == "int4" else bn
 
-    operands = [idxp, alp]
+    alp = _pad2(alphas, bj, bn_store)
+    Jp, Np_store = alp.shape
+    Np = Np_store * (2 if quant == "int4" else 1)
+    Kp = _round_up(d_in, bk)
+    nc = Jp // bj
+    out_dtype = jnp.float32 if quant else alphas.dtype
+
+    operands = [_pad1(idx.astype(jnp.int32), bj).reshape(nc, 1, bj),
+                alp.reshape(nc, bj, Np_store)]
     in_specs = [
-        pl.BlockSpec((Jp, 1), lambda k, n: (0, 0)),
-        pl.BlockSpec((Jp, bn_store), lambda k, n: (0, n)),
+        pl.BlockSpec((nc, 1, bj), lambda k, n: (0, 0, 0)),
+        pl.BlockSpec((nc, bj, bn_store), lambda k, n: (0, 0, n)),
     ]
     if quant:
         operands.append(_row_scales(alpha_scale, J, bj))
-        in_specs.append(pl.BlockSpec((Jp, 1), lambda k, n: (0, 0)))
+        in_specs.append(pl.BlockSpec((nc, 1, bj), lambda k, n: (0, 0, 0)))
 
+    vmem = (2 * nc * _tile_bytes(bj, bn_store, alphas.dtype)
+            + 2 * nc * _tile_bytes(1, bj, jnp.int32) * (2 if quant else 1)
+            + 2 * _tile_bytes(bk, bn, out_dtype)
+            + _gen_temp_bytes(bk, bj, bn))
     out = pl.pallas_call(
-        functools.partial(_decompress_kernel, bk=bk, bj=bj, seg=seg,
+        functools.partial(_decompress_kernel, bk=bk, seg=seg,
                           n_keep=keep, quant=quant),
         grid=(Kp // bk, Np // bn),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bk, bn), lambda k, n: (k, n)),
-        out_shape=jax.ShapeDtypeStruct(
-            (Kp, Np), jnp.float32 if quant else alphas.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        out_shape=jax.ShapeDtypeStruct((Kp, Np), out_dtype),
+        compiler_params=_compiler_params(("parallel", "parallel"), vmem),
         interpret=interpret,
     )(*operands)
+    if quant == "int4":
+        out = _untile_int4(out, bn)
     return out[:d_in, :d_out]
 
 
 # ---------------------------------------------------------------------------
 # helpers
 # ---------------------------------------------------------------------------
+
+# The compiler's scoped-VMEM limit when a kernel asks for none (TPU v5e; the
+# later generations allow more). A kernel whose buffers need more raises its
+# own limit, up to what the chip holds with room for the compiler's scratch.
+DEFAULT_SCOPED_VMEM = 16 * 2**20
+MAX_SCOPED_VMEM = 100 * 2**20
+
+
+def _compiler_params(semantics: tuple, vmem_bytes: int):
+    """Mosaic parameters; raises the VMEM limit only where the estimate of
+    the kernel's buffers does not fit the default."""
+    limit = None
+    if vmem_bytes > DEFAULT_SCOPED_VMEM * 3 // 4:
+        limit = min(MAX_SCOPED_VMEM, _round_up(vmem_bytes * 3 // 2, 2**20))
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
+
+
+def gemm_vmem_bytes(bm: int, bk: int, bn: int, bj: int, J: int, *, x_dtype,
+                    alpha_dtype, quant: str = "") -> int:
+    """VMEM estimate of one ``ovsf_gemm`` grid step: x, the whole-J alpha
+    column block and the code ids (two buffers each), the output block (two
+    buffers), the accumulator and the generator's temporaries. The mapper
+    budgets blocks with it; the kernel raises its VMEM limit from it."""
+    nc = _ceil_mult(J, bj) // bj
+    bn_store = bn // 2 if quant == "int4" else bn
+    return (2 * _tile_bytes(bm, bk, x_dtype)
+            + 2 * nc * _tile_bytes(bj, bn_store, alpha_dtype)
+            + 2 * nc * _tile_bytes(1, bj, jnp.int32) * (2 if quant else 1)
+            + 2 * _tile_bytes(bm, bn, x_dtype)
+            + _tile_bytes(bm, bn, jnp.float32)
+            + _gen_temp_bytes(bk, bj, bn))
+
+
+def _tile_bytes(rows: int, cols: int, dtype) -> int:
+    """VMEM bytes of a (rows, cols) buffer padded to whole (8k, 128) tiles."""
+    it = jnp.dtype(dtype).itemsize
+    sub = 8 * max(4 // it, 1)                # 8 rows fp32, 16 bf16, 32 int8
+    return _round_up(rows, sub) * _round_up(cols, 128) * it
+
+
+def _gen_temp_bytes(bk: int, bj: int, bn: int) -> int:
+    """fp32 temporaries of one generator step: the sign tile, the unpacked
+    alpha chunk, the loop carry and its update."""
+    f32 = jnp.float32
+    return (_tile_bytes(bk, bj, f32) + _tile_bytes(bj, bn, f32)
+            + 2 * _tile_bytes(bk, bn, f32))
+
+
+def _code_layout(idx: jnp.ndarray, d_in: int, block_k: int):
+    """(seg, n_keep, flat idx, block_k) for monolithic (J,) or segmented
+    (n_seg, n_keep) code ids; a segmented k-block covers whole segments."""
+    if idx.ndim != 2:
+        return 0, 0, idx, block_k
+    ns, keep = idx.shape
+    seg = d_in // ns
+    if seg & (seg - 1):
+        raise ValueError(f"segment length {seg} is not a power of two")
+    if block_k % seg:
+        block_k = max((block_k // seg) * seg, seg)
+    return seg, keep, idx.reshape(-1), block_k
+
 
 def _round_up(n: int, b: int) -> int:
     return ((n + b - 1) // b) * b

@@ -73,6 +73,7 @@ import time
 import jax
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry as R
 from repro.runtime.faults import DIE_EXIT_CODE, FaultPlan
 from repro.serving import (HealthPolicy, ModelRegistry, RequestJournal,
@@ -672,6 +673,7 @@ def main(argv=None) -> None:
         _supervised_main(args, list(sys.argv[1:] if argv is None else argv))
         return
 
+    enable_compile_cache()
     models = parse_models(args.models)
     names = [alias for _, alias, _ in models]
     budget = (None if args.alpha_budget_mb is None

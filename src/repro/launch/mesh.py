@@ -9,13 +9,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit-Auto axis types where the installed
-    jax supports them (``jax.sharding.AxisType`` landed after 0.4.x; older
-    versions treat every axis as Auto implicitly)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(at.Auto,) * len(shape))
+    """``jax.make_mesh`` with every axis explicitly Auto-sharded."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,8 +22,10 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests / smoke runs)."""
+    """(data, model) mesh over this host's devices; asking for more devices
+    than exist is an error, never a smaller mesh."""
     n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, max(n // data, 1))
+    if data * model > n:
+        raise ValueError(f"mesh data={data} x model={model} needs "
+                         f"{data * model} devices; {n} exist")
     return make_mesh((data, model), ("data", "model"))

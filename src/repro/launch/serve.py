@@ -47,13 +47,16 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry as R
 from repro.runtime.faults import FaultPlan
 from repro.serving import (LLMEngine, Request, RequestJournal, SamplingParams,
                            hw_names)
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> LLMEngine | None:
+    """Serve the requests; returns the drained engine (None when the run
+    was handed to a supervised child)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -131,8 +134,9 @@ def main(argv=None) -> None:
                              "without a journal loses every live request")
         supervise("repro.launch.serve",
                   [a for a in raw if a != "--supervise"])
-        return
+        return None
 
+    print(f"[serve] compile cache: {enable_compile_cache()}")
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.alpha_dtype:
         if not cfg.ovsf.enable:
@@ -271,6 +275,7 @@ def main(argv=None) -> None:
     if missing or bad:
         raise SystemExit(f"[serve] FAILED: unfinished={missing} "
                          f"unexpected={bad}")
+    return eng
 
 
 if __name__ == "__main__":

@@ -20,13 +20,14 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.data.synthetic import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import registry as R
 from repro.runtime import supervisor
 from repro.train import optim, steps
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> supervisor.RunReport:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -45,13 +46,13 @@ def main(argv=None) -> None:
                          "restore (verification is the default)")
     args = ap.parse_args(argv)
 
+    print(f"[train] compile cache: {enable_compile_cache()}")
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_local_mesh(args.data_par, args.model_par)
     print(f"[train] {cfg.name}: mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
-    key = jax.random.PRNGKey(args.seed)
-    state = steps.train_state_init(key, cfg)
-    n_params = R.param_count(state["params"])
+    state_specs = steps.train_state_specs(cfg)
+    n_params = R.param_count(state_specs["params"])
     print(f"[train] params: {n_params/1e6:.1f}M")
 
     ocfg = optim.OptConfig(lr=args.lr, warmup_steps=max(args.steps // 20, 5),
@@ -66,11 +67,12 @@ def main(argv=None) -> None:
         extra["image_embeds"] = np.zeros((args.batch, n_img, cfg.d_model),
                                          np.dtype(cfg.dtype))
     batch0.update(extra)
-    state_specs = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
     fn, state_sh, batch_sh = steps.jit_train_step(cfg, ocfg, mesh,
                                                   state_specs, batch0)
-    state = jax.device_put(state, state_sh)
+    # Born sharded: each device makes only its own shards of the state, so
+    # no device ever holds the whole of it.
+    state = jax.jit(functools.partial(steps.train_state_init, cfg=cfg),
+                    out_shardings=state_sh)(jax.random.PRNGKey(args.seed))
 
     stream = TokenStream(cfg.vocab, args.seq, args.batch, seed=args.seed)
 
@@ -87,6 +89,7 @@ def main(argv=None) -> None:
                                    state_shardings=state_sh)
     print(f"[train] done: steps={report.steps_run} failures={report.failures} "
           f"first loss={report.losses[0]:.4f} last loss={report.losses[-1]:.4f}")
+    return report
 
 
 if __name__ == "__main__":

@@ -38,8 +38,11 @@ import math
 import re
 from typing import Optional, Sequence
 
+import jax.numpy as jnp
+
 from repro.hwmodel import perf_model as pm
 from repro.hwmodel import tile_balance as tb
+from repro.kernels.ovsf_gemm import gemm_vmem_bytes
 
 
 DEFAULT_PATHS = ("materialize", "fused")
@@ -158,7 +161,7 @@ def classify_gemm(M: int, d_in: int, d_out: int, rho: float, *,
                          alpha_dtype=alpha_dtype if rho < 1.0 else "")
     if not layer.ovsf:
         blocks = tb.balance_blocks(M, d_in, d_out,
-                                   vmem_limit=int(hw.vmem_bytes * 0.75))
+                                   vmem_limit=int(hw.vmem_scoped_bytes))
         t = pm.layer_timing(layer, hw)
         return LayerPlan("materialize", block_m=blocks.bm, block_n=blocks.bn,
                          block_k=blocks.bk, cache_weights=False,
@@ -179,12 +182,24 @@ def classify_gemm(M: int, d_in: int, d_out: int, rho: float, *,
             f"produced a non-finite modeled II; check the perf model / "
             f"calibration factors for hw={hw.name!r}")
 
-    # DSE block search over the consumer GEMM of the chosen path. The
+    # DSE block search over the consumer GEMM of the chosen path, in blocks
+    # the chip's tiling accepts and the fused kernel's VMEM estimate fits
+    # (int4 packs two columns a byte: its stored lane block is bn // 2). The
     # spectral path contracts over J (= rho * d_in) instead of d_in.
     k_eff = layer.j_total if best_path == "spectral" else d_in
-    blocks = tb.balance_blocks(M, k_eff, d_out,
-                               vmem_limit=int(hw.vmem_bytes * 0.75))
     bj = min(128, _ceil8(layer.j_total))
+    quant = layer.alpha_dtype
+    store = {"": jnp.bfloat16, "int8": jnp.int8, "int4": jnp.int8}[quant]
+
+    def footprint(bm, bk, bn):
+        return gemm_vmem_bytes(bm, bk, bn, bj, layer.j_total,
+                               x_dtype=jnp.bfloat16, alpha_dtype=store,
+                               quant=quant)
+
+    blocks = tb.balance_blocks(
+        M, k_eff, d_out, vmem_limit=int(hw.vmem_scoped_bytes),
+        n_align=2 * tb.LANE if quant == "int4" else tb.LANE,
+        footprint=footprint if best_path == "fused" else None)
     bk = blocks.bk
     if seg and bk % seg:
         bk = max((bk // seg) * seg, seg)

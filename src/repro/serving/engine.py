@@ -38,7 +38,10 @@ Fault tolerance (see ``docs/serving.md`` "Failure semantics"):
   seen) requeues every live slot recompute-style, rebuilds
   :class:`EngineCore` (fused step fns are lru-cached per config — no
   recompile), and carries the fault-plan step index forward. No in-flight
-  request is lost, only delayed.
+  request is lost, only delayed. A failure that recurs — after
+  ``MAX_RECOVERIES_WITHOUT_TOKEN`` rebuilds with no token committed in
+  between, as with a kernel the compiler refuses or a device OOM — is
+  re-raised instead of replayed forever.
 * **Deadlines + load shedding** — ``Request.deadline_s`` expires queued and
   running requests as ``FINISH_TIMEOUT``; a bounded waiting queue
   (``max_waiting``) sheds the least-urgent request as ``FINISH_SHED``, and
@@ -66,9 +69,11 @@ import time
 import warnings
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.hwmodel.perf_model import check_hw_for_device
 from repro.runtime.faults import FaultPlan
 from repro.serving.api import (FINISH_CANCELLED, FINISH_EOS, FINISH_ERROR,
                                FINISH_LENGTH,
@@ -81,6 +86,10 @@ from repro.serving.scheduler import (FCFSScheduler, SchedulerOutput,
 
 __all__ = ["LLMEngine", "EngineStats", "Request",
            "SamplingParams", "RequestOutput"]
+
+# Watchdog rebuilds allowed in a row with no token committed between them;
+# the next step failure is re-raised.
+MAX_RECOVERIES_WITHOUT_TOKEN = 3
 
 
 @dataclasses.dataclass
@@ -161,6 +170,7 @@ class LLMEngine:
                  model_label: Optional[str] = None,
                  journal=None):
         self._base_cfg = cfg
+        check_hw_for_device(hw, jax.devices()[0])
         self.hw = hw
         self.hw_label = resolve_hw(hw).name
         # Multi-model mode: variants = stacked-alpha variant count of the
@@ -249,6 +259,7 @@ class LLMEngine:
         # non-durable (the default). A broken journal degrades silently to
         # None-like behaviour — it never blocks the step loop.
         self.journal = journal
+        self._recoveries_since_token = 0
 
     # The fused decode+sample callable; kept assignable for instrumentation.
     @property
@@ -442,6 +453,8 @@ class LLMEngine:
             with self._ops.weight_cache_scope(self.model_label):
                 out = self.core.step(so, last)
         except Exception:               # watchdog: step crashed — recover
+            if self._recoveries_since_token >= MAX_RECOVERIES_WITHOUT_TOKEN:
+                raise                   # it recurs: surface, do not replay
             self._recover()
             return self._remaining()
         # Stall watchdog: measure around the core call (injected/organic
@@ -667,12 +680,15 @@ class LLMEngine:
         self.core.prefill_compiles = old.prefill_compiles
         self.core.step_shapes = old.step_shapes
         self.stats.recoveries += 1
+        self._recoveries_since_token += 1
 
     def _remaining(self) -> int:
         return (sum(s is not None for s in self.slots)
                 + len(self.scheduler))
 
     def _commit(self, so: SchedulerOutput, out: StepOutput) -> None:
+        if out.first_tokens or out.decode_tokens:
+            self._recoveries_since_token = 0
         for c in so.chunks:
             self._prefill_done[c.slot] += c.length
         self.stats.chunk_tokens += sum(c.length for c in so.chunks)

@@ -25,17 +25,6 @@ from repro.configs.base import ModelConfig
 from repro.models import transformer as T
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` where available; 0.4.x experimental API otherwise
-    (which spells the replication check ``check_rep``, not ``check_vma``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _apply_local_layers(blocks_local, cfg: ModelConfig, x, positions):
     """Run this stage's slice of the layer stack (scan, like _scan_stack)."""
     def body(carry, pp):
@@ -89,7 +78,8 @@ def gpipe_apply(mesh: Mesh, cfg: ModelConfig, stacked_blocks, x,
         jax.tree_util.tree_map(lambda _: P(axis), stacked_blocks),
         P(),
     )
-    fn = _shard_map(stage_fn, mesh=mesh, in_specs=in_specs, out_specs=P())
+    fn = jax.shard_map(stage_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(), check_vma=False)
     xm = x.reshape(n_micro, mb, S, d)
     outs = fn(stacked_blocks, xm)
     return outs.reshape(B, S, d)

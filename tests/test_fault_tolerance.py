@@ -307,6 +307,19 @@ def test_injected_step_failure_recovers_with_identical_streams(tiny):
         assert outs[rid].tokens == toks0[rid]
 
 
+def test_recurring_step_failure_surfaces_after_recovery_bound(tiny):
+    # A failure on every step (a kernel the compiler refuses, a device OOM)
+    # must surface, not rebuild the core and replay forever.
+    from repro.serving.engine import MAX_RECOVERIES_WITHOUT_TOKEN
+    cfg, params = tiny
+    eng = LLMEngine(params, cfg, batch_slots=4, buffer_len=64, chunk_size=8,
+                    faults=FaultPlan.parse(["fail:step=0,every=1"]))
+    eng.submit(_req(0, 10, max_new=6, vocab=cfg.vocab))
+    with pytest.raises(InjectedFault):
+        eng.run_until_drained()
+    assert eng.stats.recoveries == MAX_RECOVERIES_WITHOUT_TOKEN
+
+
 def test_combined_nan_and_failure_chaos(tiny):
     # The full acceptance scenario: NaN at step 3 AND a crash at step 7.
     cfg, params = tiny

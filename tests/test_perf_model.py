@@ -1,4 +1,5 @@
 """Paper §5 performance model + §6.2 autotuning + §4.3 balancer tests."""
+import collections
 import dataclasses
 
 import pytest
@@ -108,6 +109,18 @@ def test_input_selective_model_bounds():
     g = tb.input_selective_speedup(T_R=64, T_C=128, C=64, P=1024, T_P=64)
     assert 1.0 <= g <= 2.1
     assert tb.input_selective_speedup(64, 128, 128, 1024, 64) == 1.0
+
+
+def test_hw_preset_follows_device_kind():
+    Dev = collections.namedtuple("Dev", "platform device_kind")
+    v5e = Dev("tpu", "TPU v5 lite")
+    assert pm.hw_for_device(v5e) is pm.V5E
+    with pytest.raises(KeyError, match="TPU v9"):
+        pm.hw_for_device(Dev("tpu", "TPU v9"))   # unknown: never a default
+    pm.check_hw_for_device("v5e", v5e)
+    with pytest.raises(ValueError, match="does not match"):
+        pm.check_hw_for_device("v6e", v5e)
+    pm.check_hw_for_device("v5e", Dev("cpu", "cpu"))   # plans for any target
 
 
 def test_dse_prunes_infeasible():

@@ -74,3 +74,14 @@ def test_no_fsdp_replicates_weights():
         for entry in spec:
             names = entry if isinstance(entry, tuple) else (entry,)
             assert not any(n in daxes for n in names if n), spec
+
+
+def test_local_mesh_refuses_more_devices_than_exist():
+    from repro.launch.mesh import make_local_mesh
+    n = len(jax.devices())
+    mesh = make_local_mesh(n, 1)
+    assert mesh.devices.size == n
+    with pytest.raises(ValueError, match="devices"):
+        make_local_mesh(n + 1, 1)
+    with pytest.raises(ValueError, match="devices"):
+        make_local_mesh(1, 2 * n)
