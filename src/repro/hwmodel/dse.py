@@ -61,8 +61,7 @@ def explore(cfg, shape, *, hw: pm.HW = pm.V5E, n_devices: int = 256,
             continue
         t = pm.model_timing(layers, hw).total_s
         l0 = max(layers, key=lambda l: l.M * l.d_in * l.d_out)
-        blocks = tb.balance_blocks(l0.M, l0.d_in, l0.d_out,
-                                   vmem_limit=int(hw.vmem_scoped_bytes))
+        blocks = tb.balance_blocks(l0.M, l0.d_in, l0.d_out)
         mem = hbm_per_device(c, n_devices, tp, train=train,
                              cache_bytes=cache_bytes)
         out.append(DesignPoint(path, tp, blocks, t, mem <= hw.hbm_bytes, mem))

@@ -40,9 +40,6 @@ class HW:
     ici_bw: float = 50e9              # B/s per link
     hbm_bytes: float = 16e9
     vmem_bytes: float = 128 * 2**20
-    # VMEM a Pallas kernel gets when it asks for no limit (the compiler's
-    # default scoped limit); the mapper plans blocks that fit it.
-    vmem_scoped_bytes: float = 16 * 2**20
     vpu_flops: float = 197e12 / 8     # non-MXU elementwise throughput
     # Weights-generator unit. 0.0 -> generation timeshares the main unit
     # (TPU MXU: t_gen serialises into the engine stage). > 0 -> dedicated
@@ -65,8 +62,7 @@ V5P = HW(name="v5p", peak_flops=459e12, hbm_bw=2765e9, ici_bw=100e9,
 # TPU v6e (Trillium): 918 TFLOP/s bf16, 32 GB HBM at 1640 GB/s, 4 ICI
 # links totalling ~3.58 Tbps one-way (Google Cloud "TPU v6e" docs).
 V6E = HW(name="v6e", peak_flops=918e12, hbm_bw=1640e9, ici_bw=112e9,
-         hbm_bytes=32e9, vmem_bytes=128 * 2**20,
-         vmem_scoped_bytes=32 * 2**20, vpu_flops=918e12 / 8)
+         hbm_bytes=32e9, vmem_bytes=128 * 2**20, vpu_flops=918e12 / 8)
 
 # Generic dual-socket AVX-512 server: ~2 TFLOP/s f32 across cores,
 # ~100 GB/s sustained DDR5 (STREAM-like), 32 MiB LLC standing in for

@@ -22,6 +22,10 @@ import numpy as np
 BLOCK_MENU = (64, 128, 192, 256, 384, 512)
 LANE = 128      # last block dim: a multiple of this, or the whole dim
 SUBLANE = 8     # second-to-last block dim: a multiple of this, or whole
+# VMEM a Pallas kernel gets when it asks for no limit: the compiler's default
+# scoped limit on TPU v5e, the least of the supported chips. Blocks are
+# planned to fit it; a kernel whose buffers need more raises its own limit.
+SCOPED_VMEM = 16 * 2**20
 
 
 def util(dim: int, block: int) -> float:
@@ -58,7 +62,7 @@ class BalanceChoice:
 
 def balance_blocks(M: int, K: int, N: int, *,
                    menu: Sequence[int] = BLOCK_MENU,
-                   vmem_limit: int = 16 * 2**20,
+                   vmem_limit: int = SCOPED_VMEM,
                    dtype_bytes: int = 2, n_align: int = LANE,
                    footprint: Optional[Callable[[int, int, int], int]] = None
                    ) -> BalanceChoice:

@@ -21,12 +21,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ovsf_gemm import DEFAULT_SCOPED_VMEM
+from repro.hwmodel.tile_balance import SCOPED_VMEM
 
 _LANES = 128
 # VMEM the row block may take: under the default scoped limit, with room
 # for the compiler's own scratch.
-_VMEM_BUDGET = DEFAULT_SCOPED_VMEM * 3 // 4
+_VMEM_BUDGET = SCOPED_VMEM * 3 // 4
 
 
 def _iota_hadamard(n: int, dtype) -> jnp.ndarray:

@@ -28,6 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.ovsf import next_pow2
+from repro.hwmodel.tile_balance import SCOPED_VMEM
 
 
 def _sign_tile(codes: jnp.ndarray, j0, k0, bk: int, seg: int,
@@ -329,18 +330,16 @@ def ovsf_decompress(alphas: jnp.ndarray, idx: jnp.ndarray, *, d_in: int,
 # helpers
 # ---------------------------------------------------------------------------
 
-# The compiler's scoped-VMEM limit when a kernel asks for none (TPU v5e; the
-# later generations allow more). A kernel whose buffers need more raises its
-# own limit, up to what the chip holds with room for the compiler's scratch.
-DEFAULT_SCOPED_VMEM = 16 * 2**20
+# The most a kernel raises its VMEM limit to: what the chip holds, with room
+# for the compiler's scratch.
 MAX_SCOPED_VMEM = 100 * 2**20
 
 
 def _compiler_params(semantics: tuple, vmem_bytes: int):
     """Mosaic parameters; raises the VMEM limit only where the estimate of
-    the kernel's buffers does not fit the default."""
+    the kernel's buffers does not fit the default ``SCOPED_VMEM``."""
     limit = None
-    if vmem_bytes > DEFAULT_SCOPED_VMEM * 3 // 4:
+    if vmem_bytes > SCOPED_VMEM * 3 // 4:
         limit = min(MAX_SCOPED_VMEM, _round_up(vmem_bytes * 3 // 2, 2**20))
     return pltpu.CompilerParams(dimension_semantics=semantics,
                                 vmem_limit_bytes=limit)

@@ -54,6 +54,18 @@ from repro.serving import (LLMEngine, Request, RequestJournal, SamplingParams,
                            hw_names)
 
 
+def synthetic_prompts(n: int, buffer: int, vocab: int,
+                      seed: int) -> list[np.ndarray]:
+    """The launcher's ``n`` request prompts: 4 .. buffer/4 random ids each,
+    made from ``seed``."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        plen = int(rng.integers(4, buffer // 4))
+        out.append(rng.integers(0, vocab, plen, dtype=np.int32))
+    return out
+
+
 def main(argv=None) -> LLMEngine | None:
     """Serve the requests; returns the drained engine (None when the run
     was handed to a supervised child)."""
@@ -182,10 +194,9 @@ def main(argv=None) -> LLMEngine | None:
         ndone = sum(1 for e in journal.entries.values() if e.done)
         print(f"[serve] journal: {len(recovered)} live request(s) recovered "
               f"mid-stream, {ndone} already terminal (replayed, not re-run)")
-    rng = np.random.default_rng(args.seed)
-    for rid in range(args.requests):
-        plen = int(rng.integers(4, args.buffer // 4))
-        prompt = rng.integers(0, cfg.vocab, plen, dtype=np.int32)
+    prompts = synthetic_prompts(args.requests, args.buffer, cfg.vocab,
+                                args.seed)
+    for rid, prompt in enumerate(prompts):
         if journal is not None and rid in journal.entries:
             continue    # journaled before the crash: recovered or terminal
         admitted, bp = eng.add_request(Request(

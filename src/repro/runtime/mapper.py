@@ -160,8 +160,7 @@ def classify_gemm(M: int, d_in: int, d_out: int, rho: float, *,
                          alphas_resident=alphas_resident,
                          alpha_dtype=alpha_dtype if rho < 1.0 else "")
     if not layer.ovsf:
-        blocks = tb.balance_blocks(M, d_in, d_out,
-                                   vmem_limit=int(hw.vmem_scoped_bytes))
+        blocks = tb.balance_blocks(M, d_in, d_out)
         t = pm.layer_timing(layer, hw)
         return LayerPlan("materialize", block_m=blocks.bm, block_n=blocks.bn,
                          block_k=blocks.bk, cache_weights=False,
@@ -197,8 +196,7 @@ def classify_gemm(M: int, d_in: int, d_out: int, rho: float, *,
                                quant=quant)
 
     blocks = tb.balance_blocks(
-        M, k_eff, d_out, vmem_limit=int(hw.vmem_scoped_bytes),
-        n_align=2 * tb.LANE if quant == "int4" else tb.LANE,
+        M, k_eff, d_out, n_align=2 * tb.LANE if quant == "int4" else tb.LANE,
         footprint=footprint if best_path == "fused" else None)
     bk = blocks.bk
     if seg and bk % seg:

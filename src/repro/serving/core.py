@@ -423,6 +423,7 @@ class EngineCore:
         self.keys = np.array(
             np.broadcast_to(np.asarray(jax.random.PRNGKey(0)),
                             (batch_slots, 2)))
+        self.seeded = np.zeros(batch_slots, bool)   # keys[i] is its request's
 
         alloc_len = self.T_alloc
 
@@ -457,6 +458,7 @@ class EngineCore:
         # exactly where the unpreempted run would be
         self.keys[i] = (np.asarray(resume_key) if resume_key is not None
                         else np.asarray(jax.random.PRNGKey(sp.seed)))
+        self.seeded[i] = True
 
     def clear_sampling(self, i: int) -> None:
         """Reset a freed slot to greedy defaults (the next request re-seeds
@@ -465,6 +467,7 @@ class EngineCore:
         self.temps[i] = 0.0
         self.topks[i] = 0
         self.greedy[i] = True
+        self.seeded[i] = False
 
     def _sample(self, logits: jnp.ndarray) -> np.ndarray:
         """Sample (B,) tokens from (B, V) logits; advances NO keys itself —

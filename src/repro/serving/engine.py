@@ -38,10 +38,11 @@ Fault tolerance (see ``docs/serving.md`` "Failure semantics"):
   seen) requeues every live slot recompute-style, rebuilds
   :class:`EngineCore` (fused step fns are lru-cached per config — no
   recompile), and carries the fault-plan step index forward. No in-flight
-  request is lost, only delayed. A failure that recurs — after
-  ``MAX_RECOVERIES_WITHOUT_TOKEN`` rebuilds with no token committed in
+  request is lost, only delayed. A step failure that recurs — after
+  ``MAX_RECOVERIES_WITHOUT_TOKEN`` failed steps with no token committed in
   between, as with a kernel the compiler refuses or a device OOM — is
-  re-raised instead of replayed forever.
+  re-raised instead of replayed forever; a gateway then fails the replica
+  over.
 * **Deadlines + load shedding** — ``Request.deadline_s`` expires queued and
   running requests as ``FINISH_TIMEOUT``; a bounded waiting queue
   (``max_waiting``) sheds the least-urgent request as ``FINISH_SHED``, and
@@ -87,8 +88,9 @@ from repro.serving.scheduler import (FCFSScheduler, SchedulerOutput,
 __all__ = ["LLMEngine", "EngineStats", "Request",
            "SamplingParams", "RequestOutput"]
 
-# Watchdog rebuilds allowed in a row with no token committed between them;
-# the next step failure is re-raised.
+# Step failures recovered in a row with no token committed between them;
+# the next one is re-raised. Stall rebuilds follow a step that succeeded
+# and do not count.
 MAX_RECOVERIES_WITHOUT_TOKEN = 3
 
 
@@ -259,7 +261,7 @@ class LLMEngine:
         # non-durable (the default). A broken journal degrades silently to
         # None-like behaviour — it never blocks the step loop.
         self.journal = journal
-        self._recoveries_since_token = 0
+        self._failures_since_token = 0
 
     # The fused decode+sample callable; kept assignable for instrumentation.
     @property
@@ -453,8 +455,9 @@ class LLMEngine:
             with self._ops.weight_cache_scope(self.model_label):
                 out = self.core.step(so, last)
         except Exception:               # watchdog: step crashed — recover
-            if self._recoveries_since_token >= MAX_RECOVERIES_WITHOUT_TOKEN:
+            if self._failures_since_token >= MAX_RECOVERIES_WITHOUT_TOKEN:
                 raise                   # it recurs: surface, do not replay
+            self._failures_since_token += 1
             self._recover()
             return self._remaining()
         # Stall watchdog: measure around the core call (injected/organic
@@ -548,6 +551,8 @@ class LLMEngine:
         (failover ``adopt``), or nowhere."""
         req = self.slots[i]
         self.slots[i] = None
+        if self.core.seeded[i]:     # else the step that binds it failed
+            req.resume_key = np.array(self.core.keys[i])
         self.core.clear_sampling(i)
         if self.core.pager is not None:
             self.core.pager.release(i)  # victim pages free immediately
@@ -562,7 +567,6 @@ class LLMEngine:
             req.prompt = np.concatenate(
                 [np.asarray(req.prompt, np.int32),
                  np.asarray(new_tail, np.int32)])
-        req.resume_key = np.array(self.core.keys[i])
         return req
 
     def _requeue_slot(self, i: int, *, preempt: bool) -> None:
@@ -680,7 +684,6 @@ class LLMEngine:
         self.core.prefill_compiles = old.prefill_compiles
         self.core.step_shapes = old.step_shapes
         self.stats.recoveries += 1
-        self._recoveries_since_token += 1
 
     def _remaining(self) -> int:
         return (sum(s is not None for s in self.slots)
@@ -688,7 +691,7 @@ class LLMEngine:
 
     def _commit(self, so: SchedulerOutput, out: StepOutput) -> None:
         if out.first_tokens or out.decode_tokens:
-            self._recoveries_since_token = 0
+            self._failures_since_token = 0
         for c in so.chunks:
             self._prefill_done[c.slot] += c.length
         self.stats.chunk_tokens += sum(c.length for c in so.chunks)

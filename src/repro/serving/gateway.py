@@ -61,16 +61,18 @@ exactly as many step shapes as ONE chunked engine.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import dataclasses
 import itertools
 import json
 import threading
+import time
 from typing import Any, Callable, Optional
 
 import numpy as np
 
-from repro.serving.api import (FINISH_EVICTED, FINISH_TIMEOUT, Request,
-                               RequestOutput, SamplingParams)
+from repro.serving.api import (FINISH_ERROR, FINISH_EVICTED, FINISH_TIMEOUT,
+                               Request, RequestOutput, SamplingParams)
 from repro.serving.journal import body_fingerprint
 from repro.serving.engine import LLMEngine
 from repro.serving.health import (DEAD, HEALTHY, CircuitBreaker, HealthPolicy,
@@ -129,6 +131,8 @@ class ReplicaSet:
     engines: list
     health: list
     snapshots: list
+    replaced: set = dataclasses.field(default_factory=set)  # replacement
+                                    # replicas built by failover
 
     def alive(self) -> list:
         return [r for r, e in enumerate(self.engines) if e is not None]
@@ -405,7 +409,14 @@ class ServingGateway:
             eng = rs.engines[r]
             if eng is None:
                 continue                # already failed over this iteration
-            eng.step()
+            try:
+                eng.step()
+            except Exception:
+                # the engine re-raises only a step failure that recurred
+                # across its own watchdog rebuilds: the replica is DEAD
+                # whatever its incident points
+                self._failover(g, r)
+                continue
             self._health_tick(g, r)
         self._rr = (self._rr + 1) % n
         return self.pending
@@ -446,9 +457,17 @@ class ServingGateway:
         """Drain DEAD replica ``r`` and re-route its in-flight requests to
         surviving replicas via the recompute path (token-identical resume).
         The last replica of a group gets a fresh replacement instead —
-        losing every replica must not strand admitted work."""
+        losing every replica must not strand admitted work. A replacement
+        that dies before committing a token shows the fault is not the
+        replica's (a kernel the compiler refuses, a device OOM): that is
+        raised, not rebuilt forever."""
         rs = self._groups[group]
         eng = rs.engines[r]
+        if (rs.alive() == [r] and r in rs.replaced
+                and eng.stats.tokens_out == 0):
+            raise RuntimeError(
+                f"replica {eng.model_label!r} failed again after it was "
+                f"replaced, before committing a token")
         rs.engines[r] = None
         self.stats.replicas_dead += 1
         self.stats.failovers += 1
@@ -460,6 +479,7 @@ class ServingGateway:
             rs.engines[r] = self._make_replica(group, r, with_faults=False)
             rs.health[r] = ReplicaHealth(self.health_policy)
             rs.snapshots[r] = {attr: 0 for _k, attr in _INCIDENTS}
+            rs.replaced.add(r)
         for req in reqs:
             t = self._pick_replica(rs)
             self._routes[id(req)] = (group, t)
@@ -686,8 +706,9 @@ class GatewayHTTPServer:
     (a string prompt is mapped deterministically onto ids via char codes
     modulo the model's vocab). The engine pump runs in ONE background
     thread — engines are not thread-safe, so intake (``add_request``),
-    cancellation, and stepping share ``self._lock``; token/finish
-    callbacks hop back into the event loop via ``call_soon_threadsafe``.
+    cancellation, and stepping share ``self._lock``, and the pump lets a
+    waiting handler in between steps; token/finish callbacks hop back into
+    the event loop via ``call_soon_threadsafe``.
 
     ``breaker_after > 0`` arms a per-model :class:`CircuitBreaker`:
     ``breaker_after`` consecutive FINISH_ERROR completions trip the model
@@ -733,8 +754,11 @@ class GatewayHTTPServer:
         self.draining = False
         self.drained: Optional[asyncio.Event] = None
         self._lock = threading.Lock()
+        self._waiters = 0               # handlers queued on _lock
+        self._waiters_lock = threading.Lock()
         self._stop = threading.Event()
         self._pump_thread: Optional[threading.Thread] = None
+        self.failure: Optional[BaseException] = None    # the pump's, if any
         self._server: Optional[asyncio.AbstractServer] = None
         self.loop: Optional[asyncio.AbstractEventLoop] = None
         self._rids = itertools.count()
@@ -766,23 +790,64 @@ class GatewayHTTPServer:
             await self._server.wait_closed()
 
     async def serve_forever(self) -> None:
-        async with self._server:
-            await self._server.serve_forever()
+        """Serve until stopped; raises if the step loop failed."""
+        if self.failure is None:
+            try:
+                async with self._server:
+                    await self._server.serve_forever()
+            except asyncio.CancelledError:
+                if self.failure is None:
+                    raise
+        if self.failure is not None:
+            raise RuntimeError("gateway step loop failed") from self.failure
 
     def _pump(self) -> None:
         """Background step loop: drains the pool whenever any engine has
         work; idles on a short wait otherwise. Completes the graceful
         drain: once draining is requested and the pool is empty, the
-        ``drained`` event fires (the launcher exits 0 on it)."""
-        while not self._stop.is_set():
+        ``drained`` event fires (the launcher exits 0 on it). A step
+        failure the gateway could not fail over closes the server: nothing
+        would advance the requests it went on accepting."""
+        try:
+            while not self._stop.is_set():
+                with self._lock:
+                    pending = self.gateway.pending
+                    work = self.gateway.step() if pending else 0
+                while self._waiters:    # stepping back to back would
+                    time.sleep(1e-4)    # re-take the lock first
+                if self.draining and not work and not pending:
+                    self.loop.call_soon_threadsafe(self.drained.set)
+                    return
+                if not work:
+                    self._stop.wait(0.002)
+        except Exception as e:
+            self.failure = e            # serve_forever re-raises it
+            self.loop.call_soon_threadsafe(self._fail_live)
+
+    @contextlib.contextmanager
+    def _locked(self):
+        """Hold the pool lock from a handler thread, ahead of the pump."""
+        with self._waiters_lock:
+            self._waiters += 1
+        try:
             with self._lock:
-                pending = self.gateway.pending
-                work = self.gateway.step() if pending else 0
-            if self.draining and not work and not pending:
-                self.loop.call_soon_threadsafe(self.drained.set)
-                return
-            if not work:
-                self._stop.wait(0.002)
+                yield
+        finally:
+            with self._waiters_lock:
+                self._waiters -= 1
+
+    def _fail_live(self) -> None:
+        """The step loop failed: stop accepting, and finish every waiting
+        connection with an error (loop thread only)."""
+        self._server.close()
+        for rid, rec in self._records.items():
+            if rec["out"] is None and rec["queues"]:
+                out = RequestOutput(rid=rid, prompt_len=0,
+                                    tokens=tuple(rec["tokens"]),
+                                    finish_reason=FINISH_ERROR)
+                for q in rec["queues"]:
+                    q.put_nowait(("fin", out))
+                rec["queues"] = []
 
     # -- durability: journal restore + token fan-out -------------------------
 
@@ -836,7 +901,7 @@ class GatewayHTTPServer:
             req.stream = on_tok
             req.on_finish = on_fin
 
-        with self._lock:
+        with self._locked():
             return len(self.gateway.recover_from_journal(wire=wire))
 
     def _record(self, rid: int) -> dict:
@@ -1122,7 +1187,7 @@ class GatewayHTTPServer:
             on_finish=on_fin)
 
         def _add():
-            with self._lock:
+            with self._locked():
                 return self.gateway.add_request(req)
 
         try:
@@ -1244,7 +1309,7 @@ class GatewayHTTPServer:
                 return                  # attached retry: just detach below
 
             def _cancel():
-                with self._lock:
+                with self._locked():
                     return self.gateway.cancel(req)
             await self.loop.run_in_executor(None, _cancel)
         finally:
@@ -1272,7 +1337,7 @@ class GatewayHTTPServer:
                                      code="invalid_request_error")
 
         def _add():
-            with self._lock:
+            with self._locked():
                 return self.gateway.add_model(name, cfg, loader, tags=tags)
 
         try:
@@ -1289,7 +1354,7 @@ class GatewayHTTPServer:
 
     async def _admin_remove(self, writer, name: str) -> None:
         def _remove():
-            with self._lock:
+            with self._locked():
                 return self.gateway.remove_model(name)
 
         try:
@@ -1307,7 +1372,7 @@ class GatewayHTTPServer:
         """Graceful drain: stop admitting, let the pump finish live work,
         then fire ``drained`` (the launcher awaits it and exits 0)."""
         self.draining = True
-        with self._lock:
+        with self._locked():
             pending = self.gateway.pending
         if pending == 0:
             # pump may already be parked; don't make the caller wait on it

@@ -307,6 +307,25 @@ def test_injected_step_failure_recovers_with_identical_streams(tiny):
         assert outs[rid].tokens == toks0[rid]
 
 
+def test_failure_in_admitting_step_keeps_sampled_streams(tiny):
+    # A step that fails while binding new requests has not seeded their
+    # keys: recovery must resume them from their own seed.
+    cfg, params = tiny
+    sp = SamplingParams(temperature=0.8, top_k=20, seed=7)
+
+    def run(faults=None):
+        eng = LLMEngine(params, cfg, batch_slots=2, buffer_len=64,
+                        chunk_size=8, faults=faults)
+        for rid in range(2):
+            eng.submit(_req(rid, 10, max_new=6, vocab=cfg.vocab, sampling=sp))
+        return eng, _drain_tokens(eng)
+
+    _, toks0 = run()
+    eng, toks = run(FaultPlan.parse(["fail:step=0"]))
+    assert eng.stats.recoveries == 1
+    assert toks == toks0
+
+
 def test_recurring_step_failure_surfaces_after_recovery_bound(tiny):
     # A failure on every step (a kernel the compiler refuses, a device OOM)
     # must surface, not rebuild the core and replay forever.
@@ -318,6 +337,33 @@ def test_recurring_step_failure_surfaces_after_recovery_bound(tiny):
     with pytest.raises(InjectedFault):
         eng.run_until_drained()
     assert eng.stats.recoveries == MAX_RECOVERIES_WITHOUT_TOKEN
+
+
+def test_single_failure_after_stalls_still_recovers(tiny):
+    # Stall rebuilds follow steps that succeeded: they must not use up the
+    # bound that surfaces recurring step failures. Prompts of five chunks
+    # keep steps 1-3 in prefill, so no token is committed before step 4.
+    from repro.serving.engine import MAX_RECOVERIES_WITHOUT_TOKEN
+
+    def run(faults=None, **kw):
+        eng = LLMEngine(params, cfg, batch_slots=4, buffer_len=64,
+                        chunk_size=8, faults=faults, **kw)
+        for rid in range(4):
+            eng.submit(_req(rid, 40, max_new=6, vocab=cfg.vocab))
+        eng.run_until_drained()
+        return eng
+
+    cfg, params = tiny
+    toks0 = {o.rid: o.tokens for o in run().outputs()}
+    eng = run(FaultPlan.parse(["delay:step=1,s=0.3", "delay:step=2,s=0.3",
+                               "delay:step=3,s=0.3", "fail:step=4"]),
+              step_timeout_s=0.2)
+    assert eng.stats.stalls >= MAX_RECOVERIES_WITHOUT_TOKEN
+    assert eng.stats.recoveries > MAX_RECOVERIES_WITHOUT_TOKEN
+    outs = _outs(eng)
+    assert len(outs) == 4 and eng.stats.completed == 4
+    for rid in outs:
+        assert outs[rid].tokens == toks0[rid]
 
 
 def test_combined_nan_and_failure_chaos(tiny):

@@ -265,6 +265,50 @@ def test_single_replica_group_rebuilds_in_place(tiny):
         _dedicated_streams(cfg, base, var, cfg.vocab)
 
 
+def test_recurring_replica_fault_fails_over_past_engine_bound(tiny):
+    """A fault on every step of one replica surfaces from its engine after
+    the engine's recovery bound, before the health points reach a high
+    ``dead_after``: the gateway fails that replica over, losing nothing."""
+    cfg, base, var = tiny
+    plan = FaultPlan.parse(["fail:step=0,every=1"], seed=0)
+    gw = ServingGateway(_registry(cfg, base, var), batch_slots=4,
+                        buffer_len=64, chunk_size=8, hw="cpu",
+                        faults={"m-a": plan}, replicas=2,
+                        health=HealthPolicy(degraded_after=1, dead_after=10))
+    for r in _mixed_requests(cfg.vocab):
+        assert gw.add_request(r)[0]
+    gw.run_until_drained()
+    assert gw.stats.failovers == 1 and gw.stats.replicas_dead == 1
+    outs = {o.rid: o for o in gw.outputs()}
+    assert len(outs) == 6                            # ZERO lost requests
+    for o in outs.values():
+        assert o.finish_reason in ("eos", "length"), o
+    assert {rid: tuple(o.tokens) for rid, o in outs.items()} == \
+        _dedicated_streams(cfg, base, var, cfg.vocab)
+    assert DEAD in gw.health_of("m-a")
+
+
+def _always_failing_step(monkeypatch):
+    from repro.serving.core import EngineCore
+
+    def step(self, so, last):
+        raise RuntimeError("step refused")
+    monkeypatch.setattr(EngineCore, "step", step)
+
+
+def test_failure_recurring_on_replacement_surfaces(tiny, monkeypatch):
+    """A failure no replica survives (a kernel the compiler refuses) is
+    raised once a fresh replacement hits it too, not rebuilt forever."""
+    cfg, base, var = tiny
+    gw = ServingGateway(_registry(cfg, base, var), batch_slots=4,
+                        buffer_len=64, chunk_size=8, hw="cpu", replicas=1)
+    _always_failing_step(monkeypatch)
+    assert gw.add_request(_req(0, 5, cfg.vocab, model="m-a"))[0]
+    with pytest.raises(RuntimeError, match="replaced"):
+        gw.run_until_drained()
+    assert gw.stats.failovers == 1 and gw.stats.replicas_built == 2
+
+
 # ---------------------------------------------------------------------------
 # Gateway scrub cadence: injected flip detected + repaired mid-traffic
 # ---------------------------------------------------------------------------
@@ -568,6 +612,34 @@ def test_http_drain_stops_admission_and_finishes_live_work(tiny):
             assert resp["choices"][0]["finish_reason"] in ("eos", "length")
             await asyncio.wait_for(srv.drained.wait(), timeout=30)
             assert gw.pending == 0
+        finally:
+            await srv.stop()
+
+    asyncio.run(drive())
+
+
+def test_http_step_loop_failure_stops_serving(tiny, monkeypatch):
+    """When the step loop fails, waiting clients get an error finish, the
+    server stops accepting and serve_forever raises: it must not go on
+    accepting requests nothing will advance."""
+    cfg, base, var = tiny
+    gw = ServingGateway(_registry(cfg, base, var), batch_slots=2,
+                        buffer_len=64, chunk_size=8, hw="cpu")
+    _always_failing_step(monkeypatch)
+
+    async def drive():
+        srv = GatewayHTTPServer(gw, port=0)
+        await srv.start()
+        try:
+            st, body, _ = await asyncio.wait_for(_call(
+                srv.host, srv.port, "POST", "/v1/completions",
+                {"model": "m-a", "prompt": [3, 1, 4], "max_tokens": 4}),
+                timeout=60)
+            assert st == 200
+            assert body["choices"][0]["finish_reason"] == "error"
+            with pytest.raises(RuntimeError, match="step loop failed"):
+                await asyncio.wait_for(srv.serve_forever(), timeout=60)
+            assert "replaced" in str(srv.failure)
         finally:
             await srv.stop()
 

@@ -27,11 +27,11 @@ ROWS = 8                                    # decode step of 8 slots
 @pytest.fixture(scope="module")
 def one_chip():
     from jax.experimental import topologies
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — any failure to describe skips
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+    # libtpu is pinned in requirements.txt: failing to describe the chip is
+    # a broken installation, and fails these tests rather than skipping.
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
     # A compile for a described chip cannot be read back from the
     # persistent cache without one; keep these out of it.
     was = jax.config.jax_enable_compilation_cache
