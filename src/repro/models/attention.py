@@ -60,6 +60,7 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(B, S, H, hd).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def attn_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray, *,
                positions: jnp.ndarray,
                mode: str = "causal",                 # causal | bidir | cross
@@ -110,6 +111,7 @@ def attn_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray, *,
     return y, new_cache
 
 
+@jax.named_scope("attention")
 def attn_apply_packed(p: dict, cfg: ModelConfig, x: jnp.ndarray, *,
                       positions: jnp.ndarray, slot_ids: jnp.ndarray,
                       cache: dict,
@@ -166,6 +168,7 @@ def attn_apply_packed(p: dict, cfg: ModelConfig, x: jnp.ndarray, *,
     return y, {"k": ck, "v": cv}
 
 
+@jax.named_scope("attention")
 def attn_apply_paged(p: dict, cfg: ModelConfig, x: jnp.ndarray, *,
                      positions: jnp.ndarray, slot_ids: jnp.ndarray,
                      page_table: jnp.ndarray,
@@ -219,6 +222,7 @@ def attn_apply_paged(p: dict, cfg: ModelConfig, x: jnp.ndarray, *,
     return y, {"k": ck, "v": cv}
 
 
+@jax.named_scope("attention")
 def cross_attn_packed(p: dict, cfg: ModelConfig, x: jnp.ndarray, *,
                       slot_ids: jnp.ndarray, cache: dict,
                       mids: Optional[jnp.ndarray] = None) -> jnp.ndarray:

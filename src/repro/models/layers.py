@@ -67,23 +67,24 @@ def linear_apply(p: dict, x: jnp.ndarray, cfg: ModelConfig,
     per-token variant when the alpha bank is stacked (M, J, d_out) — the
     multi-model gateway's same-architecture batching; dense and unstacked
     OVSF leaves are variant-shared and ignore it."""
-    if "alphas" in p or "alphas_q8" in p or "alphas_q4" in p:
-        al, scale, adt = ovsf.alpha_params(p)
-        plan = layer_plan(cfg, name)
-        if mids is not None and al.ndim == 3:
-            y = kops.ovsf_matmul_multi(x, al, p["idx"], mids,
-                                       alpha_scale=scale, alpha_dtype=adt)
-        elif plan is not None:
-            y = kops.ovsf_matmul(x, al, p["idx"], plan=plan,
-                                 alpha_scale=scale, alpha_dtype=adt)
+    with jax.named_scope(f"linear.{name}" if name else "linear"):
+        if "alphas" in p or "alphas_q8" in p or "alphas_q4" in p:
+            al, scale, adt = ovsf.alpha_params(p)
+            plan = layer_plan(cfg, name)
+            if mids is not None and al.ndim == 3:
+                y = kops.ovsf_matmul_multi(x, al, p["idx"], mids,
+                                           alpha_scale=scale, alpha_dtype=adt)
+            elif plan is not None:
+                y = kops.ovsf_matmul(x, al, p["idx"], plan=plan,
+                                     alpha_scale=scale, alpha_dtype=adt)
+            else:
+                y = kops.ovsf_matmul(x, al, p["idx"], path=cfg.ovsf.exec_path,
+                                     alpha_scale=scale, alpha_dtype=adt)
         else:
-            y = kops.ovsf_matmul(x, al, p["idx"], path=cfg.ovsf.exec_path,
-                                 alpha_scale=scale, alpha_dtype=adt)
-    else:
-        y = x @ p["w"].astype(x.dtype)
-    if "b" in p:
-        y = y + p["b"].astype(y.dtype)
-    return y
+            y = x @ p["w"].astype(x.dtype)
+        if "b" in p:
+            y = y + p["b"].astype(y.dtype)
+        return y
 
 
 def linear_convert_to_ovsf(p: dict, rho: float, strategy: str = "iterative",
@@ -137,6 +138,7 @@ def embed_init(key: jax.Array, vocab: int, d: int, dtype) -> dict:
     return {"table": jax.random.normal(key, (vocab, d), dtype) * 0.02}
 
 
+@jax.named_scope("embed")
 def embed_apply(p: dict, tokens: jnp.ndarray) -> jnp.ndarray:
     return jnp.take(p["table"], tokens, axis=0)
 

@@ -104,6 +104,7 @@ MOE_GROUP = 1024   # tokens per routing group; aligned to data shards for
                    # train shapes so queue-position cumsums stay shard-local.
 
 
+@jax.named_scope("moe")
 def moe_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray
               ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """x: (B, S, d) -> (y, aux_loss). Grouped top-k dispatch with capacity."""
@@ -118,35 +119,42 @@ def moe_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray
     G = xt.shape[0] // g
     xg = xt.reshape(G, g, d)
 
-    logits = jnp.einsum("gtd,de->gte", xg,
-                        p["router"]["w"].astype(xg.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                     # (G, g, E)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)               # (G, g, k)
-    gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True) + 1e-9)
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("gtd,de->gte", xg, p["router"]["w"].astype(
+            xg.dtype)).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                 # (G, g, E)
+        gate_vals, gate_idx = jax.lax.top_k(probs, k)           # (G, g, k)
+        gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True)
+                                 + 1e-9)
 
-    cap = max(int(np.ceil(cfg.capacity_factor * k * g / E)), 1)
-    # queue position of each (token, choice) within its expert, per group
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)       # (G, g, k, E)
-    flat = onehot.reshape(G, g * k, E)
-    pos_all = jnp.cumsum(flat, axis=1) - flat                   # (G, g*k, E)
-    pos = jnp.sum(pos_all * flat, axis=-1).reshape(G, g, k)
-    keep = pos < cap
-    gate_vals = gate_vals * keep
+    with jax.named_scope("moe.dispatch"):
+        cap = max(int(np.ceil(cfg.capacity_factor * k * g / E)), 1)
+        # queue position of each (token, choice) within its expert, per
+        # group
+        onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)   # (G,g,k,E)
+        flat = onehot.reshape(G, g * k, E)
+        pos_all = jnp.cumsum(flat, axis=1) - flat               # (G,g*k,E)
+        pos = jnp.sum(pos_all * flat, axis=-1).reshape(G, g, k)
+        keep = pos < cap
+        gate_vals = gate_vals * keep
 
-    pos_oh = jax.nn.one_hot(jnp.where(keep, pos, cap), cap + 1,
-                            dtype=xg.dtype)[..., :cap]          # (G, g, k, cap)
-    oh = onehot.astype(xg.dtype)
-    disp = jnp.einsum("gtke,gtkc->gtec", oh, pos_oh)            # (G, g, E, cap)
-    comb = jnp.einsum("gtk,gtke,gtkc->gtec", gate_vals.astype(xg.dtype),
-                      oh, pos_oh)
+        pos_oh = jax.nn.one_hot(jnp.where(keep, pos, cap), cap + 1,
+                                dtype=xg.dtype)[..., :cap]      # (G,g,k,cap)
+        oh = onehot.astype(xg.dtype)
+        disp = jnp.einsum("gtke,gtkc->gtec", oh, pos_oh)        # (G,g,E,cap)
+        comb = jnp.einsum("gtk,gtke,gtkc->gtec", gate_vals.astype(xg.dtype),
+                          oh, pos_oh)
+        ex_in = jnp.einsum("gtec,gtd->gecd", disp, xg)          # (G,E,cap,d)
 
-    ex_in = jnp.einsum("gtec,gtd->gecd", disp, xg)              # (G, E, cap, d)
-    gg = _expert_matmul(p["gate"], ex_in, cfg, "expert_gate")
-    uu = _expert_matmul(p["up"], ex_in, cfg, "expert_up")
-    h = jax.nn.silu(gg.astype(jnp.float32)).astype(uu.dtype) * uu
-    ex_out = _expert_matmul(p["down"], h, cfg, "expert_down")   # (G, E, cap, d)
-    y = jnp.einsum("gtec,gecd->gtd", comb, ex_out).reshape(G * g, d)
-    y = y[:T].reshape(B, S, d)
+    with jax.named_scope("moe.experts"):
+        gg = _expert_matmul(p["gate"], ex_in, cfg, "expert_gate")
+        uu = _expert_matmul(p["up"], ex_in, cfg, "expert_up")
+        h = jax.nn.silu(gg.astype(jnp.float32)).astype(uu.dtype) * uu
+        ex_out = _expert_matmul(p["down"], h, cfg, "expert_down")
+
+    with jax.named_scope("moe.combine"):
+        y = jnp.einsum("gtec,gecd->gtd", comb, ex_out).reshape(G * g, d)
+        y = y[:T].reshape(B, S, d)
 
     if "shared" in p:
         sp = p["shared"]

@@ -265,6 +265,7 @@ def _encode(params: dict, cfg: ModelConfig, frames: jnp.ndarray,
     return L.rmsnorm_apply(params["encoder"]["norm"], h, cfg.norm_eps)
 
 
+@jax.named_scope("unembed")
 def _unembed(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     if cfg.tie_embeddings:

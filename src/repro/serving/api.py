@@ -116,8 +116,11 @@ class Request:
     on_finish: Optional[Callable[["RequestOutput"], None]] = None
     out_tokens: list = dataclasses.field(default_factory=list)
     finish_reason: Optional[str] = None
-    # latency bookkeeping: the engine stamps submission; emit stamps tokens
+    # latency bookkeeping: the engine stamps submission and the first
+    # binding to a slot (kept across preemptions: queue wait is
+    # t_admit - t_submit); emit stamps tokens
     t_submit: float = 0.0
+    t_admit: Optional[float] = None
     token_times: list = dataclasses.field(default_factory=list)
     # -- preemption/recompute state (engine-managed) ------------------------
     preemptions: int = 0                # times this request lost its slot

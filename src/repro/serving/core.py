@@ -78,6 +78,7 @@ from repro.runtime.faults import FaultPlan
 from repro.serving.api import Request, SamplingParams
 from repro.serving.kvcache import PagedKVCache
 from repro.serving.scheduler import SchedulerOutput
+from repro.serving.trace import call_step, span
 
 _BUCKETED_FAMILIES = ("dense", "moe", "vlm", "encdec")
 
@@ -122,6 +123,7 @@ def _fused_sample(logits, temps, topks, greedy, keys):
     return jax.lax.cond(jnp.all(greedy), _all_greedy, _mixed, None)
 
 
+@jax.named_scope("sample")
 def _health_and_sample(logits, poison, temps, topks, greedy, keys):
     """Shared fused tail: apply the (B,) additive poison (zeros when no
     fault fires — same shape either way, so chaos never retraces), check
@@ -547,13 +549,16 @@ class EngineCore:
         """Advance ALL slots one token with ONE fused decode+sample call.
         Returns ((B,) next tokens, (B,) finite-logits flags)."""
         self.step_shapes.add(("decode", 1))
-        next_toks, self.caches, nkeys, ok = self._step_fn(
-            self.params, self.caches, jnp.asarray(last_tokens),
-            jnp.asarray(poison if poison is not None else self._zero_poison),
-            jnp.asarray(self.temps), jnp.asarray(self.topks),
-            jnp.asarray(self.greedy), jnp.asarray(self.keys))
-        self.keys = np.array(nkeys)                  # writable host copy
-        return np.asarray(next_toks), np.asarray(ok)   # single host sync
+        with span("engine.launch"):
+            next_toks, self.caches, nkeys, ok = self._step_fn(
+                self.params, self.caches, jnp.asarray(last_tokens),
+                jnp.asarray(poison if poison is not None
+                            else self._zero_poison),
+                jnp.asarray(self.temps), jnp.asarray(self.topks),
+                jnp.asarray(self.greedy), jnp.asarray(self.keys))
+        with span("engine.wait"):
+            self.keys = np.array(nkeys)              # writable host copy
+            return np.asarray(next_toks), np.asarray(ok)   # one host sync
 
     # -- unified step ------------------------------------------------------
 
@@ -652,14 +657,12 @@ class EngineCore:
         out.n_decode_tokens = len(out.decode_tokens)
         return out
 
-    def _window_step(self, so: SchedulerOutput,
-                     last_tokens: Optional[np.ndarray],
-                     out: StepOutput,
-                     poison: Optional[np.ndarray] = None) -> None:
-        """ONE fused ragged window call: decode slots ride at width 1, chunk
-        slots at their slice length, idle slots at 0 — all inside a single
-        (B, W) batch so prefill never stalls inter-token latency."""
-        W = self.window or max(c.length for c in so.chunks)
+    def _window_arrays(self, so: SchedulerOutput,
+                       last_tokens: Optional[np.ndarray], W: int) -> tuple:
+        """The (B, W) ragged window's host arrays — decode slots at width 1,
+        chunk slots at their slice length, idle slots at 0 — with newly
+        bound slots' sampling state seeded and their device fill level
+        re-based to 0. Returns (tokens, n_tok, the newly bound slots)."""
         tokens = np.zeros((self.B, W), np.int32)
         n_tok = np.zeros(self.B, np.int32)
         for i in so.decode_slots:
@@ -675,20 +678,30 @@ class EngineCore:
         if fresh:
             self.caches["pos"] = self.caches["pos"].at[
                 jnp.asarray(fresh)].set(0)
-        self.step_shapes.add(("window", W))
-        fn = _window_step_fn(self.cfg, W)
-        toks, self.caches, nkeys, ok = fn(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(n_tok),
-            jnp.asarray(poison if poison is not None else self._zero_poison),
-            jnp.asarray(self.temps),
-            jnp.asarray(self.topks), jnp.asarray(self.greedy),
-            jnp.asarray(self.keys))
-        toks, nkeys, ok = np.asarray(toks), np.asarray(nkeys), np.asarray(ok)
-        # Commit keys ONLY for emitting slots: a mid-prompt chunk consumes no
-        # randomness, keeping sampled streams identical to the unchunked path.
-        # A slot whose emitted logits went non-finite commits nothing — its
-        # token is garbage and its request is quarantined by the engine.
+        return tokens, n_tok, fresh
+
+    def _launch(self, fn, host_args: tuple,
+                poison: Optional[np.ndarray]) -> tuple:
+        """Upload a fused step's host arrays, then its sampling state, call
+        ``fn(params, caches, *arrays)`` and wait for its outputs. Returns
+        the host (tokens, keys, ok)."""
+        with span("engine.launch"):
+            args = host_args + (
+                poison if poison is not None else self._zero_poison,
+                self.temps, self.topks, self.greedy, self.keys)
+            toks, self.caches, nkeys, ok = call_step(
+                fn, self.params, self.caches,
+                *(jnp.asarray(a) for a in args))
+        with span("engine.wait"):
+            return np.asarray(toks), np.asarray(nkeys), np.asarray(ok)
+
+    def _emit(self, so: SchedulerOutput, toks: np.ndarray,
+              nkeys: np.ndarray, ok: np.ndarray, out: StepOutput) -> None:
+        """Book a fused step's sampled tokens. Keys commit ONLY for emitting
+        slots: a mid-prompt chunk consumes no randomness, keeping sampled
+        streams identical to the unchunked path. A slot whose emitted logits
+        went non-finite commits nothing — its token is garbage and its
+        request is quarantined by the engine."""
         bad: list = []
         for i in so.decode_slots:
             if not ok[i]:
@@ -704,6 +717,21 @@ class EngineCore:
                 out.first_tokens[c.slot] = int(toks[c.slot])
                 self.keys[c.slot] = nkeys[c.slot]
         out.bad_slots = out.bad_slots + tuple(bad)
+
+    def _window_step(self, so: SchedulerOutput,
+                     last_tokens: Optional[np.ndarray],
+                     out: StepOutput,
+                     poison: Optional[np.ndarray] = None) -> None:
+        """ONE fused ragged window call: decode slots ride at width 1, chunk
+        slots at their slice length, idle slots at 0 — all inside a single
+        (B, W) batch so prefill never stalls inter-token latency."""
+        W = self.window or max(c.length for c in so.chunks)
+        with span("engine.pack"):
+            tokens, n_tok, _ = self._window_arrays(so, last_tokens, W)
+        self.step_shapes.add(("window", W))
+        toks, nkeys, ok = self._launch(_window_step_fn(self.cfg, W),
+                                       (tokens, n_tok), poison)
+        self._emit(so, toks, nkeys, ok, out)
         out.n_valid_tokens += int(n_tok.sum())
         out.n_batch_tokens += self.B * W
 
@@ -716,55 +744,29 @@ class EngineCore:
         (T = pow-2 bucket), so no slot drags padded columns through the
         model. See ``models.transformer.serve_step_packed``."""
         from repro.serving.scheduler import pack_step
-        for c in so.chunks:
-            if c.start == 0:            # new request: seed sampling state
-                self._set_sampling(c.slot, c.req.sampling, c.req.resume_key)
-        ps = pack_step(so, last_tokens, self._host_pos, self.B,
-                       self.window or 1)
+        with span("engine.pack"):
+            for c in so.chunks:
+                if c.start == 0:        # new request: seed sampling state
+                    self._set_sampling(c.slot, c.req.sampling,
+                                       c.req.resume_key)
+            ps = pack_step(so, last_tokens, self._host_pos, self.B,
+                           self.window or 1)
+            packed = (ps.tokens, ps.slot_ids, ps.positions,
+                      np.asarray(ps.new_pos, np.int32),
+                      np.asarray(ps.emit_idx, np.int32))
         self.step_shapes.add(("packed", ps.n_batch))
-        sample_args = (
-            jnp.asarray(poison if poison is not None else self._zero_poison),
-            jnp.asarray(self.temps), jnp.asarray(self.topks),
-            jnp.asarray(self.greedy), jnp.asarray(self.keys))
-        packed_args = (
-            jnp.asarray(ps.tokens),
-            jnp.asarray(ps.slot_ids), jnp.asarray(ps.positions),
-            jnp.asarray(ps.new_pos, dtype=jnp.int32),
-            jnp.asarray(ps.emit_idx, dtype=jnp.int32))
         if self.paged:
             fn = _paged_step_fn(self.cfg, ps.n_batch)
-            toks, self.caches, nkeys, ok = fn(
-                self.params, self.caches,
-                jnp.asarray(self.pager.page_table), *packed_args,
-                *sample_args)
+            args = (self.pager.page_table,) + packed
         elif self.variants:
             fn = _mm_packed_step_fn(self.cfg, ps.n_batch)
-            toks, self.caches, nkeys, ok = fn(
-                self.params, self.caches, *packed_args,
-                jnp.asarray(self.model_ids), *sample_args)
+            args = packed + (self.model_ids,)
         else:
             fn = _packed_step_fn(self.cfg, ps.n_batch)
-            toks, self.caches, nkeys, ok = fn(
-                self.params, self.caches, *packed_args, *sample_args)
-        toks, nkeys, ok = np.asarray(toks), np.asarray(nkeys), np.asarray(ok)
+            args = packed
+        toks, nkeys, ok = self._launch(fn, args, poison)
         self._host_pos[:] = ps.new_pos
-        # Same key-commit discipline as the window path: emitting slots only;
-        # non-finite emitted logits commit nothing (quarantine).
-        bad: list = []
-        for i in so.decode_slots:
-            if not ok[i]:
-                bad.append(i)
-                continue
-            out.decode_tokens[i] = int(toks[i])
-            self.keys[i] = nkeys[i]
-        for c in so.chunks:
-            if c.last:
-                if not ok[c.slot]:
-                    bad.append(c.slot)
-                    continue
-                out.first_tokens[c.slot] = int(toks[c.slot])
-                self.keys[c.slot] = nkeys[c.slot]
-        out.bad_slots = out.bad_slots + tuple(bad)
+        self._emit(so, toks, nkeys, ok, out)
         out.n_valid_tokens += ps.n_valid
         out.n_batch_tokens += ps.n_batch
 
@@ -777,49 +779,15 @@ class EngineCore:
         (``serve_step_window_paged``) — one call, two steady-state shapes
         (W = chunk_size, W = 1), K/V written straight into granted pages."""
         W = self.window or max(c.length for c in so.chunks)
-        tokens = np.zeros((self.B, W), np.int32)
-        n_tok = np.zeros(self.B, np.int32)
-        for i in so.decode_slots:
-            tokens[i, 0] = last_tokens[i]
-            n_tok[i] = 1
-        fresh = []
-        for c in so.chunks:
-            tokens[c.slot, :c.length] = c.req.prompt[c.start:c.start + c.length]
-            n_tok[c.slot] = c.length
-            if c.start == 0:            # new request: re-base pos, seed keys
-                self._set_sampling(c.slot, c.req.sampling, c.req.resume_key)
-                fresh.append(c.slot)
-        if fresh:
-            self.caches["pos"] = self.caches["pos"].at[
-                jnp.asarray(fresh)].set(0)
+        with span("engine.pack"):
+            tokens, n_tok, fresh = self._window_arrays(so, last_tokens, W)
             self._host_pos[fresh] = 0
         self.step_shapes.add(("window", W))
-        fn = _paged_window_step_fn(self.cfg, W)
-        toks, self.caches, nkeys, ok = fn(
-            self.params, self.caches, jnp.asarray(self.pager.page_table),
-            jnp.asarray(tokens), jnp.asarray(n_tok),
-            jnp.asarray(poison if poison is not None else self._zero_poison),
-            jnp.asarray(self.temps),
-            jnp.asarray(self.topks), jnp.asarray(self.greedy),
-            jnp.asarray(self.keys))
-        toks, nkeys, ok = np.asarray(toks), np.asarray(nkeys), np.asarray(ok)
+        toks, nkeys, ok = self._launch(
+            _paged_window_step_fn(self.cfg, W),
+            (self.pager.page_table, tokens, n_tok), poison)
         self._host_pos[:] = self._host_pos + n_tok
-        # Same key-commit discipline as the contiguous window path.
-        bad: list = []
-        for i in so.decode_slots:
-            if not ok[i]:
-                bad.append(i)
-                continue
-            out.decode_tokens[i] = int(toks[i])
-            self.keys[i] = nkeys[i]
-        for c in so.chunks:
-            if c.last:
-                if not ok[c.slot]:
-                    bad.append(c.slot)
-                    continue
-                out.first_tokens[c.slot] = int(toks[c.slot])
-                self.keys[c.slot] = nkeys[c.slot]
-        out.bad_slots = out.bad_slots + tuple(bad)
+        self._emit(so, toks, nkeys, ok, out)
         out.n_valid_tokens += int(n_tok.sum())
         out.n_batch_tokens += self.B * W
 
@@ -835,48 +803,14 @@ class EngineCore:
         matches the single-model window engine (two steady-state shapes)."""
         W = ((self.window or max(c.length for c in so.chunks))
              if so.chunks else 1)
-        tokens = np.zeros((self.B, W), np.int32)
-        n_tok = np.zeros(self.B, np.int32)
-        for i in so.decode_slots:
-            tokens[i, 0] = last_tokens[i]
-            n_tok[i] = 1
-        fresh = []
-        for c in so.chunks:
-            tokens[c.slot, :c.length] = c.req.prompt[c.start:c.start + c.length]
-            n_tok[c.slot] = c.length
-            if c.start == 0:            # new request: re-base pos, seed keys
-                self._set_sampling(c.slot, c.req.sampling, c.req.resume_key)
-                fresh.append(c.slot)
-        if fresh:
-            self.caches["pos"] = self.caches["pos"].at[
-                jnp.asarray(fresh)].set(0)
+        with span("engine.pack"):
+            tokens, n_tok, fresh = self._window_arrays(so, last_tokens, W)
             self._host_pos[fresh] = 0
         self.step_shapes.add(("window", W) if so.chunks else ("decode", 1))
-        fn = _mm_window_step_fn(self.cfg, W)
-        toks, self.caches, nkeys, ok = fn(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(n_tok), jnp.asarray(self.model_ids),
-            jnp.asarray(poison if poison is not None else self._zero_poison),
-            jnp.asarray(self.temps),
-            jnp.asarray(self.topks), jnp.asarray(self.greedy),
-            jnp.asarray(self.keys))
-        toks, nkeys, ok = np.asarray(toks), np.asarray(nkeys), np.asarray(ok)
+        toks, nkeys, ok = self._launch(_mm_window_step_fn(self.cfg, W),
+                                       (tokens, n_tok, self.model_ids),
+                                       poison)
         self._host_pos[:] = self._host_pos + n_tok
-        # Same key-commit discipline as the single-model window path.
-        bad: list = []
-        for i in so.decode_slots:
-            if not ok[i]:
-                bad.append(i)
-                continue
-            out.decode_tokens[i] = int(toks[i])
-            self.keys[i] = nkeys[i]
-        for c in so.chunks:
-            if c.last:
-                if not ok[c.slot]:
-                    bad.append(c.slot)
-                    continue
-                out.first_tokens[c.slot] = int(toks[c.slot])
-                self.keys[c.slot] = nkeys[c.slot]
-        out.bad_slots = out.bad_slots + tuple(bad)
+        self._emit(so, toks, nkeys, ok, out)
         out.n_valid_tokens += int(n_tok.sum())
         out.n_batch_tokens += self.B * W

@@ -84,6 +84,7 @@ from repro.serving.api import (FINISH_CANCELLED, FINISH_EOS, FINISH_ERROR,
 from repro.serving.core import _BUCKETED_FAMILIES, EngineCore, StepOutput
 from repro.serving.scheduler import (FCFSScheduler, SchedulerOutput,
                                      legacy_schedule)
+from repro.serving.trace import span
 
 __all__ = ["LLMEngine", "EngineStats", "Request",
            "SamplingParams", "RequestOutput"]
@@ -421,15 +422,21 @@ class LLMEngine:
         FINISH_TIMEOUT before scheduling; scheduler-decided preemptions are
         executed (evict + recompute-requeue) before the device call; a step
         exception triggers watchdog recovery instead of propagating."""
-        self._expire_deadlines()
-        self._drain_shed()
-        so = self._schedule()
-        for i in so.preempt_slots:      # evict + recompute-requeue
-            self._requeue_slot(i, preempt=True)
-        self._drain_shed()              # requeue into a full queue sheds
-        if self.paged:
-            so = self._page_gate(so)    # grant KV pages / preempt on OOM
+        with span("engine.step"):
+            return self._step()
+
+    def _step(self) -> int:
+        with span("engine.schedule"):
+            self._expire_deadlines()
             self._drain_shed()
+            so = self._schedule()
+            for i in so.preempt_slots:  # evict + recompute-requeue
+                self._requeue_slot(i, preempt=True)
+            self._drain_shed()          # requeue into a full queue sheds
+        if self.paged:
+            with span("engine.page_gate"):
+                so = self._page_gate(so)    # grant KV pages / preempt on OOM
+                self._drain_shed()
         if so.empty:
             return self._remaining()
         last = np.zeros(self.B, np.int32)
@@ -437,8 +444,7 @@ class LLMEngine:
             last[i] = self.slots[i].out_tokens[-1]
         for c in so.chunks:             # bind newly admitted requests
             if c.start == 0:
-                self.slots[c.slot] = c.req
-                self._prefill_done[c.slot] = 0
+                self._bind(c.slot, c.req)
                 if self.variants:       # route the slot to its alpha variant
                     self.core.model_ids[c.slot] = (
                         self._model_index(c.req.model)
@@ -446,8 +452,7 @@ class LLMEngine:
                         and c.req.model is not None else 0)
         for pg in so.prefill_groups:    # legacy whole-prompt prefill
             for i, req in pg.slot_reqs:
-                self.slots[i] = req
-                self._prefill_done[i] = 0
+                self._bind(i, req)
         t0 = time.perf_counter()
         try:
             # Scope the decompress weight cache to this engine's model label
@@ -466,13 +471,27 @@ class LLMEngine:
         # runs on a fresh core; recompute keeps streams identical.
         stalled = (self.step_timeout_s is not None
                    and time.perf_counter() - t0 > self.step_timeout_s)
-        self._commit(so, out)
-        if self.journal is not None:
-            self.journal.flush()    # group-commit this step's records
+        with span("engine.commit"):
+            self._commit(so, out)
+            if self.journal is not None:
+                self.journal.flush()    # group-commit this step's records
         if stalled:
             self.stats.stalls += 1
             self._recover()
         return self._remaining()
+
+    def _bind(self, i: int, req: Request) -> None:
+        """Put a request's first chunk (or whole prompt) in slot ``i``. The
+        first binding admits it: it stamps ``t_admit``, which a preempted
+        request keeps, and marks the profile with an ``engine.admit`` span
+        that carries the queue wait."""
+        self.slots[i] = req
+        self._prefill_done[i] = 0
+        if req.t_admit is None:
+            req.t_admit = time.perf_counter()
+            with span("engine.admit", rid=req.rid,
+                      queue_wait_s=req.t_admit - req.t_submit):
+                pass
 
     def _page_gate(self, so: SchedulerOutput) -> SchedulerOutput:
         """Grant KV pages for everything the scheduler just emitted, treating
@@ -669,21 +688,22 @@ class LLMEngine:
         are lru-cached per config, so the rebuilt core re-uses their traces;
         the fault-plan step index carries forward so a step-pinned fault
         fires once per run, not once per core."""
-        for i in range(self.B):
-            if self.slots[i] is not None:
-                self._requeue_slot(i, preempt=False)
-        self._drain_shed()
-        old = self.core
-        self.core = EngineCore(self.params, self.cfg, batch_slots=self.B,
-                               buffer_len=self.T, window=self.chunk or 0,
-                               packed=self.packed, paged=self.paged,
-                               page_size=self.page_size,
-                               kv_pages=self.kv_pages, faults=self.faults,
-                               variants=self.variants)
-        self.core.step_idx = old.step_idx
-        self.core.prefill_compiles = old.prefill_compiles
-        self.core.step_shapes = old.step_shapes
-        self.stats.recoveries += 1
+        with span("engine.recover"):
+            for i in range(self.B):
+                if self.slots[i] is not None:
+                    self._requeue_slot(i, preempt=False)
+            self._drain_shed()
+            old = self.core
+            self.core = EngineCore(self.params, self.cfg, batch_slots=self.B,
+                                   buffer_len=self.T, window=self.chunk or 0,
+                                   packed=self.packed, paged=self.paged,
+                                   page_size=self.page_size,
+                                   kv_pages=self.kv_pages, faults=self.faults,
+                                   variants=self.variants)
+            self.core.step_idx = old.step_idx
+            self.core.prefill_compiles = old.prefill_compiles
+            self.core.step_shapes = old.step_shapes
+            self.stats.recoveries += 1
 
     def _remaining(self) -> int:
         return (sum(s is not None for s in self.slots)

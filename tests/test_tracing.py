@@ -1,0 +1,94 @@
+"""The serving program's own spans, scopes and stamps: named scopes per layer
+in the compiled step's HLO metadata, the engine's host spans under the
+profiler, and the queue-wait stamp ``Request.t_admit``."""
+import glob
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from repro.configs import get_smoke_config
+from repro.models import registry as R
+from repro.serving import LLMEngine, Request, trace
+from repro.runtime.faults import FaultPlan
+
+
+@pytest.fixture(scope="module")
+def moe():
+    cfg = get_smoke_config("olmoe_1b_7b")
+    return cfg, R.model_init(jax.random.PRNGKey(0), cfg)
+
+
+def _req(rid, plen, max_new=4, vocab=512, **kw):
+    rng = np.random.default_rng(rid)
+    return Request(rid, rng.integers(0, vocab, plen, dtype=np.int32),
+                   max_new_tokens=max_new, **kw)
+
+
+def _paged(cfg, params, **kw):
+    return LLMEngine(params, cfg, batch_slots=2, buffer_len=64, chunk_size=8,
+                     packed=True, paged=True, page_size=8, **kw)
+
+
+def test_compiled_paged_step_names_each_layer_in_its_metadata(moe):
+    cfg, params = moe
+    eng = _paged(cfg, params)
+    for rid in range(2):
+        eng.submit(_req(rid, 12, vocab=cfg.vocab))
+    eng.run_until_drained()
+    texts = trace.step_program_texts()
+    names = {part for t in texts
+             for op in re.findall(r'op_name="([^"]*)"', t)
+             for part in op.split("/")}
+    assert names >= {"embed", "attention", "linear.attn_q", "linear.attn_o",
+                     "moe", "moe.router", "moe.dispatch", "moe.experts",
+                     "moe.combine", "unembed", "sample"}
+
+
+def test_engine_spans_nest_inside_the_step(moe, tmp_path):
+    from jax.profiler import ProfileData
+    cfg, params = moe
+    eng = _paged(cfg, params, faults=FaultPlan.parse(["fail:step=3"]))
+    for rid in range(3):
+        eng.submit(_req(rid, 12, vocab=cfg.vocab))
+    eng.step()                                      # compile outside
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run_until_drained()
+    assert eng.stats.recoveries == 1
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for p in ProfileData.from_file(path).planes
+             if p.name.startswith("/host") for line in p.lines
+             for e in line.events if e.name.startswith("engine.")]
+    names = {s[0] for s in spans}
+    assert names == {"engine.step", "engine.schedule", "engine.page_gate",
+                     "engine.admit", "engine.pack", "engine.launch",
+                     "engine.wait", "engine.commit", "engine.recover"}
+    steps = [s for s in spans if s[0] == "engine.step"]
+    for name, a, b, _ in spans:
+        if name != "engine.step":
+            assert any(s <= a and b <= e for _, s, e, _ in steps), name
+    # the request admitted under the profiler carries its queue wait
+    (admit,) = [s for s in spans if s[0] == "engine.admit"]
+    assert admit[3]["rid"] == 2 and admit[3]["queue_wait_s"] > 0
+
+
+def test_t_admit_is_stamped_once_and_kept_across_preemption(moe):
+    cfg, params = moe
+    eng = LLMEngine(params, cfg, batch_slots=2, buffer_len=64, chunk_size=8,
+                    admission="preempt", packed=True, paged=True,
+                    page_size=8)
+    reqs = [_req(rid, 10, max_new=6, vocab=cfg.vocab) for rid in range(2)]
+    for r in reqs:
+        eng.submit(r)
+    for _ in range(4):                              # both slots mid-decode
+        eng.step()
+    first = [r.t_admit for r in reqs]
+    urgent = _req(9, 10, vocab=cfg.vocab, priority=5)
+    eng.submit(urgent)
+    eng.run_until_drained()
+    assert sum(r.preemptions for r in reqs) >= 1
+    assert [r.t_admit for r in reqs] == first
+    for r in reqs + [urgent]:
+        assert r.t_submit <= r.t_admit <= r.token_times[0]
