@@ -21,6 +21,7 @@ Block sizes (bm, bn, bk, bj) are the TPU analogue of the paper's
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +78,32 @@ def _unpack_tile(al_c: jnp.ndarray, quant: str) -> jnp.ndarray:
     return al_c.astype(jnp.float32)
 
 
+def _chunk_run(bk: int, bj: int, seg: int, n_keep: int, n_chunks: int
+               ) -> int:
+    """Alpha chunks the generator loop runs per k-block: all of them for
+    monolithic codes (seg == 0), which touch every k. Segmented, a k-block
+    meets only its own segments' ``bk // seg * n_keep`` alpha rows, which
+    start at a multiple of ``g = gcd(rows, bj)`` and so span at most
+    ``(bj - g + rows - 1) // bj + 1`` chunks of ``bj``."""
+    if not seg:
+        return n_chunks
+    rows = bk // seg * n_keep
+    g = math.gcd(rows, bj)
+    return min(n_chunks, (bj - g + rows - 1) // bj + 1)
+
+
+def _first_chunk(k, bk: int, bj: int, seg: int, n_keep: int, n_chunks: int):
+    """The chunk the generator loop of k-block ``k`` starts at: the one
+    holding the k-block's first alpha row, moved back so that its
+    ``_chunk_run`` chunks end by the last one (the rows that brings in have
+    all-zero sign tiles). Chunk 0 where the loop runs them all."""
+    n_run = _chunk_run(bk, bj, seg, n_keep, n_chunks)
+    if n_run == n_chunks:
+        return 0
+    return jnp.minimum(jax.lax.div(k * (bk // seg * n_keep), bj),
+                       n_chunks - n_run)
+
+
 def _gen_w_tile(idx_ref, alpha_ref, k: jnp.ndarray, *, bk: int,
                 seg: int = 0, n_keep: int = 0, scale_ref=None,
                 quant: str = "") -> jnp.ndarray:
@@ -88,12 +115,19 @@ def _gen_w_tile(idx_ref, alpha_ref, k: jnp.ndarray, *, bk: int,
     ``alpha_ref`` holds int8 (or int4-packed-in-int8) coefficients and
     ``scale_ref`` (n_chunks, 1, bj) their per-row fp32 scales, folded into
     the sign tile's columns right before the MXU contraction.
+
+    Segmented codes bound the loop to ``_chunk_run`` chunks from
+    ``_first_chunk``: every chunk skipped has an all-zero sign tile, so the
+    tile is the one the full loop makes.
     """
     n_chunks, bj, _ = alpha_ref.shape
     bn = 2 * alpha_ref.shape[2] if quant == "int4" else alpha_ref.shape[2]
     k0 = k * bk
+    n_run = _chunk_run(bk, bj, seg, n_keep, n_chunks)
+    c0 = _first_chunk(k, bk, bj, seg, n_keep, n_chunks)
 
-    def body(c, acc):
+    def body(i, acc):
+        c = c0 + i
         st = _sign_tile(idx_ref[c], c * bj, k0, bk, seg, n_keep)     # (bk, bj)
         al_c = alpha_ref[c]                                          # (bj, bn)
         if quant:
@@ -103,7 +137,7 @@ def _gen_w_tile(idx_ref, alpha_ref, k: jnp.ndarray, *, bk: int,
             st = st.astype(al_c.dtype)  # +-1 is exact; products stay exact
         return acc + _dot(st, al_c)
 
-    return jax.lax.fori_loop(0, n_chunks, body,
+    return jax.lax.fori_loop(0, n_run, body,
                              jnp.zeros((bk, bn), jnp.float32))
 
 
@@ -136,6 +170,20 @@ def _untile_int4(out: jnp.ndarray, bn: int) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # Fused on-the-fly GEMM (TiWGen)
 # ---------------------------------------------------------------------------
+
+# What the generator loop of each distinct ``ovsf_gemm`` call runs, noted
+# when the call is traced (``gemm_notes``).
+_GEMM_NOTES: dict = {}
+
+
+def gemm_notes() -> list:
+    """One dict per distinct ``ovsf_gemm`` call this process has traced:
+    ``d_in``, ``d_out``, the code segment length ``seg`` (0: monolithic),
+    the blocks ``bk`` and ``bj``, the alpha chunks ``nc`` and ``n_run``, the
+    chunks the generator loop runs per k-block. A traced call is cached, so
+    calls of one shape and blocks are noted once, however many there are."""
+    return [dict(n) for n in _GEMM_NOTES.values()]
+
 
 def _ovsf_gemm_kernel(idx_ref, x_ref, alpha_ref, *rest,
                       bk: int, nk: int, seg: int, n_keep: int,
@@ -211,6 +259,9 @@ def ovsf_gemm(x: jnp.ndarray, alphas: jnp.ndarray, idx: jnp.ndarray, *,
     Np = Np_store * (2 if quant == "int4" else 1)
     nk = Kp // bk
     nc = Jp // bj
+    note = dict(d_in=d_in, d_out=d_out, seg=seg, bk=bk, bj=bj, nc=nc,
+                n_run=_chunk_run(bk, bj, seg, keep, nc))
+    _GEMM_NOTES.setdefault(tuple(note.items()), note)
 
     operands = [_pad1(idx.astype(jnp.int32), bj).reshape(nc, 1, bj), xp,
                 alp.reshape(nc, bj, Np_store)]

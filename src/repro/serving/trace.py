@@ -22,10 +22,16 @@ metadata: ``embed``, ``attention``, ``linear.<weight type>``, ``moe`` with
 ``unembed`` and ``sample``. A profile read without HLO protos names device
 operations by instruction only; ``step_program_texts()`` gives the compiled
 text that maps each instruction to its scope.
+
+Kernel counters: ``kernel_notes()`` gives what the kernels noted of each
+call they traced, such as the alpha chunks the fused OVSF generator runs
+per k-block.
 """
 from __future__ import annotations
 
 import jax
+
+from repro.kernels.ovsf_gemm import gemm_notes
 
 # jitted step function -> abstract arguments of its first call. Process-wide,
 # like the lru-cached step functions it keys on: a profile is reduced after
@@ -62,3 +68,11 @@ def step_program_texts() -> list:
     a scope change makes a new entry)."""
     return [fn.lower(*args).compile().as_text()
             for fn, args in _STEP_PROGRAMS.items()]
+
+
+def kernel_notes() -> list:
+    """What the kernels noted of the calls this process traced: one dict
+    per distinct ``ovsf_gemm`` call (``kernels.ovsf_gemm.gemm_notes``),
+    with ``n_run`` of its ``nc`` alpha chunks of ``bj`` rows run by the
+    weight generator per k-block."""
+    return gemm_notes()

@@ -131,3 +131,69 @@ def test_gradients_flow_through_all_paths():
             ops.ovsf_matmul(x, a, idx, path=path, use_pallas=False) ** 2))(al)
         assert np.isfinite(np.asarray(g)).all()
         assert float(jnp.abs(g).max()) > 0
+
+
+def _seg_case(seed, M, d_in, N, dtype):
+    """Segmented (L0 = 16, rho 0.5) codes and alphas, rounded to ``dtype``."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    W = jax.random.normal(k1, (d_in, N)) * 0.1
+    x = jax.random.normal(k2, (M, d_in)).astype(dtype)
+    p = ovsf.compress_matrix(W, ovsf.OVSFSpec(d_in, N, rho=0.5, seg=16))
+    assert p["idx"].shape == (d_in // 16, 8)
+    return x, p["alphas"].astype(dtype), p["idx"]
+
+
+# A k-block of bk inputs meets bk / 16 * 8 alpha rows; J = 128 at d_in 256.
+SEG_BLOCKS = {
+    "rows_below_chunk": (16, 16),   # 8 rows: two k-blocks share a chunk
+    "rows_equal_chunk": (32, 16),   # 16 rows: one chunk, aligned
+    "rows_above_chunk": (64, 16),   # 32 rows: two chunks
+    "rows_unaligned": (48, 16),     # 24 rows from row 24k: two chunks, the
+                                    # last k-block padded past d_in
+    "last_chunk_padded": (32, 24),  # J = 128 padded to 144
+}
+
+
+@pytest.mark.parametrize("blocks", SEG_BLOCKS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("kernel", ["gemm", "decompress"])
+def test_segmented_generator_matches_oracle(kernel, dtype, blocks):
+    """The generator loop, bounded to each k-block's own alpha chunks,
+    against the oracle on the same rounded inputs."""
+    bk, bj = SEG_BLOCKS[blocks]
+    x, al, idx = _seg_case(bk + bj, 8, 256, 64, dtype)
+    al32 = al.astype(jnp.float32)
+    if kernel == "gemm":
+        y = ovsf_gemm(x, al, idx, interpret=True, block_m=8, block_n=32,
+                      block_k=bk, block_j=bj)
+        yr = kref.ovsf_matmul_ref(x.astype(jnp.float32), al32, idx)
+    else:
+        y = ovsf_decompress(al, idx, d_in=256, interpret=True, block_n=32,
+                            block_k=bk, block_j=bj)
+        yr = kref.ovsf_decompress_ref(al32, idx, 256)
+    tol = 1e-3 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(yr),
+                               rtol=tol, atol=tol * 30)
+
+
+@pytest.mark.parametrize("d_in,bk,bj", [
+    (256, 16, 16), (256, 48, 16), (256, 32, 24), (256, 64, 24),
+    (2048, 128, 128), (24576, 128, 128), (6144, 384, 128), (256, 256, 8),
+])
+def test_generator_chunk_window_covers_each_k_block(d_in, bk, bj):
+    """Segmented codes (L0 16, 8 kept): for every k-block, the chunks the
+    bounded loop runs hold all of its alpha rows that exist, and none lies
+    past the last chunk, which interpret mode would not show."""
+    from repro.kernels.ovsf_gemm import _chunk_run, _first_chunk
+    seg, keep = 16, 8
+    J = d_in // seg * keep
+    nc = -(-J // bj)
+    n_run = _chunk_run(bk, bj, seg, keep, nc)
+    ks = np.arange(-(-d_in // bk))
+    c0 = np.asarray(_first_chunk(jnp.asarray(ks, jnp.int32), bk, bj, seg,
+                                 keep, nc)) * np.ones_like(ks)
+    rows = bk // seg * keep
+    first = ks * rows // bj
+    last = np.minimum((ks * rows + rows - 1) // bj, nc - 1)
+    assert (c0 >= 0).all() and (c0 + n_run <= nc).all()
+    assert (c0 <= first).all() and (last < c0 + n_run).all()

@@ -182,6 +182,22 @@ def test_ovsf_gemm_quantised_matches_dequant_oracle(dt, seg):
 
 
 @pytest.mark.parametrize("dt", ["int8", "int4"])
+@pytest.mark.parametrize("bk,bj", [(16, 8), (48, 16)])
+def test_ovsf_gemm_quantised_bounded_loop_reads_its_own_scales(dt, bk, bj):
+    """Segmented codes: each k-block's generator loop starts at the chunk
+    of its own alpha rows (the k-th of 8 rows, or rows 24k.. across two
+    chunks of 16), so it reads scale chunks past the first, and the
+    per-segment scales differ."""
+    x, al, sc, idx = _quant_case(13, 5, 128, 64, dt, seg=16)
+    assert len(np.unique(np.asarray(sc))) > 1
+    y = ovsf_gemm(x, al, idx, alpha_scale=sc, alpha_dtype=dt, interpret=True,
+                  block_m=8, block_n=32, block_k=bk, block_j=bj)
+    yr = kref.ovsf_matmul_ref(x, ovsf.dequantize_alphas(al, sc, dt), idx)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                               rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("dt", ["int8", "int4"])
 def test_ovsf_decompress_quantised_matches_dequant_oracle(dt):
     _, al, sc, idx = _quant_case(5, 1, 128, 64, dt, seg=0)
     W = ovsf_decompress(al, idx, d_in=128, alpha_scale=sc, alpha_dtype=dt,

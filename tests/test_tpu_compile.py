@@ -2,10 +2,11 @@
 
 Interpret mode accepts what the chip's compiler refuses (unaligned blocks,
 unsupported in-kernel reshapes, too much VMEM). These tests compile each
-kernel at tinyllama_1_1b's widths, with the blocks the mapper plans, for a
-described v5e chip, and check the result holds the Pallas custom call. The
-topology is described inside a fixture, never at import: only one process at
-a time may load the TPU compiler's library.
+kernel at tinyllama_1_1b's widths (the fused one also at starcoder2_15b's
+widest linear), with the blocks the mapper plans, for a described v5e chip,
+and check the result holds the Pallas custom call. The topology is
+described inside a fixture, never at import: only one process at a time may
+load the TPU compiler's library.
 """
 import dataclasses
 
@@ -45,21 +46,28 @@ def _compiled_text(fn, sharding, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _plan(alpha_dtype: str, name: str):
-    cfg = get_config(ARCH)
+def _plan(alpha_dtype: str, name: str, arch: str = ARCH, rows: int = ROWS):
+    cfg = get_config(arch)
     cfg = cfg.replace(ovsf=dataclasses.replace(cfg.ovsf,
                                                alpha_dtype=alpha_dtype))
-    shape = ShapeConfig("serve_decode", 1, ROWS, "decode")
+    shape = ShapeConfig("serve_decode", 1, rows, "decode")
     return cfg, mapper.plan_model(cfg, shape, hw="v5e",
                                   weight_reuse=1).plan_for(name)
 
 
-@pytest.mark.parametrize("alpha_dtype,name", [
-    ("", "attn_q"), ("", "mlp_up"), ("", "mlp_down"),
-    ("int8", "mlp_down"), ("int4", "mlp_up"),
+@pytest.mark.parametrize("alpha_dtype,name,arch,rows", [
+    pytest.param("", "attn_q", ARCH, ROWS, id="-attn_q"),
+    pytest.param("", "mlp_up", ARCH, ROWS, id="-mlp_up"),
+    pytest.param("", "mlp_down", ARCH, ROWS, id="-mlp_down"),
+    pytest.param("int8", "mlp_down", ARCH, ROWS, id="int8-mlp_down"),
+    pytest.param("int4", "mlp_up", ARCH, ROWS, id="int4-mlp_up"),
+    # d_in 24576, J 12288: the generator loop runs 1 of 96 alpha chunks
+    pytest.param("", "mlp_down", "starcoder2_15b", 128,
+                 id="starcoder2_15b-mlp_down"),
 ])
-def test_ovsf_gemm_compiles_at_planned_blocks(one_chip, alpha_dtype, name):
-    cfg, lp = _plan(alpha_dtype, name)
+def test_ovsf_gemm_compiles_at_planned_blocks(one_chip, alpha_dtype, name,
+                                              arch, rows):
+    cfg, lp = _plan(alpha_dtype, name, arch, rows)
     assert lp.path == "fused"
     d_in, d_out = {"attn_q": (cfg.d_model, cfg.n_heads * cfg.hd),
                    "mlp_up": (cfg.d_model, cfg.d_ff),
@@ -68,7 +76,7 @@ def test_ovsf_gemm_compiles_at_planned_blocks(one_chip, alpha_dtype, name):
     n_seg, keep = d_in // seg, int(round(cfg.ovsf.rho * seg))
     J = n_seg * keep
     stored = (J, d_out // 2 if alpha_dtype == "int4" else d_out)
-    shapes = [((ROWS, d_in), jnp.bfloat16),
+    shapes = [((rows, d_in), jnp.bfloat16),
               (stored, jnp.int8 if alpha_dtype else jnp.bfloat16),
               ((n_seg, keep), jnp.int32)]
     if alpha_dtype:

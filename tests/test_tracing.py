@@ -1,16 +1,21 @@
 """The serving program's own spans, scopes and stamps: named scopes per layer
 in the compiled step's HLO metadata, the engine's host spans under the
-profiler, and the queue-wait stamp ``Request.t_admit``."""
+profiler, the queue-wait stamp ``Request.t_admit``, and the kernels'
+counters (``trace.kernel_notes``)."""
 import glob
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
+from repro.configs.base import ShapeConfig
+from repro.kernels.ovsf_gemm import ovsf_gemm
 from repro.models import registry as R
 from repro.serving import LLMEngine, Request, trace
+from repro.runtime import mapper
 from repro.runtime.faults import FaultPlan
 
 
@@ -92,3 +97,53 @@ def test_t_admit_is_stamped_once_and_kept_across_preemption(moe):
     assert [r.t_admit for r in reqs] == first
     for r in reqs + [urgent]:
         assert r.t_submit <= r.t_admit <= r.token_times[0]
+
+
+def _note_of(d_in, d_out, bk, bj, seg):
+    notes = [n for n in trace.kernel_notes()
+             if (n["d_in"], n["d_out"], n["bk"], n["bj"], n["seg"])
+             == (d_in, d_out, bk, bj, seg)]
+    assert len(notes) == 1, notes
+    return notes[0]
+
+
+@pytest.mark.parametrize("arch,rows,name,nc", [
+    ("starcoder2_15b", 128, "attn_q", 24),
+    ("starcoder2_15b", 128, "mlp_up", 24),
+    ("starcoder2_15b", 128, "mlp_down", 96),
+    ("tinyllama_1_1b", 32, "attn_q", 8),
+    ("tinyllama_1_1b", 32, "mlp_down", 22),
+])
+def test_kernel_notes_count_the_generator_chunks_per_k_block(arch, rows,
+                                                             name, nc):
+    """At the blocks the mapper plans, a segmented (L0 = 16) linear's
+    k-block meets 64 alpha rows, inside one chunk of 128: the generator
+    runs 1 of its nc chunks. Noted at trace time, at full width."""
+    cfg = get_config(arch)
+    lp = mapper.plan_model(cfg, ShapeConfig("serve_decode", 1, rows, "decode"),
+                           hw="v5e", weight_reuse=1).plan_for(name)
+    d_in, d_out = {"attn_q": (cfg.d_model, cfg.n_heads * cfg.hd),
+                   "mlp_up": (cfg.d_model, cfg.d_ff),
+                   "mlp_down": (cfg.d_ff, cfg.d_model)}[name]
+    seg = cfg.ovsf.seg_len
+    keep = int(round(cfg.ovsf.rho * seg))
+    assert (lp.path, seg, keep) == ("fused", 16, 8)
+    jax.eval_shape(
+        lambda x, al, idx: ovsf_gemm(x, al, idx, block_m=lp.block_m,
+                                     block_n=lp.block_n, block_k=lp.block_k,
+                                     block_j=lp.block_j),
+        jax.ShapeDtypeStruct((rows, d_in), jnp.bfloat16),
+        jax.ShapeDtypeStruct((d_in // seg * keep, d_out), jnp.bfloat16),
+        jax.ShapeDtypeStruct((d_in // seg, keep), jnp.int32))
+    note = _note_of(d_in, d_out, lp.block_k, lp.block_j, seg)
+    assert (note["nc"], note["n_run"]) == (nc, 1)
+
+
+def test_kernel_notes_keep_the_full_loop_for_monolithic_codes():
+    jax.eval_shape(
+        lambda x, al, idx: ovsf_gemm(x, al, idx, block_k=128, block_j=128),
+        jax.ShapeDtypeStruct((8, 2048), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1024, 384), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1024,), jnp.int32))
+    note = _note_of(2048, 384, 128, 128, 0)
+    assert (note["nc"], note["n_run"]) == (8, 8)
