@@ -26,6 +26,26 @@ import numpy as np
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
 
+# what ``bench.run.model_of`` can describe and this reference does not
+# compute: key -> the only value it computes (beside these, the router's
+# experts are the experts held, and expert and MLP widths are one)
+NOT_COMPUTED = {"mla": None, "n_shared_experts": 0, "first_dense": 0,
+                "router_scoring": "softmax", "router_bias": False,
+                "routed_scaling": 1.0, "rope_scaling": None}
+
+
+def check_model(m: dict) -> None:
+    """Raise ValueError, naming each key, where ``m`` states what this
+    reference does not compute; such a configuration names a reference of
+    its own (``modules.reference``)."""
+    computes = dict(NOT_COMPUTED, router_experts=m["n_experts"],
+                    moe_d_ff=m["d_ff"])
+    bad = {k: m[k] for k, v in computes.items() if m[k] != v}
+    if bad:
+        raise ValueError(f"bench/reference.py does not compute {bad}: GQA, "
+                         "softmax routing over the experts held, no shared "
+                         "expert, no leading dense layer, rope unscaled")
+
 
 def hadamard(L: int) -> np.ndarray:
     H = np.ones((1, 1), np.float32)

@@ -141,28 +141,130 @@ def metric_reader(root: Path, name: str):
 # ---------------------------------------------------------------------------
 
 _MLP = {"silu": "swiglu", "gelu_pytorch_tanh": "gelu_tanh"}
+MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim")
+_SCORING = ("softmax", "sigmoid")
+# topk_method -> router_bias: plain top-k, or top-k over scores plus a
+# correction bias that picks experts but does not weight them
+_TOPK_METHOD = {None: False, "noaux_tc": True}
+
+
+def _refuse(c: dict, key: str, why: str):
+    raise BenchError(f"{c['name']}: {key} {c.get(key)!r}: {why}")
+
+
+def _expert_count(c: dict) -> int:
+    """The experts held here; with an ``expert_share`` they are this chip's
+    share of the published ``expert_share.n_routed_experts`` over
+    ``expert_share.chips``, and their key is listed in ``reduced``."""
+    key = "n_routed_experts" if "n_routed_experts" in c else "num_experts"
+    E = c.get(key) or 0
+    share = c.get("expert_share")
+    if share is not None:
+        if E * share["chips"] != share["n_routed_experts"]:
+            raise BenchError(
+                f"{c['name']}: {key} {E} x expert_share.chips "
+                f"{share['chips']} is not expert_share.n_routed_experts "
+                f"{share['n_routed_experts']}")
+        if key not in c.get("reduced", []):
+            raise BenchError(f"{c['name']}: {key} is this chip's share of "
+                             "the experts and must be listed in reduced")
+    return E
+
+
+def _rope_scaling(c: dict):
+    rs = c.get("rope_scaling")
+    if rs is None:
+        return None
+    kind = rs.get("type", rs.get("rope_type"))
+    if kind != "yarn":
+        raise BenchError(f"{c['name']}: rope_scaling type {kind!r} is not "
+                         "described (yarn only)")
+    return dict(rs)
 
 
 def model_of(c: dict) -> dict:
     """The sizes of a configuration file, under the names the reference and
-    ``bench/work.py`` use."""
+    ``bench/work.py`` use. A shape that these names cannot describe is
+    refused, naming its key."""
     if c.get("norm_type", "rms_norm") != "rms_norm":
         raise BenchError(f"{c['name']}: norm_type {c['norm_type']!r} has no "
                          "reference")
+    if c.get("moe_layer_freq", 1) != 1:
+        _refuse(c, "moe_layer_freq", "only every layer after the leading "
+                "dense ones is described as an expert layer")
+    for key in ("n_group", "topk_group"):
+        if (c.get(key) or 1) > 1:
+            _refuse(c, key, "grouped expert choice is not described")
+    if (c.get("num_nextn_predict_layers") or 0) > 0:
+        _refuse(c, "num_nextn_predict_layers", "multi-token prediction "
+                "layers are not described")
+    scoring = c.get("scoring_func", "softmax")
+    if scoring not in _SCORING:
+        _refuse(c, "scoring_func", f"not one of {_SCORING}")
+    if c.get("topk_method") not in _TOPK_METHOD:
+        _refuse(c, "topk_method", f"not one of {list(_TOPK_METHOD)}")
+    if "quantization_config" in c:
+        _refuse(c, "quantization_config", "weights are made as OVSF alphas "
+                "in torch_dtype; a quantised weight format is not described")
     d, H = c["hidden_size"], c["num_attention_heads"]
+    d_ff = c["intermediate_size"]
+    E = _expert_count(c)
+    mla = None
+    if c.get("kv_lora_rank") is not None:
+        mla = {k: c.get(k) for k in MLA_KEYS}
+        if not mla["q_lora_rank"]:
+            _refuse(c, "q_lora_rank", "latent attention without a query "
+                    "latent is not described")
     return {
         "n_layers": c["num_hidden_layers"], "d_model": d, "n_heads": H,
         "n_kv_heads": c.get("num_key_value_heads", H),
-        "head_dim": c.get("head_dim") or d // H,
-        "d_ff": c["intermediate_size"], "vocab": c["vocab_size"],
+        # latent attention has no single head width: its five widths
+        # are under "mla"
+        "head_dim": None if mla else c.get("head_dim") or d // H,
+        "d_ff": d_ff, "vocab": c["vocab_size"],
         "rope_theta": float(c["rope_theta"]),
         "norm_eps": float(c.get("rms_norm_eps", c.get("norm_epsilon"))),
-        "n_experts": c.get("num_experts", 0),
+        "n_experts": E,
         "top_k": c.get("num_experts_per_tok", 0),
         "norm_topk_prob": c.get("norm_topk_prob", False),
         "mlp": _MLP[c["hidden_act"]],
         "ovsf": c["ovsf"],
+        "router_experts": (c["expert_share"]["n_routed_experts"]
+                           if "expert_share" in c else E),
+        "n_shared_experts": c.get("n_shared_experts") or 0,
+        "moe_d_ff": c.get("moe_intermediate_size") or d_ff,
+        "first_dense": c.get("first_k_dense_replace") or 0,
+        "mla": mla,
+        "router_scoring": scoring,
+        "router_bias": _TOPK_METHOD[c.get("topk_method")],
+        "routed_scaling": float(c.get("routed_scaling_factor") or 1.0),
+        "rope_scaling": _rope_scaling(c),
     }
+
+
+def _program_fields(m: dict) -> dict:
+    """The fields beyond the dense and softmax-routed block that a program's
+    ModelConfig must carry to run a configuration: field -> (the file's
+    value, its default, the file's key). A program without a field runs the
+    default."""
+    rs = m["rope_scaling"]
+    mla = m["mla"] or {}
+    out = {
+        "router_experts": (m["router_experts"], m["n_experts"],
+                           "expert_share.n_routed_experts"),
+        "n_shared_experts": (m["n_shared_experts"], 0, "n_shared_experts"),
+        "moe_d_ff": (m["moe_d_ff"], m["d_ff"], "moe_intermediate_size"),
+        "first_dense_layers": (m["first_dense"], 0, "first_k_dense_replace"),
+        "router_scoring": (m["router_scoring"], "softmax", "scoring_func"),
+        "router_bias": (m["router_bias"], False, "topk_method"),
+        "routed_scaling": (m["routed_scaling"], 1.0,
+                           "routed_scaling_factor"),
+        "rope_scaling": (None if rs is None else tuple(sorted(rs.items())),
+                         None, "rope_scaling"),
+    }
+    out.update({k: (mla.get(k), None, k) for k in MLA_KEYS})
+    return out
 
 
 def program_config(c: dict, m: dict):
@@ -177,15 +279,25 @@ def program_config(c: dict, m: dict):
         cfg = cfg.replace(ovsf=dataclasses.replace(cfg.ovsf,
                                                    **over.pop("ovsf")))
     cfg = cfg.replace(**over)
+    fields = _program_fields(m)
+    lacks = {f: key for f, (v, default, key) in fields.items()
+             if not hasattr(cfg, f) and v != default}
+    if lacks:
+        raise BenchError(f"{c['name']}: the program's config has no field "
+                         f"for what the file states (field: file key): "
+                         f"{lacks}")
     want = {"n_layers": m["n_layers"], "d_model": m["d_model"],
             "n_heads": m["n_heads"], "n_kv_heads": m["n_kv_heads"],
-            "hd": m["head_dim"], "d_ff": m["d_ff"], "vocab": m["vocab"],
+            "d_ff": m["d_ff"], "vocab": m["vocab"],
             "rope_theta": m["rope_theta"], "norm_eps": m["norm_eps"],
             "n_experts": m["n_experts"], "top_k": m["top_k"],
             "mlp_gated": m["mlp"] == "swiglu", "dtype": c["torch_dtype"],
             "qkv_bias": bool(c.get("use_bias", c.get("attention_bias"))),
-            "tie_embeddings": bool(c.get("tie_word_embeddings")),
-            "n_shared_experts": 0}
+            "tie_embeddings": bool(c.get("tie_word_embeddings"))}
+    if m["mla"] is None:
+        want["hd"] = m["head_dim"]
+    want.update({f: v for f, (v, _d, _k) in fields.items()
+                 if hasattr(cfg, f)})
     got = {k: getattr(cfg, k) for k in want}
     ov = cfg.ovsf
     want.update(ovsf_rho=m["ovsf"]["rho"], ovsf_seg=m["ovsf"]["seg_len"],
@@ -479,15 +591,25 @@ def gaps(rows, tokens) -> list:
             for row, t in zip(rows, tokens)]
 
 
-def check(cell: Cell, params, served: Served, seed: int,
+def reference_of(cell: Cell):
+    """The configuration's plain reference (``modules.reference``), which
+    refuses a model it does not compute before any weight is made."""
+    ref = load_module(HERE / f"{cell.config['modules']['reference']}.py",
+                      "bench_reference")
+    try:
+        ref.check_model(cell.model)
+    except ValueError as e:
+        raise BenchError(f"{cell.config['name']}: {e}") from None
+    return ref
+
+
+def check(cell: Cell, ref, params, served: Served, seed: int,
           control: bool = False) -> dict:
-    """Score the sampled served tokens against the reference. With
+    """Score the sampled served tokens against the reference ``ref``. With
     ``control``, also read the control: the reference at the precision
     below the configuration's, at the same positions, scored under
     ``res['control']`` as the program is."""
     import numpy as np
-    ref = load_module(HERE / f"{cell.config['modules']['reference']}.py",
-                      "bench_reference")
     rids = sample_for_check(served, cell.traffic["check"], seed)
     if not rids:
         return {"tokens": 0}
@@ -653,6 +775,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     compile_clock()
     dev = device_info(int(cell.workload["chips"]), need_tpu)
     pcfg = program_config(cell.config, cell.model)
+    ref = reference_of(cell)
     from repro.models import registry as R
     wmod = load_module(HERE / f"{cell.config['modules']['weights']}.py",
                        "bench_weights")
@@ -693,7 +816,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     else:
         result["metrics"] = end_to_end_metrics(cell, served)
     result["device"] = device
-    scored = check(cell, params, served, seed, control=control)
+    scored = check(cell, ref, params, served, seed, control=control)
     extra["scored"] = scored
     checks = checks_of(cell, served, scored)
     result["correct"] = is_correct(checks)

@@ -159,7 +159,9 @@ class _Ctx:
     trace = object()
     trace_window = (0.0, 10.0)
     model = {"d_model": 128, "n_heads": 4, "n_kv_heads": 4, "head_dim": 32,
-             "n_experts": 8, "d_ff": 64, "mlp": "swiglu",
+             "n_experts": 8, "d_ff": 64, "mlp": "swiglu", "n_layers": 2,
+             "router_experts": 8, "n_shared_experts": 0, "moe_d_ff": 64,
+             "first_dense": 0, "mla": None,
              "ovsf": {"rho": 0.5, "seg_len": 16, "min_dim": 32}}
 
 

@@ -20,7 +20,8 @@ from pathlib import Path  # noqa: E402
 
 SMOKE = {"n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv_heads": 2,
          "head_dim": 32, "d_ff": 256, "vocab": 512, "mlp": "gelu_tanh",
-         "n_experts": 0, "top_k": 0,
+         "n_experts": 0, "top_k": 0, "router_experts": 0,
+         "n_shared_experts": 0, "moe_d_ff": 256, "first_dense": 0, "mla": None,
          "ovsf": {"rho": 0.5, "seg_len": 16, "min_dim": 32}}
 
 
@@ -57,7 +58,8 @@ def test_ovsf_flops_equal_the_spectral_gemm(M):
 
 
 def test_experts_count_top_k_not_capacity():
-    m = dict(SMOKE, mlp="swiglu", n_experts=8, top_k=2, d_ff=64)
+    m = dict(SMOKE, mlp="swiglu", n_experts=8, top_k=2, d_ff=64,
+             router_experts=8, moe_d_ff=64)
     st = work.StepTokens(n_tokens=10, ctx_sum=0, n_emit=0)
     w = work.step_work(m, st)
     d, f, J_d, J_f = 128, 64, 64, 32
@@ -128,3 +130,143 @@ def test_counter_and_clock_readers():
     ctx.window = window.WindowCounts(10.0, 0, [], [])
     assert all(read(n) is None for n in ("padding_eff", "step_ms",
                                          "ttft_p90_s", "idle_share"))
+
+
+# step_work of the two real configurations, recorded from the harness
+# before it read latent attention, shared experts or leading dense layers:
+# (flops, bytes) on each StepTokens below
+STEPS = {"decode": work.StepTokens(128, 128 * 600, 128),
+         "mixed": work.StepTokens(256, 32 * 700 + 224 * (200 + 113), 33),
+         "one": work.StepTokens(1, 1, 1)}
+PARENT_WORK = {
+    ("olmoe_1b_7b", "decode"): (174415937536.0, 7312703488.0),
+    ("olmoe_1b_7b", "mixed"): (294876872704.0, 7680506112.0),
+    ("olmoe_1b_7b", "one"): (1284112384.0, 1287463168.0),
+    ("starcoder2_15b_pp4", "decode"): (587420663808.0, 4711309312.0),
+    ("starcoder2_15b_pp4", "mixed"): (1025140850688.0, 4955082752.0),
+    ("starcoder2_15b_pp4", "one"): (4442013696.0, 4444971008.0),
+}
+# the ovsf_gemm_roofline and mfu readers on all three steps (a 0.5-s kernel
+# in a 1-s traced window, a 2-s window), recorded alike
+PARENT_READS = {"olmoe_1b_7b": (0.24614009279609275, 0.11943576716345178),
+                "starcoder2_15b_pp4": (2.9991472136752138,
+                                       0.41040698685076143)}
+
+
+def _real(name):
+    return model_of(load_json(Path(ROOT) / "bench" / "configs"
+                              / f"{name}.json"))
+
+
+@pytest.mark.parametrize("name,step", sorted(PARENT_WORK))
+def test_step_work_of_the_real_configs_is_bit_identical(name, step):
+    w = work.step_work(_real(name), STEPS[step])
+    assert (w.flops, w.bytes) == PARENT_WORK[(name, step)]
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_READS))
+def test_work_readers_of_the_real_configs_are_bit_identical(name):
+    from bench import trace
+    m = _real(name)
+    ctx = types.SimpleNamespace(
+        model=m, peaks={"bf16_flops": 197e12, "hbm_bytes_s": 819e9},
+        window_s=2.0, steps=3, step_tokens=list(STEPS.values()),
+        valid_tokens=0, batch_tokens=0,
+        trace=trace.Trace({0: [trace.Event("ovsf_gemm.3", 0.0, 0.5)]}, []),
+        trace_window=(0.0, 1.0))
+    root = Path(ROOT)
+    got = (metric_reader(root, "ovsf_gemm_roofline").read(ctx),
+           metric_reader(root, "mfu").read(ctx))
+    assert got == PARENT_READS[name]
+
+
+def _kimi():
+    return model_of(load_json(Path(__file__).parent / "data"
+                              / "kimi_k2_pp5_ep48.json"))
+
+
+def _ovsf(d_in, d_out, M):
+    """OVSF linear at rho 0.5 with L0 16: J = d_in / 2 kept codes."""
+    J = d_in // 2
+    return (2 * M * J * d_out, J * d_out * 2 + J * 4 + M * (d_in + d_out) * 2)
+
+
+def _dense(d_in, d_out, M):
+    return (2 * M * d_in * d_out, d_in * d_out * 2 + M * (d_in + d_out) * 2)
+
+
+def _sum(*parts):
+    return tuple(sum(p[i] for p in parts) for i in (0, 1))
+
+
+def _kimi_by_hand(n, ctx_sum, n_emit, reached):
+    """The Kimi-K2 cut's step counted from its published widths: 12 layers,
+    the first dense, 8 of 384 experts held, one shared expert. Each token's
+    8 picks land here with chance 8/384 each, so n / 6 picks are routed
+    here; ``reached`` is the experts whose alphas are read."""
+    d, H, f, fe = 7168, 64, 18432, 2048
+    attn = _sum(_ovsf(d, 1536, n),                     # q_a
+                _ovsf(1536, H * (128 + 64), n),        # q_b
+                _ovsf(d, 512 + 64, n),                 # kv_a
+                _ovsf(512, H * (128 + 128), n),        # kv_b
+                _ovsf(H * 128, d, n))                  # o
+    scores = (ctx_sum * (2 * H * (128 + 64) + 2 * H * 128), 0)
+    dense_mlp = _sum(_ovsf(d, f, n), _ovsf(d, f, n), _ovsf(f, d, n))
+    shared = _sum(_ovsf(d, fe, n), _ovsf(d, fe, n), _ovsf(fe, d, n))
+    routed = n / 6
+    experts = (0, 0)
+    for d_in, d_out in ((d, fe), (d, fe), (fe, d)):
+        J = d_in // 2
+        experts = _sum(experts, (2 * routed * J * d_out,
+                                 reached * J * d_out * 2 + J * 4
+                                 + routed * (d_in + d_out) * 2))
+    router = _dense(d, 384, n)
+    dense_layer = _sum(attn, scores, dense_mlp)
+    moe_layer = _sum(attn, scores, shared, experts, router)
+    return _sum(dense_layer, *[moe_layer] * 11, _dense(d, 20480, n_emit))
+
+
+@pytest.mark.parametrize("st,reached", [
+    # decode: 64/6 = 10.7 picks here, more than the 8 experts: all read
+    (work.StepTokens(64, 64 * 1000, 64), 8),
+    # mixed: 42.7 picks here, all 8 read
+    (work.StepTokens(256, 32 * 900 + 224 * (512 + 112), 33), 8),
+    # one token: 1/6 of a pick here, 1/6 of an expert's alphas expected
+    (work.StepTokens(1, 700, 1), 1 / 6)])
+def test_step_work_of_the_kimi_cut_is_its_hand_count(st, reached):
+    w = work.step_work(_kimi(), st)
+    flops, nbytes = _kimi_by_hand(st.n_tokens, st.ctx_sum, st.n_emit,
+                                  reached)
+    assert w.flops == pytest.approx(flops, rel=1e-12)
+    assert w.bytes == pytest.approx(nbytes, rel=1e-12)
+
+
+@pytest.mark.parametrize("E,R,k", [(8, 384, 8), (64, 64, 8), (16, 128, 2)])
+def test_expert_bytes_estimate_the_experts_reached(E, R, k):
+    """min(E, routed) experts' weights are read: at or above the expected
+    number of distinct held experts that n tokens' top-k picks reach under
+    uniform routing, and above it by at most routed**2 / (2E)."""
+    m = dict(_kimi(), n_experts=E, router_experts=R, top_k=k)
+    li = work.expert_linears(m)
+    one = sum(work.kept_codes(x.d_in, m["ovsf"]) * x.d_out * 2 for x in li)
+    for n in (0, 1, 3, 10, 100, 1000, 10000):
+        router = work.dense_linear(work.Linear("router", m["d_model"], R), n)
+        routed = n * k * E / R
+        acts = sum(routed * (x.d_in + x.d_out) * 2
+                   + work.kept_codes(x.d_in, m["ovsf"]) * 4 for x in li)
+        spent = work.expert_work(m, n).bytes - router.bytes
+        reached = (spent - acts) / one if n else 0.0
+        expected = E * (1 - (1 - k / R) ** n)
+        assert expected - 1e-9 <= reached == pytest.approx(min(E, routed))
+        assert reached - expected <= routed ** 2 / (2 * E) + 1e-9
+    assert work.expert_work(m, 0).bytes == 0
+
+
+def test_the_kimi_cut_counts_each_linear_in_the_layers_that_hold_it():
+    counts = {li.name: n for li, n in work.layer_linears(_kimi())}
+    assert counts == {"attn_q_a": 12, "attn_q_b": 12, "attn_kv_a": 12,
+                      "attn_kv_b": 12, "attn_o": 12, "mlp_up": 1,
+                      "mlp_down": 1, "mlp_gate": 1, "shared_up": 11,
+                      "shared_down": 11, "shared_gate": 11}
+    assert [li.d_in for li in work.expert_linears(_kimi())] == \
+        [7168, 7168, 2048]
