@@ -503,12 +503,21 @@ def serve(cell: Cell, params, pcfg, hw: str, seed: int, seconds: float,
     for _ in range(clients):
         send()
     first = list(range(clients))
-    while not all(reqs[i].out_tokens or reqs[i].done for i in first):
+    # a loop that opens at steady state also waits out the queue that the
+    # first prompts' prefill left behind
+    steady = cell.traffic.get("start") == "steady"
+    warm_steps = 0
+    while not all(reqs[i].out_tokens or reqs[i].done for i in first) \
+            or (steady and len(eng.scheduler)):
         eng.step()
         settle(False)
+        warm_steps += 1
+        if warm_steps > 20 * clients:
+            raise BenchError("the warm-up's queue does not drain")
     setup["warmup_s"] = time.perf_counter() - tw
     st = eng.stats
     valid0, batch0, rec0 = st.packed_tokens, st.padded_tokens, st.recoveries
+    pre0, pages0 = st.preemptions, eng.core.pager.used_pages
     c0, n0, h0 = clock.seconds, clock.compiles, clock.hits
     setup["compile_s"] = clock.seconds
     setup["compiles"] = clock.compiles
@@ -541,6 +550,10 @@ def serve(cell: Cell, params, pcfg, hw: str, seed: int, seconds: float,
     setup["compile_s_in_window"] = clock.seconds - c0
     setup["cache_hits_in_window"] = clock.hits - h0
     st = eng.stats
+    setup["kv_pages"] = {"pool": eng.core.pager.P, "at_start": pages0,
+                         "at_end": eng.core.pager.used_pages,
+                         "peak": st.kv_pages_used}
+    setup["preemptions_in_window"] = st.preemptions - pre0
     mem = 0
     for d in jax.local_devices()[:int(cell.workload["chips"])]:
         ms = d.memory_stats() or {}
@@ -594,7 +607,8 @@ def gaps(rows, tokens) -> list:
 def reference_of(cell: Cell):
     """The configuration's plain reference (``modules.reference``), which
     refuses a model it does not compute before any weight is made."""
-    ref = load_module(HERE / f"{cell.config['modules']['reference']}.py",
+    ref = load_module(cell.root / "bench" /
+                      f"{cell.config['modules']['reference']}.py",
                       "bench_reference")
     try:
         ref.check_model(cell.model)
@@ -777,7 +791,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     pcfg = program_config(cell.config, cell.model)
     ref = reference_of(cell)
     from repro.models import registry as R
-    wmod = load_module(HERE / f"{cell.config['modules']['weights']}.py",
+    wmod = load_module(root / "bench" /
+                       f"{cell.config['modules']['weights']}.py",
                        "bench_weights")
     setup: dict = {}
     t = time.perf_counter()
@@ -860,7 +875,8 @@ def main(argv=None) -> int:
                            "cache_hits", "warmup_s", "compiles_in_window",
                            "compile_s_in_window", "cache_hits_in_window",
                            "buckets", "window_s", "window_steps",
-                           "longest_step_s", "longest_step_at")}),
+                           "longest_step_s", "longest_step_at",
+                           "kv_pages", "preemptions_in_window")}),
           flush=True)
     print("[bench] check: " + json.dumps(extra["scored"]), flush=True)
     if "control_checks" in extra:

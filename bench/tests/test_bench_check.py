@@ -93,3 +93,22 @@ def test_the_control_is_not_correct_at_the_limits(root, cell, seed):
     assert not R.is_correct(ctrl)
     gap = ctrl[_compared(cell)]
     assert gap["value"] > gap["limit"] >= checks[_compared(cell)]["value"]
+
+
+def test_a_run_that_opens_at_steady_state_is_checked(tmp_path):
+    """A loop that opens at steady state (``"start": "steady"``) runs end to
+    end and reports its page pool's fill; its sound run is correct, and the
+    same run with altered tokens is not."""
+    import json
+    root = tiny_cell.make_root(tmp_path)
+    path = root / "bench" / "traffic" / "tiny.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    start="steady")))
+    res, checks, extra = _run(root, "tiny_moe.chat", 2**31 + 29)
+    assert res["correct"], checks
+    assert extra["setup"]["kv_pages"]["pool"] >= \
+        extra["setup"]["kv_pages"]["peak"] > 0
+    assert extra["scored"]["requests"] >= 2
+    res, checks, _ = _run(root, "tiny_moe.chat", 2**31 + 29,
+                          alter=_alter_tokens)
+    assert not res["correct"]

@@ -19,6 +19,7 @@ import pytest  # noqa: E402
 
 from bench import reference  # noqa: E402
 from bench import run as R  # noqa: E402
+from repro.configs.base import OVSFConfig  # noqa: E402
 
 KIMI = Path(__file__).resolve().parent / "data" / "kimi_k2_pp5_ep48.json"
 YARN = {"beta_fast": 1, "beta_slow": 1, "factor": 32, "mscale": 1,
@@ -107,7 +108,66 @@ def test_the_expert_share_must_add_up_and_be_listed():
         R.model_of(c)
 
 
-def test_todays_kimi_program_is_refused_for_a_field_it_lacks():
+@dataclasses.dataclass(frozen=True)
+class Today:
+    """A program's config as today's registry has it: the fields
+    ``program_config`` compares for a dense, softmax-routed block (``hd``
+    is not compared under latent attention), ``ovsf`` and
+    ``capacity_factor``, at the Kimi cut's values, and none of
+    ``_program_fields``. The registry's own entry is not read."""
+    n_layers: int = 12
+    d_model: int = 7168
+    n_heads: int = 64
+    n_kv_heads: int = 64
+    d_ff: int = 18432
+    vocab: int = 20480
+    rope_theta: float = 50000.0
+    norm_eps: float = 1e-06
+    n_experts: int = 8
+    top_k: int = 8
+    mlp_gated: bool = True
+    dtype: str = "bfloat16"
+    qkv_bias: bool = False
+    tie_embeddings: bool = False
+    capacity_factor: float = 1.0    # top-8 of 8 experts held drops none
+    ovsf: OVSFConfig = OVSFConfig(enable=True, rho=0.5, seg_len=16,
+                                  min_dim=512)
+
+    def replace(self, **kw):
+        return dataclasses.replace(self, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class Carries(Today):
+    """Today's fields and every one of ``_program_fields``, at the Kimi
+    cut's values."""
+    router_experts: int = 384
+    n_shared_experts: int = 1
+    moe_d_ff: int = 2048
+    first_dense_layers: int = 1
+    router_scoring: str = "sigmoid"
+    router_bias: bool = True
+    routed_scaling: float = 2.827
+    rope_scaling: tuple = tuple(sorted(YARN.items()))
+    q_lora_rank: int = MLA["q_lora_rank"]
+    kv_lora_rank: int = MLA["kv_lora_rank"]
+    qk_nope_head_dim: int = MLA["qk_nope_head_dim"]
+    qk_rope_head_dim: int = MLA["qk_rope_head_dim"]
+    v_head_dim: int = MLA["v_head_dim"]
+
+
+def test_the_stubs_split_at_the_program_fields():
+    fields = set(R._program_fields(R.model_of(kimi())))
+    today = {f.name for f in dataclasses.fields(Today)}
+    assert not today & fields
+    assert {f.name for f in dataclasses.fields(Carries)} - today == fields
+
+
+def test_todays_kimi_program_is_refused_for_a_field_it_lacks(monkeypatch):
+    """A program config without the fields (the stub ``Today``, put in the
+    registry's place) is refused for the Kimi cut, naming each field."""
+    import repro.configs
+    monkeypatch.setattr(repro.configs, "get_config", lambda name: Today())
     c = kimi()
     with pytest.raises(R.BenchError, match="has no field") as e:
         R.program_config(c, R.model_of(c))
@@ -116,51 +176,21 @@ def test_todays_kimi_program_is_refused_for_a_field_it_lacks():
         assert field in str(e.value)
 
 
-def _carrying(**fields):
-    """The program's Kimi config as a config class that carries every field
-    the file states, at the file's values unless given."""
-    from repro.configs.base import ModelConfig, get_config
-
-    @dataclasses.dataclass(frozen=True)
-    class Carries(ModelConfig):
-        router_experts: int = 0
-        moe_d_ff: int = 0
-        first_dense_layers: int = 0
-        router_scoring: str = "softmax"
-        router_bias: bool = False
-        routed_scaling: float = 1.0
-        rope_scaling: tuple = None
-        q_lora_rank: int = None
-        kv_lora_rank: int = None
-        qk_nope_head_dim: int = None
-        qk_rope_head_dim: int = None
-        v_head_dim: int = None
-
-    base = get_config("kimi_k2_1t_a32b")
-    have = {f.name: getattr(base, f.name)
-            for f in dataclasses.fields(ModelConfig)}
-    want = dict(MLA, router_experts=384, moe_d_ff=2048, first_dense_layers=1,
-                router_scoring="sigmoid", router_bias=True,
-                routed_scaling=2.827,
-                rope_scaling=tuple(sorted(YARN.items())))
-    return Carries(**dict(have, **dict(want, **fields)))
-
-
 def test_a_program_that_carries_the_fields_is_held_to_them(monkeypatch):
     import repro.configs
     c = kimi()
     m = R.model_of(c)
     monkeypatch.setattr(repro.configs, "get_config",
-                        lambda name: _carrying())
+                        lambda name: Carries())
     cfg = R.program_config(c, m)
     assert (cfg.n_layers, cfg.n_experts, cfg.router_experts,
             cfg.n_shared_experts) == (12, 8, 384, 1)
     monkeypatch.setattr(repro.configs, "get_config",
-                        lambda name: _carrying(n_shared_experts=0))
+                        lambda name: Carries(n_shared_experts=0))
     with pytest.raises(R.BenchError, match="n_shared_experts"):
         R.program_config(c, m)
     monkeypatch.setattr(repro.configs, "get_config",
-                        lambda name: _carrying(kv_lora_rank=256))
+                        lambda name: Carries(kv_lora_rank=256))
     with pytest.raises(R.BenchError, match="kv_lora_rank"):
         R.program_config(c, m)
 
@@ -174,7 +204,7 @@ def test_the_default_reference_refuses_the_kimi_cut():
                 "router_bias", "routed_scaling", "rope_scaling",
                 "router_experts", "moe_d_ff"):
         assert f"'{key}'" in str(e.value)
-    cell = types.SimpleNamespace(config=dict(c, modules={
+    cell = types.SimpleNamespace(root=ROOT, config=dict(c, modules={
         "weights": "weights", "reference": "reference"}), model=m)
     with pytest.raises(R.BenchError, match="does not compute"):
         R.reference_of(cell)

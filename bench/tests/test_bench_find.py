@@ -59,6 +59,66 @@ def test_a_file_added_in_a_new_root_is_found(tmp_path):
         R.find_cell(root, "no.such")
 
 
+_OWN_REFERENCE = """
+from pathlib import Path
+
+LOG = Path(__file__).parent / "order.log"
+
+
+def check_model(m):
+    with open(LOG, "a") as f:
+        f.write("check_model\\n")
+
+
+def logits(params, m, seqs, rows, prec="f32"):
+    raise NotImplementedError
+"""
+
+_OWN_WEIGHTS = """
+from pathlib import Path
+
+LOG = Path(__file__).parent / "order.log"
+
+
+def make(layout, key, seg):
+    with open(LOG, "a") as f:
+        f.write("make\\n")
+    raise RuntimeError("weights made: the run stops here")
+"""
+
+
+def test_a_config_with_modules_of_its_own_is_found(tmp_path):
+    """A configuration added in a new root names a reference and a weights
+    module of its own: the harness loads them from that root, and calls the
+    reference's ``check_model`` before any weight is made."""
+    root = tiny_cell.make_root(tmp_path)
+    own = dict(tiny_cell.CONFIGS["tiny_moe"], name="tiny_moe_own",
+               modules={"weights": "own_weights",
+                        "reference": "own_reference"})
+    (root / "bench" / "configs" / "tiny_moe_own.json").write_text(
+        json.dumps(own))
+    (root / "bench" / "own_reference.py").write_text(_OWN_REFERENCE)
+    (root / "bench" / "own_weights.py").write_text(_OWN_WEIGHTS)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny_moe_own", "source": "test",
+                             "file": "bench/configs/tiny_moe_own.json",
+                             "reduced": [], "why": "t"})
+    bench["workloads"].append({"name": "tiny_moe_own.chat",
+                               "config": "tiny_moe_own", "traffic": "tiny",
+                               "chips": 1, "why": "t"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    cell = R.find_cell(root, "tiny_moe_own.chat")
+    assert cell.config["modules"]["reference"] == "own_reference"
+    ref = R.reference_of(cell)
+    assert Path(ref.__file__) == root / "bench" / "own_reference.py"
+    log = root / "bench" / "order.log"
+    log.unlink()
+    with pytest.raises(RuntimeError, match="weights made"):
+        R.run(root, "tiny_moe_own.chat", 1, 1.0, False, need_tpu=False,
+              cache=False)
+    assert log.read_text().splitlines() == ["check_model", "make"]
+
+
 def test_a_config_the_program_departs_from_is_refused():
     c = dict(tiny_cell.CONFIGS["tiny_dense"], rope_theta=5000.0)
     with pytest.raises(R.BenchError, match="rope_theta"):

@@ -23,7 +23,7 @@ def _draw(spec, seed, n):
     return [loop.next() for _ in range(n)]
 
 
-@pytest.mark.parametrize("name", ["chat", "codegen"])
+@pytest.mark.parametrize("name", ["chat", "codegen", "batch"])
 def test_same_seed_same_requests(name):
     spec = _spec(name)
     a, b = _draw(spec, 2**31 + 5, 70), _draw(spec, 2**31 + 5, 70)
@@ -32,10 +32,16 @@ def test_same_seed_same_requests(name):
         np.testing.assert_array_equal(x.prompt, y.prompt)
 
 
-@pytest.mark.parametrize("name", ["chat", "codegen"])
+def _first(spec):
+    """How many requests open the loop cut to a steady start."""
+    return spec["clients"] if spec.get("start") == "steady" else 0
+
+
+@pytest.mark.parametrize("name", ["chat", "codegen", "batch"])
 def test_other_seed_other_order_same_mix(name):
     spec = _spec(name)
-    a, b = _draw(spec, 1, 64), _draw(spec, 2, 64)
+    n0 = _first(spec)
+    a, b = _draw(spec, 1, n0 + 64)[n0:], _draw(spec, 2, n0 + 64)[n0:]
     assert [r.max_new for r in a] != [r.max_new for r in b]
     assert any(not np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
     # any 32 consecutive requests carry nearly the same work, whatever the
@@ -50,12 +56,13 @@ def test_other_seed_other_order_same_mix(name):
 
 
 def test_lengths_stay_in_bounds_and_fit_the_buffer():
-    for name in ("chat", "codegen"):
+    for name in ("chat", "codegen", "batch"):
         spec = _spec(name)
         for r in _draw(spec, 9, 3 * spec["clients"]):
             p, o = spec["prompt_tokens"], spec["output_tokens"]
             assert p["min"] <= len(r.prompt) <= p["max"]
-            assert o["min"] <= r.max_new <= o["max"]
+            least = 1 if r.index < _first(spec) else o["min"]
+            assert least <= r.max_new <= o["max"]
             assert len(r.prompt) + r.max_new <= spec["engine"]["buffer"]
 
 
@@ -65,3 +72,36 @@ def test_lognormal_median():
     assert traffic.quantile(d, 0.5) == 256
     assert traffic.quantile(d, 1e-9) == 32 and traffic.quantile(d, 1 - 1e-9) \
         == 768
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 11])
+def test_a_steady_start_leaves_what_a_steady_state_has_in_flight(seed):
+    """The first ``clients`` requests are cut to what is left of requests in
+    flight at steady state: on average half a size-biased output length,
+    E[L^2] / (2 E[L]), so that as many end in the first steps as at steady
+    state, 1 / E[L] a slot a step, and another seed leaves the same set of
+    lengths. Later requests are drawn as without it."""
+    spec = _spec("batch")
+    n = spec["clients"]
+    grid = [(i + 0.5) / 4096 for i in range(4096)]
+    lengths = np.array([traffic.quantile(spec["output_tokens"], q)
+                        for q in grid], np.float64)
+    left = [r.max_new for r in _draw(spec, seed, n)]
+    assert min(left) >= 1 and max(left) <= spec["output_tokens"]["max"]
+    residual = (lengths ** 2).mean() / (2 * lengths.mean())
+    assert np.mean(left) == pytest.approx(residual, rel=0.1)
+    ends = sum(m <= 80 for m in left)       # in 80 decode steps
+    assert ends == pytest.approx(80 * n / lengths.mean(), abs=2)
+    other = sorted(r.max_new for r in _draw(spec, seed + 1, n))
+    assert max(abs(a - b) for a, b in zip(sorted(left), other)) <= \
+        0.05 * spec["output_tokens"]["max"]
+    plain = {k: v for k, v in spec.items() if k != "start"}
+    a, b = _draw(spec, seed, 2 * n), _draw(plain, seed, 2 * n)
+    assert [r.max_new for r in a[n:]] == [r.max_new for r in b[n:]]
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x.prompt, y.prompt)
+
+
+def test_an_unknown_start_is_refused():
+    with pytest.raises(ValueError, match="unknown start"):
+        traffic.ClosedLoop(dict(_spec("batch"), start="warm"), 1, 100)

@@ -51,10 +51,14 @@ TRAFFIC = {"loop": "closed", "clients": 4,
 
 def make_root(tmp: Path) -> Path:
     """BENCHMARK.json with cells tiny_moe.chat and tiny_dense.chat, their
-    configuration and traffic files, and the repository's metric readers."""
+    configuration and traffic files, and the repository's metric readers,
+    weights and reference."""
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir(parents=True)
     os.symlink(REPO / "bench" / "metrics", tmp / "bench" / "metrics")
+    for module in ("weights", "reference"):
+        os.symlink(REPO / "bench" / f"{module}.py",
+                   tmp / "bench" / f"{module}.py")
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     bench["configs"], bench["workloads"] = [], []
     for name, c in CONFIGS.items():

@@ -9,6 +9,15 @@ consecutive requests then covers each distribution evenly, so every seed
 sends the same mix of sizes to the window, in another order, and runs differ
 by arrangement and not by how much work they were given. Token ids are
 uniform over the vocabulary.
+
+With ``"start": "steady"`` the first ``clients`` requests are what a look
+at the loop's steady state would find in flight: request i is left the
+tokens a request in flight has still to serve, the quantile frac(w + i /
+clients) of their distribution, P(R = r) proportional to P(L >= r), with
+the offset w drawn from the seed. So requests end, and their successors'
+prompts join the batch, at the steady rate from the first step, and every
+seed leaves the same set of lengths. Their contexts hold their prompts
+only: the tokens they would already have served are not prefilled.
 """
 from __future__ import annotations
 
@@ -62,6 +71,17 @@ class ClosedLoop:
         self._u, self._v = rng_for(seed, 0).random(2)
         self._tokens = rng_for(seed, 1)
         self._n = 0
+        self._steady = None
+        if spec.get("start", "empty") == "steady":
+            grid = (np.arange(4096) + 0.5) / 4096
+            lengths = np.sort([quantile(spec["output_tokens"], q)
+                               for q in grid])
+            left = np.arange(1, lengths[-1] + 1)
+            in_flight = len(lengths) - np.searchsorted(lengths, left)
+            self._steady = (left, np.cumsum(in_flight) / in_flight.sum(),
+                            rng_for(seed, 4).random())
+        elif spec.get("start", "empty") != "empty":
+            raise ValueError(f"unknown start {spec['start']!r}")
 
     def next(self) -> Req:
         i = self._n
@@ -69,6 +89,11 @@ class ClosedLoop:
         qo = min(max((self._v + i * _H) % 1.0, _EPS), 1.0 - _EPS)
         plen = quantile(self.spec["prompt_tokens"], qp)
         max_new = quantile(self.spec["output_tokens"], qo)
+        clients = int(self.spec["clients"])
+        if self._steady is not None and i < clients:
+            left, cum, w = self._steady
+            q = (w + i / clients) % 1.0
+            max_new = left[min(np.searchsorted(cum, q), len(left) - 1)]
         prompt = self._tokens.integers(0, self.vocab, plen, dtype=np.int32)
         self._n += 1
         return Req(i, prompt, int(max_new))

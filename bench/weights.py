@@ -9,9 +9,16 @@ program with ``jax.eval_shape``; the values are the benchmark's own:
 * ``alphas``: N(0, 1 / (d_in * n_keep)), so that each generated weight has
   variance 1 / d_in, as a dense fan-in initialisation would;
 * ``w`` (dense linears): N(0, 1 / d_in); ``table`` (embedding): N(0, 1);
-* ``scale`` (norms): 1 + N(0, 0.1^2).
+* ``scale`` (norms): 1 + N(0, 0.1^2);
+* ``router/bias`` (a router's per-expert correction bias, ``topk_method``
+  ``noaux_tc``): N(0, 0.1^2). With x at unit RMS and the router's ``w`` at
+  N(0, 1/d), the logits are about N(0, 1) and the sigmoid scores spread by
+  about 0.2, so a bias of 0.1 moves some top-k picks: a program that picks
+  by score alone, or weights its picks by score plus bias, departs from the
+  reference.
 
-A leaf of any other name is an error: a layout this file has no rule for.
+A leaf of any other name, or a ``bias`` outside a ``router``, is an error: a
+layout this file has no rule for.
 
 Stacked leaves (a leading layer or expert axis) are drawn one slice at a
 time, so the call's temporaries stay the size of one layer.
@@ -74,6 +81,10 @@ def _make(key, spec, seg: int, path: str = ""):
     if name == "scale":
         return (1.0 + 0.1 * jax.random.normal(k, shape, jnp.float32)
                 ).astype(dtype)
+    if path.endswith("/router/bias"):
+        return _sliced(lambda k, s: (jax.random.normal(k, s, jnp.float32)
+                                     * 0.1).astype(dtype),
+                       k, shape, len(shape) - 1)
     raise ValueError(f"no rule to make weight leaf {path!r}")
 
 
