@@ -59,45 +59,99 @@ def _expert_bank_init(key: jax.Array, cfg: ModelConfig, E: int, d: int, f: int,
     return out
 
 
-def _expert_matmul(p: dict, x: jnp.ndarray, cfg: ModelConfig,
-                   name: str = "") -> jnp.ndarray:
-    """x: (G, E, C, d_in) batched per-expert GEMM -> (G, E, C, d_out)."""
-    if "alphas" in p:
+# What each distinct expert-bank call traced ran, noted at trace time
+# (``expert_notes``).
+_EXPERT_NOTES: dict = {}
+
+
+def expert_notes() -> list:
+    """One dict per distinct expert-bank call this process has traced: the
+    bank's weight type ``name``, its ``path`` (``dense``: stored weights;
+    else the mapper's path, or ``cfg.ovsf.exec_path`` unplanned), the
+    ``experts``, the ``rows`` each expert contracts, ``d_in``, ``d_out``,
+    ``j`` (the alpha rows; 0 for a dense bank) and ``dense_w_bytes``, the
+    dense W the call materialises (0 but on the ``materialize`` path)."""
+    return [dict(n) for n in _EXPERT_NOTES.values()]
+
+
+def _bank_path(p: dict, cfg: ModelConfig, name: str) -> str:
+    if "alphas" not in p:
+        return "dense"
+    plan = L.layer_plan(cfg, name)
+    return plan.path if plan is not None else cfg.ovsf.exec_path
+
+
+def _segment_basis(idx: jnp.ndarray, d_in: int, dtype) -> jnp.ndarray:
+    """(d_in, J) block-diagonal +-1 matrix of segmented codes (n_seg, n_keep):
+    segment s's block holds the columns idx[s] of the L0-point Hadamard
+    matrix, so x @ basis is each segment's WHT at its kept codes."""
+    ns, nk = idx.shape
+    cols = ovsf.hadamard_matrix(d_in // ns, dtype)[:, idx]   # (L0, ns, nk)
+    return jnp.einsum("lsk,st->sltk", cols, jnp.eye(ns, dtype=dtype)
+                      ).reshape(d_in, ns * nk)
+
+
+def _bank_input(p: dict, x: jnp.ndarray, cfg: ModelConfig,
+                name: str) -> jnp.ndarray:
+    """What a bank contracts, per row of x (..., d_in): for a spectral bank
+    the kept-code coefficients (..., J), summed in float32 and rounded once
+    to x's dtype; else x itself. The codes are shared by every expert of
+    the bank, so the transform commutes with dispatch. Segmented codes take
+    one GEMM against their +-1 basis, whose products are exact: on a v5e
+    an OLMoE layer of 256 tokens ran in 2.0 ms so, and in 126 ms with a
+    butterfly over the 16-wide segments and a gather of the kept codes."""
+    if _bank_path(p, cfg, name) != "spectral":
+        return x
+    idx = p["idx"]
+    if idx.ndim == 1:
+        return kops.spectral_transform(x.astype(jnp.float32), idx
+                                       ).astype(x.dtype)
+    return jnp.einsum("...d,dj->...j", x,
+                      _segment_basis(idx, x.shape[-1], x.dtype),
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _contract(x: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
+    """(G, E, C, J) codes x (E, J, d_out) alphas, accumulated in float32 and
+    rounded once to x's dtype. The CPU backend has no batched bf16 dot with
+    a float32 result; it takes the operands in float32, whose products of
+    bf16 values are exact, so the sum is the same float32 accumulation."""
+    if not kops.on_tpu():
+        x32, a32 = x.astype(jnp.float32), a.astype(jnp.float32)
+        return jnp.einsum("gecj,ejn->gecn", x32, a32).astype(x.dtype)
+    return jnp.einsum("gecj,ejn->gecn", x, a,
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _expert_matmul(p: dict, x: jnp.ndarray, cfg: ModelConfig, name: str,
+                   d_in: int) -> jnp.ndarray:
+    """x: (G, E, C, k) per-expert rows of the bank's ``_bank_input`` (k = J
+    for a spectral bank, else d_in) -> (G, E, C, d_out).
+
+    A spectral bank contracts the codes against its alphas (bf16 operands
+    in a bf16 model, float32 accumulation): no dense W exists. Otherwise an
+    OVSF bank is decompressed into (E, d_in, d_out) and contracted densely;
+    the fused path has no per-expert kernel and the mapper plans none."""
+    path = _bank_path(p, cfg, name)
+    w = p["w"] if path == "dense" else p["alphas"]
+    E, j, d_out = w.shape
+    dense_w = 0 if path in ("dense", "spectral") else \
+        E * d_in * d_out * w.dtype.itemsize
+    note = dict(name=name, path=path, experts=E,
+                rows=x.shape[0] * x.shape[2], d_in=d_in, d_out=d_out,
+                j=0 if path == "dense" else j, dense_w_bytes=dense_w)
+    _EXPERT_NOTES.setdefault(tuple(note.items()), note)
+    if path == "spectral":
+        return _contract(x, w.astype(x.dtype))
+    if path != "dense":
         plan = L.layer_plan(cfg, name)
-        path = plan.path if plan is not None else cfg.ovsf.exec_path
-        # spectral path vectorised over experts (shared idx)
-        if path == "spectral":
-            d_in = x.shape[-1]
-            idx = p["idx"]
-            if idx.ndim == 2:                                    # segmented
-                ns, nk = idx.shape
-                L0 = d_in // ns
-                xs = x.reshape(x.shape[:-1] + (ns, L0))
-                xh = kops.fwht(xs, use_pallas=False)
-                xk = jnp.take_along_axis(
-                    xh, jnp.broadcast_to(idx, xh.shape[:-1] + (nk,)), axis=-1)
-                xk = xk.reshape(x.shape[:-1] + (ns * nk,))
-            else:
-                Lc = ovsf.next_pow2(d_in)
-                if Lc != d_in:
-                    x = jnp.pad(x, ((0, 0),) * (x.ndim - 1)
-                                + ((0, Lc - d_in),))
-                xh = kops.fwht(x)
-                xk = jnp.take(xh, idx, axis=-1)                  # (G, E, C, J)
-            return jnp.einsum("gecj,ejn->gecn", xk,
-                              p["alphas"].astype(xk.dtype))
-        # No per-expert fused (TiWGen) kernel yet: a plan with path="fused"
-        # falls back to the decompress dataflow below (see ROADMAP open
-        # items). Numerics are unchanged; only the modeled HBM win is lost.
         if plan is not None and plan.cache_weights:
-            W = kops.cached_decompress(
-                p["alphas"], p["idx"], x.shape[-1],
-                cache_key=plan.cache_key or name)                 # (E, d_in, d_out)
+            w = kops.cached_decompress(w, p["idx"], d_in,
+                                       cache_key=plan.cache_key or name)
         else:
-            W = jax.vmap(lambda a: kops.decompress(a, p["idx"], x.shape[-1])
-                         )(p["alphas"])                           # (E, d_in, d_out)
-        return jnp.einsum("gecd,edn->gecn", x, W.astype(x.dtype))
-    return jnp.einsum("gecd,edn->gecn", x, p["w"].astype(x.dtype))
+            w = jax.vmap(lambda a: kops.decompress(a, p["idx"], d_in))(w)
+    return jnp.einsum("gecd,edn->gecn", x, w.astype(x.dtype))
 
 
 MOE_GROUP = 1024   # tokens per routing group; aligned to data shards for
@@ -144,13 +198,28 @@ def moe_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray
         disp = jnp.einsum("gtke,gtkc->gtec", oh, pos_oh)        # (G,g,E,cap)
         comb = jnp.einsum("gtk,gtke,gtkc->gtec", gate_vals.astype(xg.dtype),
                           oh, pos_oh)
-        ex_in = jnp.einsum("gtec,gtd->gecd", disp, xg)          # (G,E,cap,d)
 
     with jax.named_scope("moe.experts"):
-        gg = _expert_matmul(p["gate"], ex_in, cfg, "expert_gate")
-        uu = _expert_matmul(p["up"], ex_in, cfg, "expert_up")
+        # gate and up take their inputs per token, before dispatch: a
+        # spectral bank's codes once per token, not once per expert slot
+        xs = [_bank_input(p[nm], xg, cfg, f"expert_{nm}")
+              for nm in ("gate", "up")]
+
+    with jax.named_scope("moe.dispatch"):
+        if xs[0] is xs[1]:
+            gin = uin = jnp.einsum("gtec,gtd->gecd", disp, xg)  # (G,E,cap,d)
+        else:
+            both = jnp.einsum("gtec,gtd->gecd", disp,
+                              jnp.concatenate(xs, axis=-1))
+            gin, uin = jnp.split(both, [xs[0].shape[-1]], axis=-1)
+
+    with jax.named_scope("moe.experts"):
+        gg = _expert_matmul(p["gate"], gin, cfg, "expert_gate", d)
+        uu = _expert_matmul(p["up"], uin, cfg, "expert_up", d)
         h = jax.nn.silu(gg.astype(jnp.float32)).astype(uu.dtype) * uu
-        ex_out = _expert_matmul(p["down"], h, cfg, "expert_down")
+        ex_out = _expert_matmul(
+            p["down"], _bank_input(p["down"], h, cfg, "expert_down"), cfg,
+            "expert_down", h.shape[-1])
 
     with jax.named_scope("moe.combine"):
         y = jnp.einsum("gtec,gecd->gtd", comb, ex_out).reshape(G * g, d)

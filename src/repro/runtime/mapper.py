@@ -29,7 +29,9 @@ Default candidate paths are the paper's two regimes (``fused`` vs
 ``materialize``).  The beyond-paper ``spectral`` path (activation-domain
 transform) is opt-in via ``paths=`` because it reshapes the dataflow of the
 consumer GEMM rather than the generator, and its win profile overlaps with
-``fused`` on decode shapes.
+``fused`` on decode shapes. Expert banks are the exception: they have no
+fused kernel, so ``plan_model`` weighs ``materialize`` against ``spectral``
+for them.
 """
 from __future__ import annotations
 
@@ -217,6 +219,15 @@ def _ceil8(n: int) -> int:
 # ---------------------------------------------------------------------------
 
 _LAYER_PREFIX = re.compile(r"^L\d+/")
+# perf_model names a GEMM that runs once per expert with a multiplicity
+# suffix ("expert_gatex64"); the model code passes the bare weight type.
+_MULTIPLICITY = re.compile(r"x\d+$")
+
+# Candidate paths of an OVSF target group whose model code runs fewer than
+# the trunk: an expert bank (models.moe) has no per-expert fused kernel, so
+# its choice is between decompressing the bank and the activation-domain
+# contraction against its alphas.
+_GROUP_PATHS = {"expert": ("materialize", "spectral")}
 
 # perf_model workload names -> the weight-type names the model code passes to
 # linear_apply (ssm.py registers its projections under the "mlp" OVSF target
@@ -233,7 +244,8 @@ def plan_model(cfg, shape, *, hw=pm.V5E, n_devices: int = 1,
     Expands the config into per-device GEMMs via ``pm.model_layers``,
     collapses them by weight type (transformer stacks are layer-homogeneous
     and scanned, so one plan per weight type), and classifies each with
-    ``classify_gemm``. ``weight_reuse`` defaults by workload kind: decode
+    ``classify_gemm`` among ``paths``, or among the paths its group's model
+    code runs (``_GROUP_PATHS``: expert banks). ``weight_reuse`` defaults by workload kind: decode
     serves frozen params (high reuse), train regenerates every step.
     ``hw`` accepts any registered HW target name (see ``pm.hw_by_name``)
     or an ``pm.HW`` instance; the emitted plan is stamped with its name.
@@ -249,14 +261,15 @@ def plan_model(cfg, shape, *, hw=pm.V5E, n_devices: int = 1,
     for l in layers:
         if not l.ovsf:
             continue
-        wtype = _LAYER_PREFIX.sub("", l.name).split("x")[0]
+        wtype = _MULTIPLICITY.sub("", _LAYER_PREFIX.sub("", l.name))
         wtype = _WTYPE_ALIASES.get(wtype, wtype)
         if wtype in seen:
             continue
         seen.add(wtype)
         entries.append((wtype, classify_gemm(
             l.M, l.d_in, l.d_out, l.rho, seg=l.seg, hw=hw, name=wtype,
-            weight_reuse=weight_reuse, paths=paths,
+            weight_reuse=weight_reuse,
+            paths=_GROUP_PATHS.get(wtype.split("_")[0], paths),
             alpha_dtype=l.alpha_dtype, calibration=calibration)))
     return ExecutionPlan(tuple(entries), hw_label=hw.name)
 

@@ -25,13 +25,15 @@ text that maps each instruction to its scope.
 
 Kernel counters: ``kernel_notes()`` gives what the kernels noted of each
 call they traced, such as the alpha chunks the fused OVSF generator runs
-per k-block.
+per k-block. ``expert_notes()`` gives what each expert-bank call of the MoE
+layer traced ran: its path and the dense W it materialised.
 """
 from __future__ import annotations
 
 import jax
 
 from repro.kernels.ovsf_gemm import gemm_notes
+from repro.models.moe import expert_notes as _expert_notes
 
 # jitted step function -> abstract arguments of its first call. Process-wide,
 # like the lru-cached step functions it keys on: a profile is reduced after
@@ -76,3 +78,12 @@ def kernel_notes() -> list:
     with ``n_run`` of its ``nc`` alpha chunks of ``bj`` rows run by the
     weight generator per k-block."""
     return gemm_notes()
+
+
+def expert_notes() -> list:
+    """What the MoE layer noted of the expert-bank calls this process
+    traced: one dict per distinct call (``models.moe.expert_notes``), with
+    the bank's ``name``, ``path``, ``experts``, ``rows`` per expert,
+    ``d_in``, ``d_out``, ``j`` and ``dense_w_bytes``, the dense W the call
+    materialises (0 on the spectral path)."""
+    return _expert_notes()

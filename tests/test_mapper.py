@@ -97,6 +97,43 @@ def test_plan_model_aliases_ssm_projection_names():
     assert ep.plan_for("ssm_in") is None or "ssm_in" not in ep.names()
 
 
+@pytest.mark.parametrize("slots", [32, 64])
+def test_plan_model_plans_the_expert_banks_under_their_names(slots):
+    """perf_model names the expert GEMMs with a multiplicity suffix
+    ("expert_gatex64"); the plan keys them by the names models.moe passes,
+    and weighs only the paths it runs for them (no fused per-expert
+    kernel): the activation-domain contraction at OLMoE's decode rows."""
+    from repro.configs import get_config
+    cfg = get_config("olmoe_1b_7b")
+    ep = mapper.plan_model(cfg, ShapeConfig("serve_decode", 1, slots,
+                                            "decode"),
+                           hw="v5e", weight_reuse=1)
+    assert "e" not in ep.names()
+    for name in ("expert_gate", "expert_up", "expert_down"):
+        assert name in ep.names()
+        assert ep.plan_for(name).path == "spectral"
+    assert ep.names() == ("attn_q", "attn_k", "attn_v", "attn_o",
+                          "expert_gate", "expert_up", "expert_down")
+
+
+def test_plan_model_keeps_the_trunk_plans_of_a_dense_model():
+    """No trunk weight type carries a multiplicity suffix: starcoder2's
+    decode plan (names, paths and blocks) is what it was."""
+    from repro.configs import get_config
+    ep = mapper.plan_model(get_config("starcoder2_15b"),
+                           ShapeConfig("serve_decode", 1, 128, "decode"),
+                           hw="v5e", weight_reuse=1)
+    assert [(n, lp.path, lp.block_m, lp.block_n, lp.block_k, lp.block_j)
+            for n, lp in ep.entries] == [
+        ("attn_q", "fused", 128, 128, 128, 128),
+        ("attn_k", "fused", 128, 128, 128, 128),
+        ("attn_v", "fused", 128, 128, 128, 128),
+        ("attn_o", "fused", 128, 128, 128, 128),
+        ("mlp_up", "fused", 128, 128, 128, 128),
+        ("mlp_down", "fused", 128, 128, 128, 128),
+    ]
+
+
 def test_plan_model_train_shape_prefers_materialize():
     cfg = get_smoke_config("tinyllama_1_1b")
     shape = ShapeConfig("t", 512, 8, "train")

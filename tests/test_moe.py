@@ -92,3 +92,79 @@ def test_moe_ovsf_expert_compression():
     x = jax.random.normal(key, (1, 8, 64))
     y, _ = moe.moe_apply(p, cfg, x)
     assert np.isfinite(np.asarray(y)).all()
+
+
+def _spectral_cfg(dtype, capacity_factor, path="spectral"):
+    """Compressed expert banks (L0 = 16) under a plan that runs ``path``."""
+    from repro.configs.base import OVSFConfig
+    from repro.runtime.mapper import ExecutionPlan, LayerPlan
+    plan = ExecutionPlan(tuple((f"expert_{nm}", LayerPlan(path))
+                               for nm in ("gate", "up", "down")))
+    return _cfg(d_model=64, d_ff=32, n_experts=4, top_k=2, dtype=dtype,
+                capacity_factor=capacity_factor, exec_plan=plan,
+                ovsf=OVSFConfig(enable=True, rho=0.5, min_dim=32,
+                                targets=("expert",)))
+
+
+def _random_codes(p, key):
+    """Each segment keeps its own sorted choice of the L0 = 16 codes, as
+    the benchmark's weights do (``moe_init`` keeps the same ones in all)."""
+    for i, nm in enumerate(("gate", "up", "down")):
+        ns, nk = p[nm]["idx"].shape
+        u = jax.random.uniform(jax.random.fold_in(key, i), (ns, 16))
+        p[nm]["idx"] = jnp.sort(jnp.argsort(u, axis=-1)[:, :nk], axis=-1)
+    return p
+
+
+@pytest.mark.parametrize("capacity_factor", [0.5, 2.0],
+                         ids=["dropping", "dropless"])
+@pytest.mark.parametrize("bank", ["gate", "up", "down"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_spectral_expert_bank_equals_its_decompressed_weights(
+        dtype, bank, capacity_factor):
+    """A spectral bank contracts kept-code coefficients against its alphas;
+    gate and up take the codes per token, before dispatch, down per expert
+    slot. Each expert's rows must equal x @ decompress(alphas[e]), and the
+    whole layer must match the layer whose plan decompresses the banks."""
+    from repro.kernels import ops as kops
+    cfg = _spectral_cfg(dtype, capacity_factor)
+    key = jax.random.PRNGKey(5)
+    p = _random_codes(moe.moe_init(key, cfg), key)
+    dt = cfg.act_dtype
+    E, k, d, f = cfg.n_experts, cfg.top_k, cfg.d_model, cfg.d_ff
+    G, g = 2, 8
+    cap = max(int(np.ceil(capacity_factor * k * g / E)), 1)
+    tol = {"float32": 1e-5, "bfloat16": 2e-2}[dtype]
+    bp, name = p[bank], f"expert_{bank}"
+    d_in = f if bank == "down" else d
+    ks = jax.random.split(key, 3)
+    if bank == "down":
+        rows = jax.random.normal(ks[0], (G, E, cap, f)).astype(dt)
+        ex = moe._bank_input(bp, rows, cfg, name)
+    else:
+        # a one-hot dispatch of tokens to (expert, slot); with capacity
+        # below k * g / E some tokens find no slot, some slots stay empty
+        tokens = jax.random.normal(ks[0], (G, g, d)).astype(dt)
+        slot = jax.random.randint(ks[1], (G, E, cap), 0, g + g // 2)
+        disp = jax.nn.one_hot(slot, g, dtype=dt)              # (G,E,cap,g)
+        codes = moe._bank_input(bp, tokens, cfg, name)        # (G,g,J)
+        assert codes.shape == (G, g, bp["alphas"].shape[1])
+        ex = jnp.einsum("gect,gtj->gecj", disp, codes)
+        rows = jnp.einsum("gect,gtd->gecd", disp, tokens)
+    y = moe._expert_matmul(bp, ex, cfg, name, d_in)
+    assert y.shape == (G, E, cap, bp["alphas"].shape[-1]) and y.dtype == dt
+    W = jax.vmap(lambda a: kops.decompress(a.astype(jnp.float32), bp["idx"],
+                                           d_in))(bp["alphas"])
+    ref = jnp.einsum("gecd,edn->gecn", rows.astype(jnp.float32), W)
+    scale = float(jnp.max(jnp.abs(ref)))
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(ref),
+                               rtol=0, atol=tol * scale)
+
+    x = jax.random.normal(ks[2], (G, g, d)).astype(dt)
+    y_spec, y_mat = (
+        jax.jit(lambda p, x, c=c: moe.moe_apply(p, c, x)[0])(p, x)
+        for c in (cfg, _spectral_cfg(dtype, capacity_factor, "materialize")))
+    scale = float(jnp.max(jnp.abs(y_mat.astype(jnp.float32))))
+    np.testing.assert_allclose(np.asarray(y_spec, np.float32),
+                               np.asarray(y_mat, np.float32),
+                               rtol=0, atol=tol * scale)

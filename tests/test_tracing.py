@@ -1,7 +1,7 @@
 """The serving program's own spans, scopes and stamps: named scopes per layer
 in the compiled step's HLO metadata, the engine's host spans under the
-profiler, the queue-wait stamp ``Request.t_admit``, and the kernels'
-counters (``trace.kernel_notes``)."""
+profiler, the queue-wait stamp ``Request.t_admit``, and the counters of the
+kernels and the expert banks (``trace.kernel_notes``, ``trace.expert_notes``)."""
 import glob
 import re
 
@@ -97,6 +97,30 @@ def test_t_admit_is_stamped_once_and_kept_across_preemption(moe):
     assert [r.t_admit for r in reqs] == first
     for r in reqs + [urgent]:
         assert r.t_submit <= r.t_admit <= r.token_times[0]
+
+
+def test_expert_notes_show_the_served_step_materialises_no_expert_w(moe):
+    """The engine plans the expert banks spectral: a traced MoE step notes
+    each bank's call with no dense W, and the kernels' counter keeps to
+    the ovsf_gemm calls."""
+    cfg, params = moe
+    eng = _paged(cfg, params)
+    for rid in range(2):
+        eng.submit(_req(rid, 12, vocab=cfg.vocab))
+    eng.run_until_drained()
+    banks = {"expert_gate": (cfg.d_model, cfg.d_ff),
+             "expert_up": (cfg.d_model, cfg.d_ff),
+             "expert_down": (cfg.d_ff, cfg.d_model)}
+    served = {n["name"]: n for n in trace.expert_notes()
+              if n["path"] == "spectral" and n["experts"] == cfg.n_experts
+              and banks.get(n["name"]) == (n["d_in"], n["d_out"])}
+    assert set(served) == set(banks)
+    for name, n in served.items():
+        assert eng.cfg.exec_plan.plan_for(name).path == "spectral"
+        assert n["dense_w_bytes"] == 0 and n["rows"] > 0
+        assert n["j"] == n["d_in"] // 2                  # rho 0.5
+    gemm_keys = {"d_in", "d_out", "seg", "bk", "bj", "nc", "n_run"}
+    assert all(set(n) == gemm_keys for n in trace.kernel_notes())
 
 
 def _note_of(d_in, d_out, bk, bj, seg):
