@@ -145,7 +145,7 @@ def kernel_phase(jax, hw, seed: int, rows: int = 8) -> None:
         for adt in ("", "int8", "int4"):
             cfg = base.replace(ovsf=dataclasses.replace(base.ovsf,
                                                         alpha_dtype=adt))
-            plan = mapper.plan_model(cfg, shape, hw=hw, weight_reuse=1)
+            plan = mapper.plan_model(cfg, shape, hw=hw)
             for name, (d_in, d_out) in shapes.items():
                 lp = plan.plan_for(name)
                 key, kx, ka = jax.random.split(key, 3)

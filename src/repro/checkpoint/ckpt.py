@@ -9,9 +9,9 @@ save can never be mistaken for a complete checkpoint. Every file is fsync'd
 before the rename and the parent directory entry after it, so a power loss
 (not just a process crash) can never surface a renamed-but-torn checkpoint.
 :func:`atomic_write_json` exports the same tmp+fsync+rename discipline for
-every other JSON artifact the repo persists (calibration tables, BENCH_*
-results). ``save_async`` hands the (host-copied) pytree to a writer thread
-so the train loop is not blocked.
+every other JSON artifact the repo persists (BENCH_* results).
+``save_async`` hands the (host-copied) pytree to a writer thread so the
+train loop is not blocked.
 Restore maps leaves back by tree path and ``jax.device_put``s them with the
 *target* mesh's NamedShardings — a checkpoint written on a 256-chip mesh
 restores onto 512 or 8 chips unchanged (elastic resharding).

@@ -429,9 +429,9 @@ def serve_step_timing(cfg, *, valid_tokens: int, batch_tokens: int,
     """Model one serving step that batches ``batch_tokens`` rows of which
     ``valid_tokens`` are real work — the padded (B, W) window step vs its
     token-packed replacement, priced on the same analytical model the
-    mapper/calibration loop uses. ``ShapeConfig`` is decode-kind with the
-    batch-token count as the per-step row dimension. ``kv_len`` adds the
-    KV-cache read bytes each row streams against its cached context."""
+    mapper uses. ``ShapeConfig`` is decode-kind with the batch-token count
+    as the per-step row dimension. ``kv_len`` adds the KV-cache read bytes
+    each row streams against its cached context."""
     from repro.configs.base import ShapeConfig
     shape = ShapeConfig("serve_step", 1, batch_tokens, "decode")
     layers = model_layers(cfg, shape, n_devices=n_devices, tp=tp,

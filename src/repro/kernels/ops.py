@@ -18,8 +18,6 @@ All paths are numerically validated against each other in tests.
 """
 from __future__ import annotations
 
-import contextlib
-import functools
 from typing import Any, Literal, Optional
 
 import jax
@@ -129,126 +127,6 @@ def spectral_transform(x: jnp.ndarray, idx: jnp.ndarray, *,
     return jnp.take(xh, idx, axis=-1)                  # (..., J)
 
 
-# ---------------------------------------------------------------------------
-# Decompressed-weight cache (mapper policy: weight-stationary layers)
-# ---------------------------------------------------------------------------
-# The mapper marks layers where the materialize path wins AND the same alphas
-# are consumed repeatedly (serving decode: params frozen across thousands of
-# steps; training: fwd+bwd within one step). For those we generate dense W
-# once per parameter version and reuse it, instead of re-running the
-# generator every invocation. Entries hold a strong ref to the source alphas
-# so the ``is`` identity check can never alias a recycled object id; a layer
-# re-keying (new params) simply overwrites its slot, so the cache holds at
-# most one (alphas, W) pair per cache_key.
-#
-# Entries and counters are keyed by a *model label* (the active
-# ``weight_cache_scope``) so a multi-model gateway gets an exact per-model
-# eviction ledger instead of one process-wide lump. Label "" is the
-# single-model default and keeps the legacy behaviour.
-
-_WEIGHT_CACHE: dict[str, dict[str, tuple[Any, Any, jnp.ndarray]]] = {}
-_WEIGHT_CACHE_HITS: dict[str, int] = {}    # eager lookups served per label
-_WEIGHT_CACHE_MISSES: dict[str, int] = {}  # eager generator runs per label
-_CACHE_LABEL = ""                          # active model/param-version label
-
-
-@contextlib.contextmanager
-def weight_cache_scope(label: str):
-    """Attribute decompress-cache entries/counters to a model label.
-
-    Engines wrap their step/prefill calls in this scope so every cached
-    dense W (and every hit/miss) lands in that model's ledger. Scopes nest;
-    the outermost default is the unlabelled ("") single-model bucket."""
-    global _CACHE_LABEL
-    prev = _CACHE_LABEL
-    _CACHE_LABEL = label or ""
-    try:
-        yield
-    finally:
-        _CACHE_LABEL = prev
-
-
-def clear_weight_cache(label: Optional[str] = None) -> None:
-    """Drop cached weights (+ counters): one label's, or everything."""
-    if label is None:
-        _WEIGHT_CACHE.clear()
-        _WEIGHT_CACHE_HITS.clear()
-        _WEIGHT_CACHE_MISSES.clear()
-    else:
-        _WEIGHT_CACHE.pop(label, None)
-        _WEIGHT_CACHE_HITS.pop(label, None)
-        _WEIGHT_CACHE_MISSES.pop(label, None)
-
-
-def weight_cache_stats(label: Optional[str] = None) -> dict:
-    """Decompress-cache counters (hits/misses/entries/bytes).
-
-    ``label`` selects one model's ledger; ``None`` aggregates every label
-    (the legacy process-wide view). Counters are cumulative since import (or
-    ``clear_weight_cache``); callers that want per-run effectiveness (e.g.
-    ``EngineStats``) snapshot a baseline and report the delta."""
-    if label is None:
-        caches = list(_WEIGHT_CACHE.values())
-        hits = sum(_WEIGHT_CACHE_HITS.values())
-        misses = sum(_WEIGHT_CACHE_MISSES.values())
-    else:
-        caches = [_WEIGHT_CACHE.get(label, {})]
-        hits = _WEIGHT_CACHE_HITS.get(label, 0)
-        misses = _WEIGHT_CACHE_MISSES.get(label, 0)
-    return {"entries": sum(len(c) for c in caches),
-            "hits": hits,
-            "misses": misses,
-            "bytes": sum(int(w.size) * w.dtype.itemsize
-                         for c in caches for *_s, w in c.values())}
-
-
-def cached_generate(cache_key: str, alphas: jnp.ndarray, idx: jnp.ndarray,
-                    gen_fn) -> jnp.ndarray:
-    """Memoise ``gen_fn()`` per (label, cache_key, parameter identity).
-
-    Only concrete arrays are cached — under a jit trace the operands are
-    tracers and caching would leak abstract values, so we fall through to the
-    generator (XLA CSEs duplicate generation within one program; the cache's
-    job is reuse *across* program invocations in eager serving)."""
-    if isinstance(alphas, jax.core.Tracer) or isinstance(idx, jax.core.Tracer):
-        return gen_fn()
-    label = _CACHE_LABEL
-    bucket = _WEIGHT_CACHE.setdefault(label, {})
-    ent = bucket.get(cache_key)
-    if ent is not None and ent[0] is alphas and ent[1] is idx:
-        _WEIGHT_CACHE_HITS[label] = _WEIGHT_CACHE_HITS.get(label, 0) + 1
-        return ent[2]
-    _WEIGHT_CACHE_MISSES[label] = _WEIGHT_CACHE_MISSES.get(label, 0) + 1
-    W = gen_fn()
-    bucket[cache_key] = (alphas, idx, W)
-    return W
-
-
-def cached_decompress(alphas: jnp.ndarray, idx: jnp.ndarray, d_in: int, *,
-                      cache_key: str, alpha_scale=None, alpha_dtype: str = "",
-                      use_pallas: bool | None = None,
-                      interpret: bool = False) -> jnp.ndarray:
-    """``decompress`` with once-per-parameter-version memoisation.
-
-    Handles an (E, J, d_out) expert bank by vmapping the generator over the
-    leading axis (shared idx), mirroring ``moe._expert_matmul``. The cache
-    key must already carry the alpha dtype (``ovsf_matmul`` appends it) so a
-    dtype switch can never serve a stale fp32 W."""
-    def gen():
-        if alphas.ndim == 3:
-            if alpha_dtype:
-                raise NotImplementedError(
-                    "quantised (E, J, d_out) expert alpha banks are not "
-                    "supported yet (per-expert scales)")
-            return jax.vmap(lambda a: decompress(
-                a, idx, d_in, use_pallas=use_pallas,
-                interpret=interpret))(alphas)
-        return decompress(alphas, idx, d_in, alpha_scale=alpha_scale,
-                          alpha_dtype=alpha_dtype, use_pallas=use_pallas,
-                          interpret=interpret)
-    return cached_generate(cache_key, alphas, idx, gen)
-
-
 def ovsf_matmul(x: jnp.ndarray, alphas: jnp.ndarray, idx: jnp.ndarray, *,
                 path: ExecPath = "materialize",
                 plan: Optional[Any] = None,
@@ -259,26 +137,18 @@ def ovsf_matmul(x: jnp.ndarray, alphas: jnp.ndarray, idx: jnp.ndarray, *,
                 block_k: int = 128, block_j: int = 128) -> jnp.ndarray:
     """Dispatch y = x @ W(alphas, idx) over (..., d_in) activations.
 
-    ``plan`` (a ``runtime.mapper.LayerPlan``) overrides path, Pallas block
-    sizes, and the decompress-cache policy — the hardware-aware per-layer
-    dispatch of paper §5. Without a plan, behaviour is the legacy explicit
-    ``path=`` dispatch with default blocks. ``alpha_dtype``/``alpha_scale``
-    select the quantised alpha-storage form (see ``core.ovsf.alpha_params``
-    to unpack a param dict): the fused Pallas path streams the quantised
-    bytes and dequantises in-kernel; the other paths dequantise at the GEMM
-    boundary.
+    ``plan`` (a ``runtime.mapper.LayerPlan``) overrides path and Pallas
+    block sizes — the hardware-aware per-layer dispatch of paper §5. Without
+    a plan, behaviour is the legacy explicit ``path=`` dispatch with default
+    blocks. ``alpha_dtype``/``alpha_scale`` select the quantised
+    alpha-storage form (see ``core.ovsf.alpha_params`` to unpack a param
+    dict): the fused Pallas path streams the quantised bytes and dequantises
+    in-kernel; the other paths dequantise at the GEMM boundary.
     """
-    cache_key = ""
     if plan is not None:
         path = plan.path  # type: ignore[assignment]
         block_m, block_n = plan.block_m, plan.block_n
         block_k, block_j = plan.block_k, plan.block_j
-        if plan.cache_weights:
-            cache_key = plan.cache_key or f"ovsf:{id(alphas)}"
-    if cache_key:
-        # the key carries the alpha dtype: an alpha-dtype switch re-keys the
-        # slot instead of ever serving a stale fp32 (or stale-int8) W
-        cache_key = f"{cache_key}|{alpha_dtype or 'fp'}"
     if use_pallas is None:
         use_pallas = on_tpu()
     lead = x.shape[:-1]
@@ -300,15 +170,9 @@ def ovsf_matmul(x: jnp.ndarray, alphas: jnp.ndarray, idx: jnp.ndarray, *,
             y = kref.ovsf_matmul_ref(x2, alphas, idx, alpha_scale=alpha_scale,
                                      alpha_dtype=alpha_dtype)
     elif path == "materialize":
-        if cache_key:
-            W = cached_decompress(alphas, idx, d_in, cache_key=cache_key,
-                                  alpha_scale=alpha_scale,
-                                  alpha_dtype=alpha_dtype,
-                                  use_pallas=use_pallas, interpret=interpret)
-        else:
-            W = decompress(alphas, idx, d_in, alpha_scale=alpha_scale,
-                           alpha_dtype=alpha_dtype, use_pallas=use_pallas,
-                           interpret=interpret)
+        W = decompress(alphas, idx, d_in, alpha_scale=alpha_scale,
+                       alpha_dtype=alpha_dtype, use_pallas=use_pallas,
+                       interpret=interpret)
         y = (x2 @ W.astype(x2.dtype)).astype(x.dtype)
     else:
         raise ValueError(f"unknown exec path: {path}")

@@ -13,10 +13,7 @@ with the token-packed step: only valid tokens reach the model, and the
 padding-efficiency counters are reported. ``--paged`` (with
 ``--chunk-size``; composes with ``--packed``) swaps the per-slot contiguous
 KV buffers for a paged pool (``--page-size`` tokens per page, ``--kv-pages``
-pool size) and reports the page-pool utilization counters. ``--calibrate`` records measured
-step times against the mapper's analytical model and reports which layers a
-calibrated re-plan would re-map (optionally saving the table with
-``--calibration-out``).
+pool size) and reports the page-pool utilization counters.
 
 Chaos flags (see ``docs/serving.md`` "Failure semantics"): ``--inject`` adds
 deterministic faults (repeatable; e.g. ``--inject nan:step=3,slot=0
@@ -121,11 +118,6 @@ def main(argv=None) -> LLMEngine | None:
     ap.add_argument("--kv-pages", type=int, default=None,
                     help="page-pool size (--paged; default slots*buffer/"
                          "page_size — enough for every slot at full length)")
-    ap.add_argument("--calibrate", action="store_true",
-                    help="record measured-vs-modeled step times and report "
-                         "the calibrated re-plan")
-    ap.add_argument("--calibration-out", default="",
-                    help="write the calibration table JSON here")
     ap.add_argument("--journal", default="",
                     help="write-ahead request journal directory: every "
                          "admission/token/finish is fsync'd there, and on "
@@ -184,7 +176,6 @@ def main(argv=None) -> LLMEngine | None:
                     admission=args.admission, chunk_size=args.chunk_size,
                     packed=args.packed, paged=args.paged,
                     page_size=args.page_size, kv_pages=args.kv_pages,
-                    calibrate=args.calibrate,
                     max_waiting=args.max_waiting,
                     step_timeout_s=args.step_timeout,
                     faults=plan if plan else None,
@@ -232,34 +223,6 @@ def main(argv=None) -> LLMEngine | None:
               f"peak_used={stats.kv_pages_used} "
               f"peak_bytes={stats.kv_bytes_used} "
               f"utilization={stats.kv_utilization:.2f}")
-    print(f"[serve] weight_cache: hits={stats.weight_cache_hits} "
-          f"misses={stats.weight_cache_misses} "
-          f"entries={stats.weight_cache_entries} "
-          f"bytes={stats.weight_cache_bytes}")
-
-    if args.calibrate:
-        old = eng.cfg.exec_plan
-        new = eng.replan()
-        if old is None or not len(eng.calibration):
-            print("[serve] calibrate: no OVSF plan / no decode samples "
-                  "recorded — nothing to correct")
-        else:
-            changed = [(n, a.path, b.path)
-                       for (n, a), (_n, b) in zip(old.entries, new.entries)
-                       if a.path != b.path]
-            facs = eng.calibration.factors(eng.hw_label)
-            print(f"[serve] calibrate: {len(eng.calibration)} keys, "
-                  f"relative factors: "
-                  + ", ".join(f"{k}={v:.2f}" for k, v in sorted(facs.items())))
-            if changed:
-                for n, a, b in changed:
-                    print(f"[serve] calibrate: {n}: {a} -> {b}")
-            else:
-                print("[serve] calibrate: measured factors keep every "
-                      "layer on its modeled path")
-        if args.calibration_out:
-            eng.calibration.save(args.calibration_out)
-            print(f"[serve] calibrate: table -> {args.calibration_out}")
 
     # Exit contract (the CI chaos smoke rides this): every request must be
     # terminal, and any finish reason other than eos/length must be

@@ -145,12 +145,7 @@ def _expert_matmul(p: dict, x: jnp.ndarray, cfg: ModelConfig, name: str,
     if path == "spectral":
         return _contract(x, w.astype(x.dtype))
     if path != "dense":
-        plan = L.layer_plan(cfg, name)
-        if plan is not None and plan.cache_weights:
-            w = kops.cached_decompress(w, p["idx"], d_in,
-                                       cache_key=plan.cache_key or name)
-        else:
-            w = jax.vmap(lambda a: kops.decompress(a, p["idx"], d_in))(w)
+        w = jax.vmap(lambda a: kops.decompress(a, p["idx"], d_in))(w)
     return jnp.einsum("gecd,edn->gecn", x, w.astype(x.dtype))
 
 

@@ -16,9 +16,6 @@ regime for the whole network. Paper terminology -> this implementation:
                                         ``hwmodel.tile_balance.balance_blocks``
   roofline bound classification         ``hwmodel.perf_model.layer_timing``
   (Eq. 5-8, {IFM, OFM, W, C})           -> ``LayerTiming.bound``
-  weights kept on-chip across reuse     ``LayerPlan.cache_weights`` — generate
-  (weight-stationary dataflow, §4.2.1)  dense W once, reuse across rows/steps
-                                        (``kernels.ops`` decompress cache)
 
 Mapper decisions are **pure functions of (layer shape, rho, HW)**: no device
 probing, no RNG, no global state — the same inputs always give the same plan,
@@ -53,14 +50,12 @@ ALL_PATHS = ("materialize", "fused", "spectral")
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    """Execution plan for one OVSF GEMM: path + Pallas blocks + cache policy."""
+    """Execution plan for one OVSF GEMM: path + Pallas blocks."""
     path: str                       # materialize | fused | spectral
     block_m: int = 128
     block_n: int = 128
     block_k: int = 128
     block_j: int = 128
-    cache_weights: bool = False     # weight-stationary: decompress once, reuse
-    cache_key: str = ""             # identity key for the decompress cache
     bound: str = "C"                # roofline bound class at decision time
     ii_s: float = 0.0               # modeled initiation interval (seconds)
     alpha_dtype: str = ""           # alpha storage dtype the plan was modeled
@@ -93,62 +88,39 @@ class ExecutionPlan:
 # ---------------------------------------------------------------------------
 
 def _candidate_ii(layer: pm.GemmLayer, path: str, hw: pm.HW, *,
-                  weight_reuse: int, block_m: int) -> tuple[float, str]:
+                  block_m: int) -> tuple[float, str]:
     """Modeled II + bound for one (layer, path) candidate.
 
-    Refines ``pm.layer_timing`` with the two costs the runtime actually pays:
-      - fused regenerates each weight tile once per M-tile of the Pallas grid
-        (the TiWGen kernel has no cross-m-tile reuse), so t_wgen scales with
-        ceil(M / bm);
-      - materialize with an active decompress cache amortises generation and
-        the dense-W write over ``weight_reuse`` invocations (serving decode:
-        params are frozen, so reuse is effectively unbounded).
+    Refines ``pm.layer_timing`` with a cost the runtime actually pays: fused
+    regenerates each weight tile once per M-tile of the Pallas grid (the
+    TiWGen kernel has no cross-m-tile reuse), so t_wgen scales with
+    ceil(M / bm). Materialize generates dense W on every invocation, as the
+    model's own timing assumes: nothing keeps W across calls.
     """
     l = dataclasses.replace(layer, exec_path=path)
     t = pm.layer_timing(l, hw)
     if path == "fused":
         m_tiles = max(math.ceil(layer.M / max(block_m, 1)), 1)
         t = dataclasses.replace(t, t_wgen=t.t_wgen * m_tiles)
-    elif path == "materialize" and weight_reuse > 1:
-        by = layer.dtype_bytes
-        dense_read = layer.d_in * layer.d_out * by / hw.hbm_bw
-        alpha_read = 0.0 if layer.alphas_resident else \
-            layer.alpha_hbm_bytes / hw.hbm_bw
-        t = dataclasses.replace(
-            t,
-            t_wgen=t.t_wgen / weight_reuse,
-            # steady state: read the cached dense W once; alphas only touched
-            # on regeneration (params changed), amortised away.
-            t_mem_w=dense_read + alpha_read / weight_reuse)
     return t.ii, t.bound
 
 
 def classify_gemm(M: int, d_in: int, d_out: int, rho: float, *,
                   seg: int = 16, hw=pm.V5E, name: str = "gemm",
-                  weight_reuse: int = 1,
                   paths: Sequence[str] = DEFAULT_PATHS,
                   alphas_resident: bool = False,
-                  alpha_dtype: str = "",
-                  calibration=None) -> LayerPlan:
+                  alpha_dtype: str = "") -> LayerPlan:
     """Map one OVSF GEMM y[M, d_out] = x[M, d_in] @ W(alphas) to a plan.
 
-    Pure in (shape, rho, hw, weight_reuse): evaluates each candidate path
+    Pure in (shape, rho, hw): evaluates each candidate path
     under the analytical model and picks the minimum-II one. First listed
     wins ties: materialize precedes fused so tiny output-bound layers keep
     the simple pre-generated dataflow, and fused precedes spectral so
     decode-shaped alpha-bandwidth ties resolve to the paper-faithful TiWGen
     path (on memory-bound decode, fused's alpha-only HBM traffic beats
     materialize's dense-W read strictly, by the 1/rho compression factor).
-    ``weight_reuse`` is how many invocations see the same alphas (1 for
-    training; the steps-per-request scale for frozen serving params).
     ``hw`` is an ``pm.HW`` instance or a registered target name
     (``"v5e"``/``"v5p"``/``"v6e"``/``"cpu"``).
-
-    ``calibration`` (a ``runtime.calibrate.CalibrationTable``) closes the
-    measured-vs-modeled loop: each candidate's modeled II is multiplied by
-    the table's relative correction factor for ``(name, path, hw.name)``
-    before the minimum is taken, so serving-measured skew re-ranks paths on
-    the next planning pass (unmeasured candidates keep factor 1.0).
 
     ``alpha_dtype`` ("int8"/"int4") models the quantised alpha stream —
     halved/quartered t_mem_w for every path that reads alphas from HBM, so
@@ -165,23 +137,19 @@ def classify_gemm(M: int, d_in: int, d_out: int, rho: float, *,
         blocks = tb.balance_blocks(M, d_in, d_out)
         t = pm.layer_timing(layer, hw)
         return LayerPlan("materialize", block_m=blocks.bm, block_n=blocks.bn,
-                         block_k=blocks.bk, cache_weights=False,
-                         cache_key=name, bound=t.bound, ii_s=t.ii)
+                         block_k=blocks.bk, bound=t.bound, ii_s=t.ii)
 
     best_path, best_ii, best_bound = None, float("inf"), "C"
     for path in paths:
-        ii, bound = _candidate_ii(layer, path, hw, weight_reuse=weight_reuse,
-                                  block_m=128)
-        if calibration is not None:
-            ii *= calibration.factor(name, path, hw.name)
+        ii, bound = _candidate_ii(layer, path, hw, block_m=128)
         if ii < best_ii:
             best_path, best_ii, best_bound = path, ii, bound
     if best_path is None:
         raise RuntimeError(
             f"mapper: no viable execution path for layer {name!r} "
             f"(candidates considered: {list(paths)}) — every candidate "
-            f"produced a non-finite modeled II; check the perf model / "
-            f"calibration factors for hw={hw.name!r}")
+            f"produced a non-finite modeled II; check the perf model "
+            f"for hw={hw.name!r}")
 
     # DSE block search over the consumer GEMM of the chosen path, in blocks
     # the chip's tiling accepts and the fused kernel's VMEM estimate fits
@@ -204,9 +172,7 @@ def classify_gemm(M: int, d_in: int, d_out: int, rho: float, *,
     if seg and bk % seg:
         bk = max((bk // seg) * seg, seg)
     return LayerPlan(best_path, block_m=blocks.bm, block_n=blocks.bn,
-                     block_k=bk, block_j=bj,
-                     cache_weights=best_path == "materialize",
-                     cache_key=name, bound=best_bound, ii_s=best_ii,
+                     block_k=bk, block_j=bj, bound=best_bound, ii_s=best_ii,
                      alpha_dtype=alpha_dtype)
 
 
@@ -236,25 +202,19 @@ _WTYPE_ALIASES = {"ssm_in": "mlp_in", "ssm_out": "mlp_out"}
 
 
 def plan_model(cfg, shape, *, hw=pm.V5E, n_devices: int = 1,
-               tp: int = 1, paths: Sequence[str] = DEFAULT_PATHS,
-               weight_reuse: Optional[int] = None,
-               calibration=None) -> ExecutionPlan:
+               tp: int = 1, paths: Sequence[str] = DEFAULT_PATHS
+               ) -> ExecutionPlan:
     """Emit an ExecutionPlan for a ModelConfig under a workload shape.
 
     Expands the config into per-device GEMMs via ``pm.model_layers``,
     collapses them by weight type (transformer stacks are layer-homogeneous
     and scanned, so one plan per weight type), and classifies each with
     ``classify_gemm`` among ``paths``, or among the paths its group's model
-    code runs (``_GROUP_PATHS``: expert banks). ``weight_reuse`` defaults by workload kind: decode
-    serves frozen params (high reuse), train regenerates every step.
-    ``hw`` accepts any registered HW target name (see ``pm.hw_by_name``)
-    or an ``pm.HW`` instance; the emitted plan is stamped with its name.
-    ``calibration`` threads a measured-vs-modeled correction table
-    (``runtime.calibrate.CalibrationTable``) into every classification.
+    code runs (``_GROUP_PATHS``: expert banks). ``hw`` accepts any
+    registered HW target name (see ``pm.hw_by_name``) or an ``pm.HW``
+    instance; the emitted plan is stamped with its name.
     """
     hw = pm.resolve_hw(hw)
-    if weight_reuse is None:
-        weight_reuse = 1 if shape.kind == "train" else 256
     layers = pm.model_layers(cfg, shape, n_devices=n_devices, tp=tp)
     entries: list[tuple[str, LayerPlan]] = []
     seen: set[str] = set()
@@ -268,9 +228,8 @@ def plan_model(cfg, shape, *, hw=pm.V5E, n_devices: int = 1,
         seen.add(wtype)
         entries.append((wtype, classify_gemm(
             l.M, l.d_in, l.d_out, l.rho, seg=l.seg, hw=hw, name=wtype,
-            weight_reuse=weight_reuse,
             paths=_GROUP_PATHS.get(wtype.split("_")[0], paths),
-            alpha_dtype=l.alpha_dtype, calibration=calibration)))
+            alpha_dtype=l.alpha_dtype)))
     return ExecutionPlan(tuple(entries), hw_label=hw.name)
 
 
@@ -300,8 +259,7 @@ def suggest_rhos(cfg, shape, *, hw=pm.V5E, n_devices: int = 1,
 # ---------------------------------------------------------------------------
 
 def plan_cnn(cfg, *, batch: int = 1, hw=pm.V5E,
-             paths: Sequence[str] = DEFAULT_PATHS,
-             weight_reuse: int = 256) -> ExecutionPlan:
+             paths: Sequence[str] = DEFAULT_PATHS) -> ExecutionPlan:
     """Plans for a CNNConfig: each OVSF conv is an im2col GEMM with
     R = B*H'*W' rows and P = Cin*K*K contraction (§4.1 mapping)."""
     hw = pm.resolve_hw(hw)
@@ -316,8 +274,7 @@ def plan_cnn(cfg, *, batch: int = 1, hw=pm.V5E,
         M = batch * hw_cur * hw_cur
         fan_in = c_in * k * k
         entries.append((name, classify_gemm(
-            M, fan_in, c_out, rho, seg=0, hw=hw, name=name,
-            weight_reuse=weight_reuse, paths=paths)))
+            M, fan_in, c_out, rho, seg=0, hw=hw, name=name, paths=paths)))
     return ExecutionPlan(tuple(entries), hw_label=hw.name)
 
 

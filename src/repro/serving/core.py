@@ -23,7 +23,7 @@ The core owns everything that touches the device, behind one contract:
   allocated). A decode slot costs 1 token instead of a W-wide padded row —
   the ``(B, W)`` window's dead decode columns never reach the model.
   ``StepOutput.n_valid_tokens``/``n_batch_tokens`` record the padding
-  efficiency of every path for the benches and calibration.
+  efficiency of every path for the benches.
 * **Paged KV cache (``paged=True``)** — K/V live in shared per-layer page
   pools (``serving.kvcache``) instead of per-slot worst-case buffers; the
   core owns a :class:`~repro.serving.kvcache.PagedKVCache` whose host page
@@ -286,9 +286,9 @@ class StepOutput:
     ``first_tokens`` maps slot -> the first sampled token of a request whose
     prompt completed this step (legacy prefill or final chunk);
     ``decode_tokens`` maps slot -> the next generated token of a decoding
-    slot. Wall times are split by phase so the measured-vs-modeled
-    calibration loop (``runtime.calibrate``) can consume clean decode-shaped
-    samples (``decode_s``) separately from prefill/mixed work.
+    slot. Wall times are split by phase (``prefill_s``, ``decode_s``,
+    ``mixed_s``): the engine sums them into ``EngineStats`` and ``serve``
+    prints the split.
     """
     first_tokens: dict = dataclasses.field(default_factory=dict)
     decode_tokens: dict = dataclasses.field(default_factory=dict)
@@ -301,8 +301,8 @@ class StepOutput:
     mixed_s: float = 0.0        # fused window/packed (chunks + decode) wall
     n_prompt_tokens: int = 0    # prompt tokens consumed (chunks + prefills)
     n_decode_tokens: int = 0    # decode slots advanced
-    # padding-efficiency raw material (one definition for benches AND
-    # calibration: hwmodel.perf_model.padding_efficiency(valid, batch))
+    # padding-efficiency raw material (one definition for the engine and
+    # the benches: hwmodel.perf_model.padding_efficiency(valid, batch))
     n_valid_tokens: int = 0     # tokens that were real work this step
     n_batch_tokens: int = 0     # tokens the device batch actually carried
 
@@ -602,8 +602,8 @@ class EngineCore:
                     self._mm_window_step(so, last_tokens, out, poison)
                 dt = time.perf_counter() - t0
                 # A chunk-free packed step IS decode-shaped: book it as
-                # decode_s so the measured-vs-modeled calibration loop
-                # (which consumes pure-decode samples) keeps working.
+                # decode_s, so the engine's and serve's phase split counts
+                # it as decode.
                 if so.chunks:
                     out.mixed_s += dt
                 else:

@@ -18,8 +18,7 @@ contract (vLLM-style):
   stream) instead of the padded ``(B, W)`` window;
 * this module tracks slots, prefill progress, finish reasons (``length`` /
   ``eos`` / ``rejected`` / ``timeout`` / ``shed`` / ``error`` /
-  ``preempted``), streaming callbacks, per-phase wall time, and the
-  decompress-weight-cache counters.
+  ``preempted``), streaming callbacks, and per-phase wall time.
 
 Fault tolerance (see ``docs/serving.md`` "Failure semantics"):
 
@@ -50,18 +49,14 @@ Fault tolerance (see ``docs/serving.md`` "Failure semantics"):
 
 When the model has OVSF layers and no explicit plan is set, the engine asks
 the hardware-aware layer mapper (``runtime.mapper``) for a decode-shaped
-ExecutionPlan against the engine's ``hw`` target. With ``calibrate=True``
-the engine additionally feeds each pure-decode step's measured wall time
-into a :class:`~repro.runtime.calibrate.CalibrationTable`; ``replan()``
-re-runs the mapper under the accumulated measured-vs-modeled corrections.
+ExecutionPlan against the engine's ``hw`` target.
 
 Multi-model serving (the gateway's same-architecture batching): construct
 with ``variants=M`` (the stacked-alpha variant count of the params pytree)
 and a ``model_index`` callable mapping ``Request.model`` names to variant
 indices — each slot's tokens then route through its own alpha bank inside
-ONE fused step (see ``serving.gateway``). ``model_label`` keys the
-decompress-weight-cache counters per model, so a multi-tenant process can
-attribute resident dense-W bytes to the engine that generated them.
+ONE fused step (see ``serving.gateway``). ``model_label`` names the engine
+in a gateway's replica errors.
 """
 from __future__ import annotations
 
@@ -107,8 +102,7 @@ class EngineStats:
                                   # (chunked steady state: <= 2; packed <= 3)
     chunk_tokens: int = 0         # prompt tokens consumed via chunks
     # Padding efficiency: valid tokens executed vs tokens the device batches
-    # actually carried. ONE definition shared by the serving bench and the
-    # calibration loop (hwmodel.perf_model.padding_efficiency).
+    # actually carried (hwmodel.perf_model.padding_efficiency).
     packed_tokens: int = 0        # valid (useful) tokens across all steps
     padded_tokens: int = 0        # batch tokens across all steps (incl. pad)
     completed: int = 0            # finished naturally (eos / length)
@@ -125,13 +119,6 @@ class EngineStats:
     prefill_s: float = 0.0        # per-phase wall time (legacy prefill)
     decode_s: float = 0.0         # pure fused decode steps
     mixed_s: float = 0.0          # fused window steps (chunks + decode)
-    # decompress-weight-cache effectiveness for THIS run (delta against the
-    # engine's model_label bucket of the kernels.ops counters, snapshotted
-    # at engine construction — multi-tenant processes see per-model figures)
-    weight_cache_hits: int = 0
-    weight_cache_misses: int = 0
-    weight_cache_entries: int = 0
-    weight_cache_bytes: int = 0   # resident dense-W footprint (this label)
     # paged KV cache (paged=True engines; all zero otherwise). Used/bytes
     # are HIGH-WATER marks across the run — a drained engine has released
     # every page, so the instantaneous value at read time is always 0; the
@@ -165,17 +152,15 @@ class LLMEngine:
                  max_step_tokens: Optional[int] = None,
                  packed: bool = False, paged: bool = False,
                  page_size: int = 16, kv_pages: Optional[int] = None,
-                 calibrate: bool = False,
                  max_waiting: Optional[int] = None,
                  step_timeout_s: Optional[float] = None,
                  faults: Optional[FaultPlan] = None,
                  variants: int = 0, model_index=None,
                  model_label: Optional[str] = None,
                  journal=None):
-        self._base_cfg = cfg
         check_hw_for_device(hw, jax.devices()[0])
+        resolve_hw(hw)                  # an unknown target name fails here
         self.hw = hw
-        self.hw_label = resolve_hw(hw).name
         # Multi-model mode: variants = stacked-alpha variant count of the
         # params pytree (0 = single-model); model_index maps Request.model
         # names to variant rows. The mapper plans per-layer exec paths for a
@@ -189,9 +174,8 @@ class LLMEngine:
                              "steps serve prompts via chunk tasks)")
         use_mapper = use_mapper and not self.variants
         self.cfg = self._plan_cfg(cfg, batch_slots, use_mapper, hw)
-        # Keys this engine's decompress-weight-cache bucket (satellite of the
-        # multi-model gateway: per-model byte attribution). Defaults to the
-        # config name so single-engine stats stay self-describing.
+        # Names this engine in a gateway's replica errors; defaults to the
+        # config name.
         self.model_label = cfg.name if model_label is None else model_label
         self.params = params
         self.B = batch_slots
@@ -251,12 +235,6 @@ class LLMEngine:
         if self.paged:
             self.stats.kv_pages_total = self.core.pager.P
         self._finished: list[RequestOutput] = []
-        from repro.kernels import ops as _ops
-        self._ops = _ops
-        self._wc_base = _ops.weight_cache_stats(self.model_label)
-        self.calibrate = calibrate
-        from repro.runtime.calibrate import CalibrationTable
-        self.calibration = CalibrationTable()
         # Durability (serving.journal): admissions/tokens/finishes append to
         # the write-ahead log; flush() group-commits once per step. None =
         # non-durable (the default). A broken journal degrades silently to
@@ -280,11 +258,7 @@ class LLMEngine:
             return cfg
         from repro.runtime import mapper
         shape = ShapeConfig("serve_decode", 1, batch_slots, "decode")
-        # weight_reuse=1: the decode step is jit'd, so the eager decompress
-        # cache cannot amortise generation across steps inside the compiled
-        # program — don't let the model assume it.
-        return mapper.apply_plan(
-            cfg, mapper.plan_model(cfg, shape, hw=hw, weight_reuse=1))
+        return mapper.apply_plan(cfg, mapper.plan_model(cfg, shape, hw=hw))
 
     # -- request intake ----------------------------------------------------
 
@@ -455,10 +429,7 @@ class LLMEngine:
                 self._bind(i, req)
         t0 = time.perf_counter()
         try:
-            # Scope the decompress weight cache to this engine's model label
-            # so a multi-tenant process attributes hits/bytes per model.
-            with self._ops.weight_cache_scope(self.model_label):
-                out = self.core.step(so, last)
+            out = self.core.step(so, last)
         except Exception:               # watchdog: step crashed — recover
             if self._failures_since_token >= MAX_RECOVERIES_WITHOUT_TOKEN:
                 raise                   # it recurs: surface, do not replay
@@ -748,36 +719,11 @@ class LLMEngine:
             len(pg.slot_reqs) if pg.exact else 1 for pg in so.prefill_groups)
         st.prefill_compiles = self.core.prefill_compiles
         st.step_compiles = len(self.core.step_shapes)
-        wc = self._ops.weight_cache_stats(self.model_label)
-        st.weight_cache_hits = wc["hits"] - self._wc_base["hits"]
-        st.weight_cache_misses = wc["misses"] - self._wc_base["misses"]
-        st.weight_cache_entries = wc["entries"]
-        st.weight_cache_bytes = wc["bytes"]
-        if (self.calibrate and out.decode_s > 0.0 and not so.chunks
-                and not so.prefill_groups and self.cfg.exec_plan is not None):
-            from repro.runtime.calibrate import update_from_step
-            update_from_step(self.calibration, self.cfg.exec_plan,
-                             out.decode_s, self.hw_label)
 
     def run_until_drained(self, max_steps: int = 10_000) -> EngineStats:
         for _ in range(max_steps):
             if self.step() == 0:
                 break
         return self.stats
-
-    # -- measured-vs-modeled calibration -----------------------------------
-
-    def replan(self):
-        """Re-run the mapper under the accumulated calibration table.
-
-        Returns the corrected decode-shaped ExecutionPlan; compare against
-        ``self.cfg.exec_plan`` to see which layers the measured-vs-modeled
-        loop re-mapped. (The engine does not hot-swap the plan — a new plan
-        keys new jit traces, so callers rebuild the engine to adopt it.)
-        """
-        from repro.runtime import mapper
-        shape = ShapeConfig("serve_decode", 1, self.B, "decode")
-        return mapper.plan_model(self._base_cfg, shape, hw=self.hw,
-                                 weight_reuse=1, calibration=self.calibration)
 
 

@@ -204,12 +204,7 @@ class ServingGateway:
         """Drop a group's whole replica set (eviction callback / rebuild).
         The caller guarantees no live requests (pins checked, or the set
         was drained first)."""
-        rs = self._groups.pop(group, None)
-        if rs is not None:
-            for eng in rs.engines:
-                if eng is not None:
-                    # the model's resident dense-W decompressions go with it
-                    eng._ops.clear_weight_cache(eng.model_label)
+        if self._groups.pop(group, None) is not None:
             self.stats.engines_dropped += 1
 
     def _make_replica(self, group: str, r: int, *, with_faults: bool
@@ -472,7 +467,6 @@ class ServingGateway:
         self.stats.replicas_dead += 1
         self.stats.failovers += 1
         reqs = eng.drain_requests()
-        eng._ops.clear_weight_cache(eng.model_label)
         if not rs.alive():
             # replacement replica: clean (no fault plan — the plan died
             # with the replica) and health-fresh

@@ -394,7 +394,7 @@ class ModelRegistry:
                     ) -> None:
         """Drop a group's params (its engine serves no in-flight work — the
         caller checked pins). ``on_evict(group)`` lets the gateway drop the
-        corresponding engine and its weight-cache bucket."""
+        corresponding engine."""
         for n in self.group_members(group):
             e = self.entries[n]
             if e.resident:

@@ -1,23 +1,17 @@
-"""Step-based serving: chunked prefill + decode interleaving, calibration.
+"""Step-based serving: chunked prefill + decode interleaving.
 
 Covers the unified ``schedule() -> SchedulerOutput -> EngineCore.step()``
 contract: scheduler chunk/budget math (pure, no model), chunk-boundary edge
 cases (prompt shorter than a chunk, exact-multiple prompts, EOS mid-run,
-determinism vs the unchunked path under the same seed), the 2-shape compile
-bound of the fused window step, the measured-vs-modeled calibration loop
-(injected skew re-maps a layer on re-plan), and per-label weight-cache
-stats surfacing.
+determinism vs the unchunked path under the same seed), and the 2-shape
+compile bound of the fused window step.
 """
 import numpy as np
 import jax
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.configs.base import ShapeConfig
 from repro.models import registry as R
-from repro.runtime import mapper
-from repro.runtime.calibrate import (CalibrationTable, attribute_step,
-                                     update_from_step)
 from repro.serving import (ChunkTask, FCFSScheduler, FINISH_EOS,
                            FINISH_LENGTH, LLMEngine, Request, SamplingParams,
                            SchedulerOutput)
@@ -286,118 +280,3 @@ def test_request_outputs_carry_ttft_and_itl_samples(tiny):
     assert out.ttft_s is not None and out.ttft_s > 0.0
     assert len(out.itls_s) == out.n_tokens - 1
     assert all(d >= 0.0 for d in out.itls_s)
-
-
-# ---------------------------------------------------------------------------
-# Measured-vs-modeled calibration
-# ---------------------------------------------------------------------------
-
-def test_calibration_table_relative_factors():
-    t = CalibrationTable()
-    # uniform model error: every layer 100x slower than modeled
-    for n in ("a", "b", "c"):
-        t.record(n, "fused", "v5e", 100.0, 1.0)
-    for n in ("a", "b", "c"):
-        assert t.factor(n, "fused", "v5e") == pytest.approx(1.0)
-    # one layer deviates: only IT gets penalised (and the rest credited)
-    t2 = CalibrationTable()
-    t2.record("a", "fused", "v5e", 10.0, 1.0)
-    t2.record("b", "fused", "v5e", 1.0, 1.0)
-    assert t2.factor("a", "fused", "v5e") > 1.0 > t2.factor("b", "fused",
-                                                            "v5e")
-    assert t2.factor("unseen", "fused", "v5e") == 1.0
-    # round-trips through JSON
-    t3 = CalibrationTable.from_json(t2.to_json())
-    assert t3.factor("a", "fused", "v5e") == pytest.approx(
-        t2.factor("a", "fused", "v5e"))
-
-
-def test_attribute_step_splits_wall_time_by_modeled_ii(tiny):
-    cfg, _ = tiny
-    shape = ShapeConfig("serve_decode", 1, 4, "decode")
-    plan = mapper.plan_model(cfg, shape, hw="v5e", weight_reuse=1)
-    samples = attribute_step(plan, wall_s=1.0)
-    assert samples and abs(sum(m for _n, _p, m, _ii in samples) - 1.0) < 1e-9
-    total_ii = sum(ii for _n, _p, _m, ii in samples)
-    for _n, _p, measured, ii in samples:
-        assert measured == pytest.approx(ii / total_ii)
-
-
-def test_calibration_skew_changes_engine_replan(tiny):
-    # Acceptance: run the engine with calibration on, feed measured factors
-    # back through plan_model, and the corrected plan differs from the
-    # uncalibrated one under an injected model-vs-measured skew.
-    cfg, params = tiny
-    assert cfg.ovsf.enable
-    eng = LLMEngine(params, cfg, batch_slots=4, buffer_len=64,
-                    chunk_size=8, calibrate=True, hw="v5e")
-    for rid, L in enumerate([5, 11, 20]):
-        eng.submit(_req(rid, L, max_new=6, vocab=cfg.vocab))
-    eng.run_until_drained()
-    base_plan = eng.cfg.exec_plan
-    assert base_plan is not None and len(eng.calibration) > 0
-    # pure-decode steps were attributed proportionally to the model, so the
-    # measured factors are ~uniform (normalised to ~1.0) and the re-plan
-    # keeps every layer on its path
-    assert [lp.path for _n, lp in eng.replan().entries] == \
-        [lp.path for _n, lp in base_plan.entries]
-    # inject a large measured-vs-modeled skew on one executed path, relative
-    # to the ratios the real run recorded (host wall vs modeled-v5e II is a
-    # huge uniform ratio — exactly what the normalisation discounts)
-    name, lp = next((n, lp) for n, lp in base_plan.entries
-                    if lp.path == "fused")
-    r = eng.calibration.raw_ratio(name, lp.path, "v5e") or 1.0
-    for _ in range(200):
-        eng.calibration.record(name, lp.path, "v5e", 100.0 * r * lp.ii_s,
-                               lp.ii_s)
-    corrected = eng.replan()
-    changed = [(n, a.path, b.path) for (n, a), (_n, b)
-               in zip(base_plan.entries, corrected.entries)
-               if a.path != b.path]
-    assert changed and changed[0][0] == name
-    assert changed[0][1] == "fused" and changed[0][2] != "fused"
-
-
-def test_update_from_step_records_executed_paths(tiny):
-    cfg, _ = tiny
-    shape = ShapeConfig("serve_decode", 1, 4, "decode")
-    plan = mapper.plan_model(cfg, shape, hw="v5e", weight_reuse=1)
-    t = CalibrationTable()
-    n = update_from_step(t, plan, wall_s=0.5, hw="v5e")
-    assert n == len(plan.entries) == len(t)
-
-
-# ---------------------------------------------------------------------------
-# Satellite: per-label weight-cache stats surfacing
-# ---------------------------------------------------------------------------
-
-def test_weight_cache_stats_surface_in_engine_stats():
-    from repro.kernels import ops
-    cfg = get_smoke_config("tinyllama_1_1b")
-    base = ops.weight_cache_stats()
-    assert set(base) >= {"hits", "misses", "entries", "bytes"}
-    params = R.model_init(jax.random.PRNGKey(0), cfg)
-    eng = LLMEngine(params, cfg, batch_slots=2, buffer_len=32, hw="cpu")
-    eng.submit(_req(0, 5, max_new=3, vocab=cfg.vocab))
-    stats = eng.run_until_drained()
-    # the engine surfaces per-run deltas of the process-wide counters
-    assert stats.weight_cache_hits >= 0
-    assert stats.weight_cache_misses >= 0
-    assert stats.weight_cache_entries == ops.weight_cache_stats()["entries"]
-
-
-def test_cached_generate_counts_hits_and_misses():
-    import jax.numpy as jnp
-    from repro.kernels import ops
-    ops.clear_weight_cache()
-    alphas = jnp.ones((8, 16))
-    idx = jnp.arange(8)
-    calls = []
-    gen = lambda: (calls.append(1), jnp.zeros((16, 16)))[1]
-    ops.cached_generate("k", alphas, idx, gen)
-    ops.cached_generate("k", alphas, idx, gen)
-    st = ops.weight_cache_stats()
-    assert (st["hits"], st["misses"], st["entries"]) == (1, 1, 1)
-    assert len(calls) == 1
-    ops.clear_weight_cache()
-

@@ -3,9 +3,10 @@
 The mapper (runtime.mapper) must be a pure function of (layer shape, rho, HW)
 and reproduce the paper's §5 regime split: memory-bound decode GEMMs run the
 fused on-the-fly generator, compute-bound train/prefill GEMMs pre-generate
-dense W once and reuse it (weight-stationary + decompress cache).
+dense W and contract it in one GEMM (weight-stationary).
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,31 +27,27 @@ from repro.runtime import mapper
 
 def test_decode_shaped_layer_maps_to_fused():
     # B=8 decode GEMV-block: memory-bound on weight bytes -> generate in-tile
-    plan = mapper.classify_gemm(8, 4096, 4096, 0.5, seg=16, weight_reuse=256)
+    plan = mapper.classify_gemm(8, 4096, 4096, 0.5, seg=16)
     assert plan.path == "fused"
-    assert not plan.cache_weights
 
 
 def test_train_shaped_layer_maps_to_materialize_with_cache():
     # 8k tokens: compute-bound -> pre-generate dense W, weight-stationary
-    plan = mapper.classify_gemm(8192, 4096, 4096, 0.5, seg=16, weight_reuse=1)
+    plan = mapper.classify_gemm(8192, 4096, 4096, 0.5, seg=16)
     assert plan.path == "materialize"
-    assert plan.cache_weights
 
 
 def test_prefill_shaped_layer_maps_to_materialize():
-    plan = mapper.classify_gemm(2048, 2048, 2048, 0.5, seg=16,
-                                weight_reuse=256)
+    plan = mapper.classify_gemm(2048, 2048, 2048, 0.5, seg=16)
     assert plan.path == "materialize"
-    assert plan.cache_weights
 
 
 def test_mapper_is_pure_in_shape_rho_hw():
-    a = mapper.classify_gemm(8, 2048, 2048, 0.5, seg=16, weight_reuse=64)
-    b = mapper.classify_gemm(8, 2048, 2048, 0.5, seg=16, weight_reuse=64)
+    a = mapper.classify_gemm(8, 2048, 2048, 0.5, seg=16)
+    b = mapper.classify_gemm(8, 2048, 2048, 0.5, seg=16)
     assert a == b                       # same inputs -> identical plan
     # and the decision flips with the workload shape, not hidden state
-    c = mapper.classify_gemm(8192, 2048, 2048, 0.5, seg=16, weight_reuse=64)
+    c = mapper.classify_gemm(8192, 2048, 2048, 0.5, seg=16)
     assert c.path != a.path
 
 
@@ -59,8 +56,7 @@ def test_bandwidth_starved_hw_pushes_toward_generation():
     # prefer generation; on an infinite-bandwidth device the distinction
     # collapses to compute and materialize's single GEMM wins ties.
     slow = pm.V5E.scaled_bw(0.1)
-    p_slow = mapper.classify_gemm(8, 4096, 4096, 0.5, seg=16, hw=slow,
-                                  weight_reuse=256)
+    p_slow = mapper.classify_gemm(8, 4096, 4096, 0.5, seg=16, hw=slow)
     assert p_slow.path == "fused"
 
 
@@ -107,7 +103,7 @@ def test_plan_model_plans_the_expert_banks_under_their_names(slots):
     cfg = get_config("olmoe_1b_7b")
     ep = mapper.plan_model(cfg, ShapeConfig("serve_decode", 1, slots,
                                             "decode"),
-                           hw="v5e", weight_reuse=1)
+                           hw="v5e")
     assert "e" not in ep.names()
     for name in ("expert_gate", "expert_up", "expert_down"):
         assert name in ep.names()
@@ -122,7 +118,7 @@ def test_plan_model_keeps_the_trunk_plans_of_a_dense_model():
     from repro.configs import get_config
     ep = mapper.plan_model(get_config("starcoder2_15b"),
                            ShapeConfig("serve_decode", 1, 128, "decode"),
-                           hw="v5e", weight_reuse=1)
+                           hw="v5e")
     assert [(n, lp.path, lp.block_m, lp.block_n, lp.block_k, lp.block_j)
             for n, lp in ep.entries] == [
         ("attn_q", "fused", 128, 128, 128, 128),
@@ -134,22 +130,44 @@ def test_plan_model_keeps_the_trunk_plans_of_a_dense_model():
     ]
 
 
+@pytest.mark.parametrize("arch", ["tinyllama_1_1b", "olmoe_1b_7b",
+                                  "starcoder2_15b", "qwen2_5_14b"])
+def test_engine_serves_the_default_decode_plan(arch):
+    """The engine serves the mapper's decode plan for its slot count as
+    ``plan_model`` emits it: no serving-only planning knob re-ranks paths."""
+    from repro.configs import get_config
+    from repro.serving.engine import LLMEngine
+    cfg = get_config(arch)
+    assert LLMEngine._plan_cfg(cfg, 32, True, "v5e").exec_plan == \
+        mapper.plan_model(cfg, ShapeConfig("serve_decode", 1, 32, "decode"),
+                          hw="v5e")
+
+
 def test_plan_model_train_shape_prefers_materialize():
     cfg = get_smoke_config("tinyllama_1_1b")
     shape = ShapeConfig("t", 512, 8, "train")
     ep = mapper.plan_model(cfg, shape)
     assert ep.plan_for("mlp_up").path == "materialize"
-    assert ep.plan_for("mlp_up").cache_weights
 
 
-def test_plan_cnn_emits_plans_for_compressed_convs():
-    from repro.models.cnn import CNNConfig
-    cfg = CNNConfig("r18", "resnet18", ovsf_enable=True,
-                    block_rhos=(1.0, 0.5, 0.5, 0.5))
-    ep = mapper.plan_cnn(cfg, batch=1)
-    assert len(ep.entries) > 0
+@pytest.mark.parametrize("arch,batch,n_conv,n_materialize", [
+    ("resnet18", 1, 12, 12),
+    ("squeezenet1_1", 1, 6, 4),
+    ("squeezenet1_1", 8, 6, 6),
+    ("squeezenet1_1", 64, 6, 6),
+])
+def test_plan_cnn_emits_plans_for_compressed_convs(arch, batch, n_conv,
+                                                   n_materialize):
+    """Each compressed 3x3 conv gets a plan. The im2col GEMMs of the paper's
+    CNNs have enough rows that most pre-generate dense W; at batch 1 two of
+    squeezenet's fire expands (f4e3, f5e3) generate in-tile instead."""
+    from repro.configs import get_config
+    ep = mapper.plan_cnn(get_config(arch), batch=batch)
+    assert len(ep.entries) == n_conv
     for name, lp in ep.entries:
         assert lp.path in ("fused", "materialize")
+    assert sum(lp.path == "materialize" for _n, lp in ep.entries) == \
+        n_materialize
 
 
 # ---------------------------------------------------------------------------
@@ -168,8 +186,11 @@ def _integer_ovsf_case(key, d_in, d_out, rho, seg):
     return x, alphas, p["idx"]
 
 
+@pytest.mark.parametrize("jit", [False, True])
 @pytest.mark.parametrize("seg", [0, 16])
-def test_paths_bit_identical_under_plans(seg):
+def test_paths_bit_identical_under_plans(seg, jit):
+    """The three paths agree eagerly and inside a jitted program, the
+    regime every served step runs in."""
     key = jax.random.PRNGKey(0)
     x, alphas, idx = _integer_ovsf_case(key, 256, 128, 0.5, seg)
     base = mapper.classify_gemm(16, 256, 128, 0.5, seg=seg,
@@ -177,7 +198,10 @@ def test_paths_bit_identical_under_plans(seg):
     outs = {}
     for path in ("materialize", "fused", "spectral"):
         plan = dataclasses.replace(base, path=path)
-        outs[path] = np.asarray(ops.ovsf_matmul(x, alphas, idx, plan=plan))
+        fn = functools.partial(ops.ovsf_matmul, plan=plan)
+        if jit:
+            fn = jax.jit(fn)
+        outs[path] = np.asarray(fn(x, alphas, idx))
     np.testing.assert_array_equal(outs["materialize"], outs["fused"])
     np.testing.assert_array_equal(outs["materialize"], outs["spectral"])
 
@@ -192,42 +216,6 @@ def test_fused_pallas_interpret_matches_plan_output():
         block_m=plan.block_m, block_n=plan.block_n,
         block_k=plan.block_k, block_j=plan.block_j))
     np.testing.assert_allclose(y_pal, y_ref, rtol=0, atol=0)
-
-
-# ---------------------------------------------------------------------------
-# Decompress cache policy
-# ---------------------------------------------------------------------------
-
-def test_weight_cache_hits_and_invalidates():
-    ops.clear_weight_cache()
-    key = jax.random.PRNGKey(2)
-    x, alphas, idx = _integer_ovsf_case(key, 256, 128, 0.5, 16)
-    plan = mapper.LayerPlan("materialize", cache_weights=True,
-                            cache_key="test_layer")
-    y1 = ops.ovsf_matmul(x, alphas, idx, plan=plan)
-    assert ops.weight_cache_stats()["entries"] == 1
-    # slots are keyed (cache_key | alpha dtype) so a dtype switch re-keys
-    w_cached = ops._WEIGHT_CACHE[""]["test_layer|fp"][2]
-    y2 = ops.ovsf_matmul(x, alphas, idx, plan=plan)
-    assert ops._WEIGHT_CACHE[""]["test_layer|fp"][2] is w_cached   # reused
-    np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
-    # new parameter version -> regenerated
-    alphas2 = alphas + 1.0
-    ops.ovsf_matmul(x, alphas2, idx, plan=plan)
-    assert ops._WEIGHT_CACHE[""]["test_layer|fp"][2] is not w_cached
-    ops.clear_weight_cache()
-
-
-def test_weight_cache_skips_tracers():
-    ops.clear_weight_cache()
-    key = jax.random.PRNGKey(3)
-    x, alphas, idx = _integer_ovsf_case(key, 256, 128, 0.5, 16)
-    plan = mapper.LayerPlan("materialize", cache_weights=True,
-                            cache_key="traced_layer")
-    y = jax.jit(lambda a: ops.ovsf_matmul(x, a, idx, plan=plan))(alphas)
-    jax.block_until_ready(y)
-    assert "traced_layer" not in ops._WEIGHT_CACHE.get("", {})  # no tracer leaks
-    ops.clear_weight_cache()
 
 
 # ---------------------------------------------------------------------------

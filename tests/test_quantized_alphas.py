@@ -246,38 +246,6 @@ def test_quantised_output_close_to_fp(seed=17):
 
 
 # ---------------------------------------------------------------------------
-# Decompress cache keys on alpha dtype (satellite: no stale fp32 weights)
-# ---------------------------------------------------------------------------
-
-def test_weight_cache_keys_on_alpha_dtype():
-    ops.clear_weight_cache()
-    x, al, sc, idx = _quant_case(23, 4, 64, 32, "int8")
-    spec = ovsf.OVSFSpec(64, 32, rho=0.5, seg=16)
-    W = jax.random.normal(jax.random.PRNGKey(23), (64, 32)) * 0.1
-    p = ovsf.compress_matrix(W, spec)
-    plan = mapper.LayerPlan("materialize", cache_weights=True,
-                            cache_key="layer0")
-    y_fp = ops.ovsf_matmul(x, p["alphas"], p["idx"], plan=plan,
-                           use_pallas=False)
-    s1 = ops.weight_cache_stats()
-    assert s1["misses"] == 1 and s1["entries"] == 1 and s1["bytes"] > 0
-    # same params again: served from cache
-    ops.ovsf_matmul(x, p["alphas"], p["idx"], plan=plan, use_pallas=False)
-    assert ops.weight_cache_stats()["hits"] == 1
-    # dtype switch under the SAME plan/cache_key: must regenerate into a new
-    # slot, never serve the stale fp32 W
-    y_q = ops.ovsf_matmul(x, al, idx, plan=plan, use_pallas=False,
-                          alpha_scale=sc, alpha_dtype="int8")
-    s2 = ops.weight_cache_stats()
-    assert s2["misses"] == 2 and s2["entries"] == 2, s2
-    assert not np.allclose(np.asarray(y_q), np.asarray(y_fp), atol=0)
-    # flipping back is a hit again (both dtypes stay resident)
-    ops.ovsf_matmul(x, p["alphas"], p["idx"], plan=plan, use_pallas=False)
-    assert ops.weight_cache_stats()["hits"] == 2
-    ops.clear_weight_cache()
-
-
-# ---------------------------------------------------------------------------
 # Perf model + mapper account the shrunken alpha stream
 # ---------------------------------------------------------------------------
 
@@ -297,8 +265,8 @@ def test_modeled_fused_ii_strictly_drops_with_quantisation():
 
 
 def test_mapper_threads_alpha_dtype():
-    p_fp = mapper.classify_gemm(8, 4096, 4096, 0.5, seg=16, weight_reuse=256)
-    p_q = mapper.classify_gemm(8, 4096, 4096, 0.5, seg=16, weight_reuse=256,
+    p_fp = mapper.classify_gemm(8, 4096, 4096, 0.5, seg=16)
+    p_q = mapper.classify_gemm(8, 4096, 4096, 0.5, seg=16,
                                alpha_dtype="int8")
     assert p_q.path == "fused" and p_q.alpha_dtype == "int8"
     assert p_q.ii_s < p_fp.ii_s          # quantising raises the roofline
@@ -307,8 +275,7 @@ def test_mapper_threads_alpha_dtype():
     cfg = get_smoke_config("tinyllama_1_1b").replace(d_model=1024, d_ff=2048)
     cfg = cfg.replace(ovsf=dataclasses.replace(cfg.ovsf, alpha_dtype="int4",
                                                min_dim=512))
-    plan = mapper.plan_model(cfg, ShapeConfig("d", 1, 8, "decode"),
-                             weight_reuse=1)
+    plan = mapper.plan_model(cfg, ShapeConfig("d", 1, 8, "decode"))
     assert plan.entries and all(lp.alpha_dtype == "int4"
                                 for _n, lp in plan.entries)
 
@@ -383,6 +350,3 @@ def test_fused_int8_serving_decode_deterministic():
     assert t1 == t2, "fused-int8 decode must be seed-deterministic"
     assert all(len(t) == 4 for t in t1)
     assert st1.completed == 2
-    # EngineStats surfaces the cache footprint counter (0 here: decode plans
-    # run fused, nothing materialised)
-    assert st1.weight_cache_bytes >= 0

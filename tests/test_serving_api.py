@@ -280,10 +280,8 @@ def test_mapper_path_decision_differs_cpu_vs_v5e():
     # Same GEMM, different machine balance, different regime: v5e's HBM wall
     # favours the fused generator; on the flat CPU hierarchy regeneration is
     # the bottleneck and materialize wins.
-    pc = mapper.classify_gemm(128, 2048, 2048, 0.5, seg=16, hw="cpu",
-                              weight_reuse=256)
-    pv = mapper.classify_gemm(128, 2048, 2048, 0.5, seg=16, hw="v5e",
-                              weight_reuse=256)
+    pc = mapper.classify_gemm(128, 2048, 2048, 0.5, seg=16, hw="cpu")
+    pv = mapper.classify_gemm(128, 2048, 2048, 0.5, seg=16, hw="v5e")
     assert pc.path == "materialize" and pv.path == "fused"
 
 

@@ -51,8 +51,7 @@ def _plan(alpha_dtype: str, name: str, arch: str = ARCH, rows: int = ROWS):
     cfg = cfg.replace(ovsf=dataclasses.replace(cfg.ovsf,
                                                alpha_dtype=alpha_dtype))
     shape = ShapeConfig("serve_decode", 1, rows, "decode")
-    return cfg, mapper.plan_model(cfg, shape, hw="v5e",
-                                  weight_reuse=1).plan_for(name)
+    return cfg, mapper.plan_model(cfg, shape, hw="v5e").plan_for(name)
 
 
 @pytest.mark.parametrize("alpha_dtype,name,arch,rows", [

@@ -145,7 +145,7 @@ def test_kernel_notes_count_the_generator_chunks_per_k_block(arch, rows,
     runs 1 of its nc chunks. Noted at trace time, at full width."""
     cfg = get_config(arch)
     lp = mapper.plan_model(cfg, ShapeConfig("serve_decode", 1, rows, "decode"),
-                           hw="v5e", weight_reuse=1).plan_for(name)
+                           hw="v5e").plan_for(name)
     d_in, d_out = {"attn_q": (cfg.d_model, cfg.n_heads * cfg.hd),
                    "mlp_up": (cfg.d_model, cfg.d_ff),
                    "mlp_down": (cfg.d_ff, cfg.d_model)}[name]
